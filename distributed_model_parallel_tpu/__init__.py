@@ -12,7 +12,7 @@ pipeline model parallelism (the reference's autograd-transparent
 BERT, a GPT-style causal LM, MoE transformer blocks), the dataset
 collection, and the trainer surface (SGD / AdamW + cosine decay + warmup,
 acc1/acc5 metrics, best-acc checkpointing with resume, elastic
-restarts). Mechanics: INTERNALS.md; numbers: RESULTS.md.
+restarts). Mechanics: INTERNALS.md; measurements: PERF.md.
 
 Package layout:
   runtime/   mesh + multi-host bootstrap (replaces dist.init_process_group)

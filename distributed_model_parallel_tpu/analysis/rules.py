@@ -1,7 +1,7 @@
 """The collective-contract rule registry.
 
 Each rule encodes ONE contract the repo claims in prose (INTERNALS §3c/
-§3e/§3f/§5c, RESULTS §3b) as a check over a parsed+classified HLO
+§3e/§3f/§5c) as a check over a parsed+classified HLO
 module. Rules are severity-tagged and declare their own applicability
 over a `LintTarget` (the engine/mode/mesh description the lint driver
 fills in when it lowers a combo), so the same registry runs over the

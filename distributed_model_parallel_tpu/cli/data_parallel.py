@@ -42,6 +42,9 @@ from distributed_model_parallel_tpu.parallel.data_parallel import (
 )
 from distributed_model_parallel_tpu.runtime.dist import initialize_backend
 from distributed_model_parallel_tpu.runtime.mesh import MeshSpec, make_mesh
+from distributed_model_parallel_tpu.runtime.platform import (
+    enable_compile_cache,
+)
 from distributed_model_parallel_tpu.training.trainer import (
     Trainer,
     TrainerConfig,
@@ -131,6 +134,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> dict:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     if args.finetune:
         # Fail fast (before datasets/engine/trainer build): typo'd paths
         # or unsupported model families should not cost a download first.

@@ -50,6 +50,9 @@ from distributed_model_parallel_tpu.parallel.sequence_parallel import (
 )
 from distributed_model_parallel_tpu.runtime.dist import initialize_backend
 from distributed_model_parallel_tpu.runtime.mesh import MeshSpec, make_mesh
+from distributed_model_parallel_tpu.runtime.platform import (
+    enable_compile_cache,
+)
 from distributed_model_parallel_tpu.training.trainer import (
     Trainer,
     TrainerConfig,
@@ -181,6 +184,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> dict:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     from distributed_model_parallel_tpu.cli.common import (
         setup_metrics_out,
     )

@@ -38,6 +38,9 @@ from distributed_model_parallel_tpu.cli.common import (
 from distributed_model_parallel_tpu.parallel.pipeline import PipelineEngine
 from distributed_model_parallel_tpu.runtime.dist import initialize_backend
 from distributed_model_parallel_tpu.runtime.mesh import MeshSpec, make_mesh
+from distributed_model_parallel_tpu.runtime.platform import (
+    enable_compile_cache,
+)
 from distributed_model_parallel_tpu.training.trainer import (
     Trainer,
     TrainerConfig,
@@ -146,6 +149,7 @@ def build_stages(model: str, num_stages: int, num_classes: int,
 
 def main(argv=None) -> dict:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     check_pipeline_schedule_args(
         args.pipeline_schedule, args.virtual_stages, args.microbatches,
         args.world_size,

@@ -35,6 +35,9 @@ from distributed_model_parallel_tpu.cli.common import (
 from distributed_model_parallel_tpu.models.gpt import GPTConfig
 from distributed_model_parallel_tpu.runtime.dist import initialize_backend
 from distributed_model_parallel_tpu.runtime.mesh import MeshSpec, make_mesh
+from distributed_model_parallel_tpu.runtime.platform import (
+    enable_compile_cache,
+)
 from distributed_model_parallel_tpu.serving.engine import ServingEngine
 from distributed_model_parallel_tpu.serving.scheduler import Request
 
@@ -363,6 +366,7 @@ def _draft_config(args, target_cfg) -> "tuple[GPTConfig, str | None]":
 
 def main(argv=None) -> dict:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     check_serving_args(args)
     from distributed_model_parallel_tpu.cli.common import (
         setup_metrics_out,

@@ -114,7 +114,7 @@ def synthetic_textures(
     with class-specific orientations/frequencies), and every sample
     draws fresh phases, amplitudes, a random spatial shift and pixel
     noise. Unlike `synthetic` (fixed class-mean images, which a
-    2.3M-param model simply memorizes — RESULTS §1c), no two samples
+    2.3M-param model simply memorizes), no two samples
     share pixels, so val accuracy measures the learned texture
     statistics, not recall.
 

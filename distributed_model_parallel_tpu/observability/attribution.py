@@ -11,9 +11,8 @@ contains) and reduces it to
   * a per-phase table (count / total / mean / share of wall) over the
     documented span names (`metrics.TRACE_EVENT_NAMES`),
   * the **unattributed residual** — main-track wall time covered by NO
-    span — called out explicitly (VERDICT §5's trace-attributed-MFU
-    discipline: a number you cannot attribute is a number you cannot
-    trust), and
+    span — called out explicitly (a number you cannot attribute is a
+    number you cannot trust), and
   * a measured-vs-predicted row per requested combo: the ledger's
     predicted per-step comm time against the measured per-step `sync`
     time (the value-fetch fences are where device+comm time surfaces

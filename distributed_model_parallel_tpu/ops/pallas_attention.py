@@ -48,14 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:  # pltpu is importable on CPU builds too; guard for safety
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover - exotic builds
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 from distributed_model_parallel_tpu.ops.attention import (
     dot_product_attention,
@@ -264,9 +257,9 @@ def _flash_forward(q, k, v, mask, scale, block_q, block_k, interpret,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            _VMEM((bq, 1), jnp.float32),   # running max
-            _VMEM((bq, 1), jnp.float32),   # running denominator
-            _VMEM((bq, dh), jnp.float32),  # running numerator
+            pltpu.VMEM((bq, 1), jnp.float32),   # running max
+            pltpu.VMEM((bq, 1), jnp.float32),   # running denominator
+            pltpu.VMEM((bq, dh), jnp.float32),  # running numerator
         ],
         interpret=interpret,
     )(*operands)
@@ -423,7 +416,7 @@ def _flash_backward(q, k, v, mask, out, lse, g, scale, bq, bk,
         in_specs=dq_specs,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((b, h, tq, dh), q.dtype),
-        scratch_shapes=[_VMEM((bq, dh), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, dh), jnp.float32)],
         interpret=interpret,
     )(*dq_ops)
 
@@ -466,8 +459,8 @@ def _flash_backward(q, k, v, mask, out, lse, g, scale, bq, bk,
             jax.ShapeDtypeStruct((b, h, tk, dh), v.dtype),
         ],
         scratch_shapes=[
-            _VMEM((bk, dh), jnp.float32),
-            _VMEM((bk, dh), jnp.float32),
+            pltpu.VMEM((bk, dh), jnp.float32),
+            pltpu.VMEM((bk, dh), jnp.float32),
         ],
         interpret=interpret,
     )(*dkv_ops)
@@ -536,21 +529,7 @@ def flash_attention(
 
     `interpret=None` auto-selects: compiled on TPU, interpreter
     elsewhere (tests). See module docstring for scope.
-
-    Availability is probed ONCE at import (`_VMEM`, module top): on a
-    build without `jax.experimental.pallas.tpu` the call degrades to
-    the dense `dot_product_attention` reference instead of raising —
-    the same probe-at-import / fall-back-at-call shape as
-    `ops/quant_matmul.quant_matmul`, so a serving or training step
-    composed against `flash_attention` stays runnable (slower, denser)
-    on exotic builds rather than failing mid-request (ISSUE 16
-    satellite; the old call-time RuntimeError turned a missing
-    OPTIONAL dependency into a hard fault).
     """
-    if _VMEM is None:
-        return dot_product_attention(
-            q, k, v, mask, scale=scale, causal=causal
-        )
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])

@@ -34,13 +34,11 @@ Dual path, same shape as `pallas_attention.flash_attention`:
          | needed)                  |
   f32    | plain `x @ w`            | plain `x @ w`
 
-The availability probe is cached ONCE at module import (`_VMEM`), never
-raised at call time: `path=None` auto-selects the Pallas kernel only on
-a TPU backend with a healthy pltpu import, and the `lax.dot_general`
-fallback otherwise — so a CPU trace of an opted-in decode step carries
-real int8 `dot_general` equations, which is exactly what the hlolint
-rule `decode-quantized-matmul` pins from the jaxpr (compiled CPU HLO
-normalizes dtypes, so the contract lives at trace level, like
+`path=None` selects the Pallas kernel on a TPU backend and the
+`lax.dot_general` twin elsewhere — so a CPU trace of an opted-in decode
+step carries real int8 `dot_general` equations, which is exactly what
+the hlolint rule `decode-quantized-matmul` pins from the jaxpr (compiled
+CPU HLO normalizes dtypes, so the contract lives at trace level, like
 `bf16-ring-upcast`). Tests drive the kernel explicitly with
 `path="pallas"` (interpret mode off-TPU).
 
@@ -60,14 +58,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:  # pltpu is importable on CPU builds too; guard for safety
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover - exotic builds
-    pltpu = None
-    _VMEM = None
 
 from distributed_model_parallel_tpu.ops.wire_codec import ABSMAX_FLOOR
 
@@ -230,11 +220,7 @@ def quant_matmul(
     if mode == "bf16":
         return x.astype(jnp.bfloat16) @ w.astype(jnp.bfloat16)
     if path is None:
-        path = (
-            "pallas"
-            if _VMEM is not None and jax.default_backend() == "tpu"
-            else "xla"
-        )
+        path = "pallas" if jax.default_backend() == "tpu" else "xla"
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     if path == "pallas":

@@ -272,14 +272,11 @@ def _dense_pair_bwd(q, k, v, maskb, out, lse, g, scale, causal):
 
 def _pair_blocks(tq, tk):
     from distributed_model_parallel_tpu.ops.pallas_attention import (
-        _VMEM,
         DEFAULT_BLOCK_Q,
         DEFAULT_BLOCK_K,
         _blocks_viable,
     )
 
-    if _VMEM is None:  # pallas.tpu unavailable: dense per-hop fallback
-        return None
     return _blocks_viable(tq, tk, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)
 
 

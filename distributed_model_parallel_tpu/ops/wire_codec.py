@@ -6,7 +6,7 @@ gradient reducer (`ops/grad_reduction.py`) and the two-level MoE expert
 dispatch (`ops/expert_dispatch.py`) — already isolate the slow
 cross-slice fabric onto a 1/ici-regrouped shard: the 'dcn' hop is the
 ONE place a payload is both large and riding a link an order of
-magnitude slower than ICI (RESULTS §3b/§3c). That is exactly where
+magnitude slower than ICI. That is exactly where
 payload compression pays, and it is the seam PyTorch DDP exposes as
 comm hooks on its bucketed Reducer (Li et al., VLDB 2020) and
 DeepSpeed-MoE cheapens its expert exchange through (Rajbhandari et al.,

@@ -262,7 +262,7 @@ class TensorParallelEngine:
         sharded across processes ('model' rules here, 'data' under
         FSDPEngine) and thus NOT fully addressable — a bare
         `jax.device_get` in `save_checkpoint` would crash exactly on the
-        ZeRO-3/TP deployments that shard (VERDICT r4 weak #3). Leaves
+        ZeRO-3/TP deployments that shard. Leaves
         are all-gathered one at a time (`tree_to_host`), so the device
         transient is a single unsharded leaf. COLLECTIVE on a
         multi-process mesh: every process must call this together."""

@@ -1,26 +1,8 @@
-"""JAX version compatibility shims.
-
-The engines are written against the current `jax.shard_map` API (top-level
-export, `check_vma=` kwarg). Older installs (<= 0.4.x) ship shard_map under
-`jax.experimental.shard_map` with the same semantics behind the older
-`check_rep=` spelling. Every in-repo import of shard_map goes through this
-module so the engines run unchanged on both.
-"""
+"""The one import site of `jax.shard_map` (top-level export, `check_vma=`
+kwarg): every engine imports it from here."""
 
 from __future__ import annotations
 
-try:  # jax >= 0.6: top-level export, check_vma kwarg
-    from jax import shard_map as shard_map  # type: ignore[attr-defined]
-except ImportError:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def shard_map(f, /, **kwargs):
-        """`jax.shard_map`-compatible wrapper over the experimental API:
-        maps `check_vma=` (current name for the replication-safety check)
-        onto `check_rep=` (its old name)."""
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _legacy_shard_map(f, **kwargs)
-
+from jax import shard_map
 
 __all__ = ["shard_map"]

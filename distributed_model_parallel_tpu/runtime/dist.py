@@ -22,6 +22,25 @@ log = logging.getLogger(__name__)
 _initialized = False
 
 
+def _multi_host_env() -> bool:
+    """True when the environment describes a job of more than one host,
+    i.e. when `jax.distributed.initialize()` has peers to find.
+
+    A single TPU host commonly carries `TPU_WORKER_ID=0` too (with
+    `TPU_WORKER_HOSTNAMES=localhost`); a rendezvous there has nobody to
+    meet and, with no network, nothing to discover — so where the host
+    list is given, its length decides, and the bare worker-id variables
+    count only without one."""
+    if "COORDINATOR_ADDRESS" in os.environ:
+        return True
+    hosts = os.environ.get("TPU_WORKER_HOSTNAMES")
+    if hosts is not None:
+        return len([h for h in hosts.split(",") if h.strip()]) > 1
+    return any(
+        v in os.environ for v in ("CLOUD_TPU_TASK_ID", "TPU_WORKER_ID")
+    )
+
+
 def initialize_backend(
     coordinator_address: Optional[str] = None,
     num_processes: Optional[int] = None,
@@ -45,12 +64,7 @@ def initialize_backend(
         # Accept reference-style URLs ('tcp://127.0.0.1:1224',
         # `model_parallel.py:19`); jax wants bare host:port.
         coordinator_address = coordinator_address.split("://", 1)[1]
-    explicit = coordinator_address is not None
-    auto = any(
-        v in os.environ
-        for v in ("COORDINATOR_ADDRESS", "CLOUD_TPU_TASK_ID", "TPU_WORKER_ID")
-    )
-    if explicit or auto:
+    if coordinator_address is not None or _multi_host_env():
         jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes,

@@ -18,7 +18,7 @@ Key structural facts it surfaces:
 * depthwise 3x3 convs do 9 flops per loaded element — pure bandwidth.
 
 Run: python experiments/mnv2_roofline.py   (no device needed)
-Writes experiments/mnv2_roofline.json; summarized in RESULTS §1.
+Writes experiments/mnv2_roofline.json.
 """
 
 from __future__ import annotations

@@ -9,9 +9,8 @@ This host has ONE real chip, so the evidence is structural + modeled:
    program asks the network for).
 2. Compile (XLA optimization pipeline, 64-way) the SAME ResNet-50 step
    and capture ITS OWN post-optimization all-reduce op count and bytes
-   (VERDICT r5 weak #2: previously only tinycnn's optimized HLO was
-   inspected and the fused-schedule shape was extrapolated from it —
-   the flagship model's own compile is what the cost model must eat).
+   (the flagship model's own compile is what the cost model must
+   eat, not a shape extrapolated from tinycnn's optimized HLO).
    The tinycnn compile+run stays as a cheap liveness check of the
    64-way program.
 3. Feed ResNet-50's own post-optimization all-reduce bytes (and op
@@ -21,9 +20,9 @@ This host has ONE real chip, so the evidence is structural + modeled:
    efficiency at 64 chips — both for this backend's unfused lowering
    and for a bucketed one.
 
-Writes experiments/scaling64.json; summarized in RESULTS.md §3.
+Writes experiments/scaling64.json.
 
-Run: python experiments/scaling64.py   (CPU-only, no TPU dial)
+Run: python experiments/scaling64.py   (CPU-only, no TPU needed)
 """
 
 from __future__ import annotations
@@ -58,7 +57,7 @@ from distributed_model_parallel_tpu.training.optim import SGD  # noqa: E402
 N = 64
 PER_CHIP_BATCH = 256
 
-# Measured on the one real chip (BENCH_r04 / RESULTS.md §1): ResNet-50
+# Measured on one v5e chip before PR 1 (BENCH_r04.json): ResNet-50
 # bs256 bf16, 2489 img/s/chip -> 0.1029 s/step, MFU 0.30.
 MEASURED_STEP_S = 256 / 2489.0
 # Per-fabric alpha/beta constants: ONE home, shared with the static

@@ -16,7 +16,7 @@ This script measures, on this framework's batched loader:
      while keeping the epoch-level permutation) — isolating the
      locality effect from everything else.
 
-Writes experiments/shuffle_cost.json; summarized in RESULTS.md.
+Writes experiments/shuffle_cost.json.
 
 Run on a QUIET host: python experiments/shuffle_cost.py
 """

@@ -598,7 +598,7 @@ def test_serve_cli_metrics_out_missing_dir_fails_fast():
 def test_progress_print_never_measures_its_own_readback_stall(
     monkeypatch, devices,
 ):
-    """The RESULTS §2 fence fix, regression-pinned with an injected
+    """The progress-print fence fix, regression-pinned with an injected
     slow clock: every `jax.device_get` of the JUST-dispatched group's
     metrics advances the fake clock by 10 s (the readback stall of
     fencing in-flight compute). Because the progress print reads the

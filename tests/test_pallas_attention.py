@@ -190,30 +190,20 @@ def test_prime_length_falls_back_to_xla_path():
     )
 
 
-def test_missing_pallas_tpu_engages_dense_fallback(monkeypatch):
-    """ISSUE 16 satellite: on a build where the module-level
-    `pallas.tpu` probe failed (`_VMEM is None`), `flash_attention`
-    degrades to the dense `dot_product_attention` reference — bit-equal
-    output, no call-time RuntimeError (the probe-at-import /
-    fall-back-at-call shape shared with `ops/quant_matmul`)."""
-    from distributed_model_parallel_tpu.ops import pallas_attention as pa
-
+def test_tileable_shapes_trace_the_kernel_not_the_reference():
+    """No quiet reference: at a length the kernels can tile, forward
+    and both backward kernels are `pallas_call`s in the traced step
+    (what chip_smoke.py asserts from the lowered text on the chip); the
+    documented shape rule (no multiple-of-8 divisor) is the only road
+    to the XLA path."""
     q, k, v, mask = _qkv(seed=21, t=64)
-    monkeypatch.setattr(pa, "_VMEM", None)
-    monkeypatch.setattr(pa, "pltpu", None)
-    got = pa.flash_attention(q, k, v, mask, causal=True)
-    want = dot_product_attention(q, k, v, mask, causal=True)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    # Gradients flow through the fallback too (it is the reference
-    # implementation, not a stub).
-    g = jax.grad(
-        lambda k: jnp.sum(pa.flash_attention(q, k, v) ** 2)
-    )(k)
-    gref = jax.grad(
-        lambda k: jnp.sum(dot_product_attention(q, k, v) ** 2)
-    )(k)
-    np.testing.assert_allclose(
-        np.asarray(g), np.asarray(gref), rtol=1e-6, atol=1e-6
+    step = jax.grad(
+        lambda q: jnp.sum(flash_attention(q, k, v, mask, causal=True) ** 2)
+    )
+    assert str(jax.make_jaxpr(step)(q)).count("pallas_call") == 3
+    q17, k17, v17, _ = _qkv(seed=8, t=17)
+    assert "pallas_call" not in str(
+        jax.make_jaxpr(lambda q: flash_attention(q, k17, v17))(q17)
     )
 
 

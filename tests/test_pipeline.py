@@ -173,8 +173,8 @@ def bn_stages(num_classes=4):
 
 @pytest.mark.slow
 def test_pipeline_bn_microbatch_state_and_grads_match_sequential(pp_mesh):
-    """Direct numerical test of pipeline+BN microbatching (VERDICT.md round
-    1, next-round item 7). `slow` (tier-1 budget); tier-1 twins:
+    """Direct numerical test of pipeline+BN microbatching. `slow`
+    (tier-1 budget); tier-1 twins:
     test_stage_local_matches_replicated[bn_stages] (BN stages, same
     mesh) and test_pipeline_schedule.py::
     test_1f1b_bn_running_stats_match_gpipe (the BN microbatch fold).
@@ -264,7 +264,7 @@ def test_pipeline_bn_microbatch_state_and_grads_match_sequential(pp_mesh):
 
 
 # ---------------------------------------------------------------------------
-# Stage-local parameter storage (VERDICT r2 item 5): params / BN state /
+# Stage-local parameter storage: params / BN state /
 # momentum sharded over 'stage' so each device stores ~1/S of the model.
 # ---------------------------------------------------------------------------
 
@@ -405,7 +405,7 @@ def test_stage_local_checkpoint_interop(pp_mesh, tmp_path):
 
 @pytest.mark.parametrize("stage_local", [False, True])
 def test_pipeline_gradients_equal_pure_jax_grad(pp_mesh, stage_local):
-    """The check_vma=False soundness canary (VERDICT r2 item 9).
+    """The check_vma=False soundness canary.
 
     The pipeline backward relies on a hand-reasoned argument: under
     `check_vma=False` the loss is kept LOCAL (no psum before grad) so

@@ -127,7 +127,7 @@ def test_embedding_casts_to_ctx_dtype():
 
 def test_profiler_trace_captured(tmp_path):
     """`TrainerConfig.profile_dir` writes a jax.profiler trace (the
-    SURVEY §5 tracing-subsystem row; VERDICT r2 item 7)."""
+    SURVEY §5 tracing-subsystem row)."""
     from distributed_model_parallel_tpu.data.datasets import synthetic
     from distributed_model_parallel_tpu.data.loader import Loader
     from distributed_model_parallel_tpu.training.trainer import (

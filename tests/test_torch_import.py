@@ -1,4 +1,4 @@
-"""MobileNetV2 torch-checkpoint transplant tests (VERDICT r2 item 8).
+"""MobileNetV2 torch-checkpoint transplant tests.
 
 Ground truth is torch itself: a functional interpreter drives
 `torch.nn.functional` ops straight off the state_dict tensors (no

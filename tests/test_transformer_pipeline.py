@@ -1,6 +1,6 @@
 """Transformer pipelines end-to-end: BERT classification and GPT LM
 through `PipelineEngine` (the wire carries the (hidden, mask) pair), and
-the CLI surface that drives them (VERDICT r4 weak #4).
+the CLI surface that drives them.
 """
 
 import jax
