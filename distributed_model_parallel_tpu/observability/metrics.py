@@ -132,6 +132,23 @@ TRACE_EVENT_NAMES: Dict[str, str] = {
         "serving: one chunked-prefill ingest (prefill_chunk tokens of "
         "one slot's prompt, sharing the iteration with decode)"
     ),
+    # Inside one pass of the paged loop (serving/engine.py _run_paged).
+    "engine_iter": (
+        "serving: one pass of the paged loop (admission, one chunk per "
+        "ingesting slot, one decode step): a decoding user's token gap"
+    ),
+    "admit": "serving: the admission loop (can_hold, reserve, prefix)",
+    "cow": "serving: copy-on-write pass before a decode step",
+    "dispatch": (
+        "serving: uploads + the compiled call, inside decode_step / "
+        "prefill_chunk (host work before the device can start; the "
+        "call blocks while the runtime's launch queue is full)"
+    ),
+    "device_wait": (
+        "serving: host blocked on the device's logits (traced runs only)"
+    ),
+    "logits_fetch": "serving: device->host copy of the logits",
+    "sample": "serving: host sampling of the fetched logits + eviction",
     "queued": "serving request leg: submit -> admission",
     "decode": "serving request leg: first token -> eviction",
     "batch_occupancy": "serving counter: active slots per decode step",

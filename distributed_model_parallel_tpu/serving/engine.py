@@ -1076,6 +1076,14 @@ class ServingEngine:
         active = np.zeros((self.num_slots,), bool)
         # slot -> [prompt, next ingest position, accumulated seconds]
         ingest: dict = {}
+        # Exact slot-step counts over the decode steps (the steps
+        # `step_occupancy` samples), so that with it they sum to
+        # num_slots x decode steps; they leave in `paged_stats`.
+        tally = dict.fromkeys((
+            "slot_steps_ingesting", "slot_steps_page_blocked",
+            "slot_steps_drain_out", "slot_steps_free_other",
+            "admit_page_blocked_iters",
+        ), 0)
 
         def evict(slot):
             sched.finish(slot)
@@ -1084,6 +1092,12 @@ class ServingEngine:
 
         while sched.has_work() or ingest:
             useful = 0
+            # The pass as a decoding user feels it: `engine_iter` with
+            # the queue's state at its start; every stretch inside has
+            # a child span (observability/metrics.py TRACE_EVENT_NAMES).
+            t_iter = tracer.now()
+            n_waiting, n_ingesting = len(sched.waiting), len(ingest)
+            n_decoding = len(sched.active) - n_ingesting
             # ---- admission: free slots AND page headroom -----------
             # The headroom check budgets the WHOLE sequence (prompt +
             # its max_new_tokens growth, capped by the cache) against
@@ -1092,62 +1106,69 @@ class ServingEngine:
             # admitted request can always allocate to completion; a
             # request the pool cannot yet hold WAITS instead of
             # crashing mid-ingest.
-            while sched.can_admit():
-                nxt = sched.waiting[0][1]
-                budget = min(
-                    int(nxt.prompt.size) + int(nxt.max_new_tokens),
-                    self.max_len,
-                )
-                if not host.can_hold(budget):
-                    break
-                seq = sched.admit()
-                host.reserve(seq.slot, budget)
-                prompt = seq.request.prompt
-                covered = host.attach_prefix(seq.slot, prompt)
-                if mx.enabled and host.prefix is not None:
-                    mx.inc(
-                        "serve_prefix_hits_total", 1 if covered else 0
+            page_blocked = False
+            with tracer.span("admit"):
+                while sched.can_admit():
+                    nxt = sched.waiting[0][1]
+                    budget = min(
+                        int(nxt.prompt.size) + int(nxt.max_new_tokens),
+                        self.max_len,
                     )
-                if not chunked:
-                    # Monolithic paged prefill: the padded one-compile
-                    # prompt ingest, landing in pages.
-                    host.ensure_pages(seq.slot, int(prompt.size))
-                    ids, length = self.pad_prompt(prompt)
-                    t0 = tracer.now()
-                    with tracer.span(
-                        "prefill", rid=repr(seq.request.rid),
-                        slot=seq.slot,
-                    ):
-                        cache, nl = self.prefill(
-                            params, cache,
-                            host.device_row(seq.slot), ids, length,
+                    if not host.can_hold(budget):
+                        page_blocked = True
+                        tally["admit_page_blocked_iters"] += 1
+                        break
+                    seq = sched.admit()
+                    host.reserve(seq.slot, budget)
+                    prompt = seq.request.prompt
+                    covered = host.attach_prefix(seq.slot, prompt)
+                    if mx.enabled and host.prefix is not None:
+                        mx.inc(
+                            "serve_prefix_hits_total",
+                            1 if covered else 0,
                         )
-                        tok = self._pick(sampler, nl, seq.slot)
-                    seq.t_first_token = tracer.now()
-                    sched.record_iteration(1)
-                    if mx.enabled:
-                        mx.observe(
-                            "serve_prefill_s", seq.t_first_token - t0
-                        )
-                        mx.inc("serve_tokens_total", 1)
-                    seq.generated.append(tok)
-                    tokens[seq.slot] = tok
-                    positions[seq.slot] = prompt.size
-                    active[seq.slot] = True
-                    if seq.done(self.max_len):
-                        evict(seq.slot)
-                elif covered >= prompt.size - 1:
-                    # Full prefix hit: every needed position is cached
-                    # — SKIP prefill entirely and decode the last
-                    # prompt token at its own position. Its write page
-                    # copies first if shared (copy-on-write), via the
-                    # pre-decode ensure_writable pass every active
-                    # slot goes through below.
-                    positions[seq.slot] = prompt.size - 1
-                    tokens[seq.slot] = int(prompt[-1])
-                    active[seq.slot] = True
-                else:
-                    ingest[seq.slot] = [prompt, covered, 0.0]
+                    if not chunked:
+                        # Monolithic paged prefill: the padded
+                        # one-compile prompt ingest, landing in pages.
+                        host.ensure_pages(seq.slot, int(prompt.size))
+                        ids, length = self.pad_prompt(prompt)
+                        t0 = tracer.now()
+                        with tracer.span(
+                            "prefill", rid=repr(seq.request.rid),
+                            slot=seq.slot,
+                        ):
+                            cache, nl = self.prefill(
+                                params, cache,
+                                host.device_row(seq.slot), ids, length,
+                            )
+                            tok = self._pick(sampler, nl, seq.slot)
+                        seq.t_first_token = tracer.now()
+                        sched.record_iteration(1)
+                        if mx.enabled:
+                            mx.observe(
+                                "serve_prefill_s",
+                                seq.t_first_token - t0,
+                            )
+                            mx.inc("serve_tokens_total", 1)
+                        seq.generated.append(tok)
+                        tokens[seq.slot] = tok
+                        positions[seq.slot] = prompt.size
+                        active[seq.slot] = True
+                        if seq.done(self.max_len):
+                            evict(seq.slot)
+                    elif covered >= prompt.size - 1:
+                        # Full prefix hit: every needed position is
+                        # cached — SKIP prefill entirely and decode the
+                        # last prompt token at its own position. Its
+                        # write page copies first if shared
+                        # (copy-on-write), via the pre-decode
+                        # ensure_writable pass every active slot goes
+                        # through below.
+                        positions[seq.slot] = prompt.size - 1
+                        tokens[seq.slot] = int(prompt[-1])
+                        active[seq.slot] = True
+                    else:
+                        ingest[seq.slot] = [prompt, covered, 0.0]
             # ---- ingestion: one chunk per ingesting slot -----------
             for slot in sorted(ingest):
                 prompt, start, acc = ingest[slot]
@@ -1161,14 +1182,24 @@ class ServingEngine:
                     "prefill_chunk", rid=repr(seq.request.rid),
                     slot=slot, start=start,
                 ):
-                    cache, nl = self.chunk_prefill(
-                        params, cache, host.device_row(slot),
-                        jnp.asarray(ids), jnp.int32(start),
-                        jnp.int32(n),
-                    )
+                    with tracer.span("dispatch"):
+                        cache, nl = self.chunk_prefill(
+                            params, cache, host.device_row(slot),
+                            jnp.asarray(ids), jnp.int32(start),
+                            jnp.int32(n),
+                        )
                     done_ingest = start + n >= prompt.size
                     if done_ingest:
-                        tok = self._pick(sampler, nl, slot)
+                        # The wait also holds the unfetched chunks
+                        # dispatched before this one. Tracing off, the
+                        # fetch alone blocks, as it always did.
+                        if tracer.enabled:
+                            with tracer.span("device_wait"):
+                                jax.block_until_ready(nl)
+                        with tracer.span("logits_fetch"):
+                            row = np.asarray(nl)
+                        with tracer.span("sample"):
+                            tok = self._pick(sampler, row, slot)
                 dt = tracer.now() - t0
                 useful += 1
                 if done_ingest:
@@ -1190,41 +1221,60 @@ class ServingEngine:
             # ---- one decode step for the active set ----------------
             n_active = int(active.sum())
             if n_active:
-                for slot in np.nonzero(active)[0]:
-                    cache = host.ensure_writable(
-                        cache, int(slot), int(positions[slot])
-                    )
+                with tracer.span("cow"):
+                    for slot in np.nonzero(active)[0]:
+                        cache = host.ensure_writable(
+                            cache, int(slot), int(positions[slot])
+                        )
                 t0 = tracer.now()
                 with tracer.span("decode_step", active=n_active):
-                    cache, logits = self.decode_step(
-                        params, cache, host.device_table(),
-                        jnp.asarray(positions), jnp.asarray(tokens),
-                        jnp.asarray(active),
-                    )
-                    logits_np = np.asarray(logits)
+                    with tracer.span("dispatch"):
+                        cache, logits = self.decode_step(
+                            params, cache, host.device_table(),
+                            jnp.asarray(positions), jnp.asarray(tokens),
+                            jnp.asarray(active),
+                        )
+                    if tracer.enabled:
+                        with tracer.span("device_wait"):
+                            jax.block_until_ready(logits)
+                    with tracer.span("logits_fetch"):
+                        logits_np = np.asarray(logits)
                 dt = tracer.now() - t0
                 sched.record_decode_step(n_active)
+                # Where this step's other slot-steps went: every slot
+                # is decoding (step_occupancy), ingesting, or free, and
+                # a free slot is charged to the one reason it is free.
+                free = self.num_slots - len(sched.active)
+                tally["slot_steps_ingesting"] += len(ingest)
+                if page_blocked:
+                    tally["slot_steps_page_blocked"] += free
+                elif not sched.waiting:
+                    tally["slot_steps_drain_out"] += free
+                else:
+                    # freed after this pass's admission had run
+                    tally["slot_steps_free_other"] += free
                 tracer.counter("batch_occupancy", n_active)
                 if mx.enabled:
                     mx.observe("serve_decode_step_s", dt)
                 useful += n_active
-                for slot, seq in list(sched.active.items()):
-                    if slot in ingest or not active[slot]:
-                        continue
-                    tok = self._pick(sampler, logits_np[slot], slot)
-                    first = not seq.generated
-                    if first:
-                        # A full prefix hit's first token arrives from
-                        # this decode step — its whole "prefill" was
-                        # the cache lookup.
-                        seq.t_first_token = tracer.now()
-                    else:
-                        seq.token_times.append(dt)
-                    seq.generated.append(tok)
-                    tokens[slot] = tok
-                    positions[slot] += 1
-                    if seq.done(self.max_len):
-                        evict(slot)
+                with tracer.span("sample"):
+                    for slot, seq in list(sched.active.items()):
+                        if slot in ingest or not active[slot]:
+                            continue
+                        tok = self._pick(sampler, logits_np[slot], slot)
+                        first = not seq.generated
+                        if first:
+                            # A full prefix hit's first token arrives
+                            # from this decode step — its whole
+                            # "prefill" was the cache lookup.
+                            seq.t_first_token = tracer.now()
+                        else:
+                            seq.token_times.append(dt)
+                        seq.generated.append(tok)
+                        tokens[slot] = tok
+                        positions[slot] += 1
+                        if seq.done(self.max_len):
+                            evict(slot)
             if mx.enabled:
                 mx.gauge(
                     "serve_kv_pages_in_use", host.pool.pages_in_use
@@ -1239,6 +1289,12 @@ class ServingEngine:
                     f"{self.paged_spec.page_size}) — size the pool "
                     "larger (num_pages / --kv-pages)"
                 )
+            if tracer.enabled:
+                tracer.complete(
+                    "engine_iter", t_iter, tracer.now(),
+                    waiting=n_waiting, ingesting=n_ingesting,
+                    active=n_decoding,
+                )
         sched.paged_stats = {
             "page_size": self.paged_spec.page_size,
             "num_pages": self.paged_spec.num_pages,
@@ -1250,6 +1306,7 @@ class ServingEngine:
                 self.num_slots * self._slot_stripe_bytes
             ),
             "cow_copies": host.cow_copies,
+            **tally,
         }
         if host.prefix is not None:
             total_prompt = sum(

@@ -75,14 +75,39 @@ def build_golden_obs_trace() -> trace.Tracer:
     t.complete(
         "ckpt_background_write", clock.t, clock.t + 0.006, tid=1,
     )
+    # One pass of the paged loop as serving/engine.py::_run_paged
+    # records it (the two 2 ms ticks it always had, split among the
+    # children), with the speculative pair riding in the same pass so
+    # the wall and the residual stay what they were.
+    t_iter = clock.t
+    with t.span("admit"):
+        clock.tick(0.00025)
     with t.span("prefill_chunk", slot=0, start=0):
-        clock.tick(0.002)
+        with t.span("dispatch"):
+            clock.tick(0.00025)
+        with t.span("device_wait"):
+            clock.tick(0.00075)
+        with t.span("logits_fetch"):
+            clock.tick(0.00025)
+        with t.span("sample"):
+            clock.tick(0.00025)
+    with t.span("cow"):
+        clock.tick(0.00025)
     with t.span("decode_step", active=2):
-        clock.tick(0.002)
+        with t.span("dispatch"):
+            clock.tick(0.00025)
+        with t.span("device_wait"):
+            clock.tick(0.001)
+        with t.span("logits_fetch"):
+            clock.tick(0.00025)
+    with t.span("sample"):
+        clock.tick(0.0005)
     with t.span("draft_round", active=2, k=2):
         clock.tick(0.002)
     with t.span("verify_step", active=2):
         clock.tick(0.002)
+    t.complete("engine_iter", t_iter, clock.t,
+               waiting=0, ingesting=1, active=2)
     t.counter("batch_occupancy", 2)
     tid = t.track_id("request 'r0'")
     t.complete("queued", 0.0, 0.004, tid=tid)
