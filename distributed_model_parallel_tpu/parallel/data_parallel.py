@@ -72,7 +72,8 @@ from distributed_model_parallel_tpu.runtime.mesh import (
 )
 from distributed_model_parallel_tpu.training.metrics import (
     cross_entropy,
-    topk_correct,
+    label_rank,
+    rank_correct,
     valid_count,
 )
 from distributed_model_parallel_tpu.training.optim import SGD, SGDState
@@ -149,10 +150,11 @@ def _metrics(loss, logits, labels):
     # Loader's static-shape padding of a ragged final val batch) are
     # excluded from every numerator and denominator.
     n = valid_count(labels)
+    rank = label_rank(logits, labels)
     return {
         "loss_sum": loss * n,
-        "correct1": topk_correct(logits, labels, 1),
-        "correct5": topk_correct(logits, labels, 5),
+        "correct1": rank_correct(rank, labels, 1),
+        "correct5": rank_correct(rank, labels, 5),
         "count": n,
     }
 

@@ -91,7 +91,8 @@ from distributed_model_parallel_tpu.parallel.data_parallel import (
 )
 from distributed_model_parallel_tpu.training.metrics import (
     cross_entropy,
-    topk_correct,
+    label_rank,
+    rank_correct,
     valid_count,
 )
 from distributed_model_parallel_tpu.training.optim import SGD
@@ -1416,13 +1417,14 @@ class PipelineEngine:
             return tuple(out)
 
         def metrics_from(logits, labels, loss_sum, is_last):
+            rank = label_rank(logits, labels)
             m = {
                 "loss_sum": lax.psum(loss_sum, "stage"),
                 "correct1": lax.psum(
-                    topk_correct(logits, labels, 1) * is_last, "stage"
+                    rank_correct(rank, labels, 1) * is_last, "stage"
                 ),
                 "correct5": lax.psum(
-                    topk_correct(logits, labels, 5) * is_last, "stage"
+                    rank_correct(rank, labels, 5) * is_last, "stage"
                 ),
                 "count": valid_count(labels),
             }

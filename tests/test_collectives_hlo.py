@@ -13,6 +13,7 @@ text-level pins import `collective_counts`/`has_op_with_result`/
 same conservative reachability). tests/test_hlolint.py lints the full
 engine matrix through the same library's rule registry."""
 
+import re
 from functools import partial
 
 import jax
@@ -643,3 +644,57 @@ def test_sp_ulysses_step_contains_all_to_all():
     ids, lb = eng.shard_batch(ids, lb)
     hlo = _hlo(eng, ts, ids, lb, jnp.float32(0.1))
     assert "all-to-all" in hlo
+
+
+# ------------------------------------------------ the step's metrics
+# `_metrics` counts top-1 / top-5 hits from the label's rank
+# (`training/metrics.py label_rank`): one compare-and-count pass. A
+# `lax.top_k` there lowers on the v5e to a sort of the whole vocabulary
+# for every row (83 % of GPT-2 small's step, PERF.md PR 26); numerics
+# tests cannot see it come back, so the lowered step is pinned.
+
+_SORT_OPS = re.compile(
+    r"\b(?:stablehlo|mhlo|chlo)\.(?:sort|top_k|topk)\b|TopK"
+)
+
+
+def test_sort_op_pattern_sees_a_top_k():
+    text = jax.jit(lambda x: jax.lax.top_k(x, 5)).lower(
+        jnp.ones((4, 61))
+    ).as_text()
+    assert _SORT_OPS.search(text)
+    text = jax.jit(lambda x: jnp.sort(x, axis=-1)).lower(
+        jnp.ones((4, 61))
+    ).as_text()
+    assert _SORT_OPS.search(text)
+
+
+@pytest.mark.parametrize("build", ["sp_lm", "fsdp_plan"])
+def test_lm_train_step_lowers_without_sort_or_top_k(build):
+    from distributed_model_parallel_tpu.models.gpt import GPTConfig
+
+    cfg = GPTConfig(vocab_size=61, dim=32, num_layers=2, num_heads=4,
+                    ffn_dim=64, max_position=16, dropout_rate=0.0)
+    if build == "sp_lm":
+        from distributed_model_parallel_tpu.parallel.sequence_parallel import (
+            CausalLMSequenceParallelEngine,
+        )
+
+        eng = CausalLMSequenceParallelEngine(
+            cfg, SGD(), make_mesh(MeshSpec(data=2, seq=4)), donate=False
+        )
+    else:
+        from distributed_model_parallel_tpu.parallel.plan import (
+            build_plan_engine,
+        )
+
+        eng = build_plan_engine(cfg, SGD(), "fsdp4", donate=False)
+    ts = eng.init_state(jax.random.PRNGKey(0))
+    ids = np.random.RandomState(0).randint(
+        1, cfg.vocab_size, size=(8, 16)
+    ).astype(np.int32)
+    ids_s, tg_s = eng.shard_batch(ids)
+    text = eng.train_step.lower(ts, ids_s, tg_s, jnp.float32(0.1)).as_text()
+    found = _SORT_OPS.search(text)
+    assert found is None, found.group(0)
+    assert "compare" in text     # the rank's pass is there
