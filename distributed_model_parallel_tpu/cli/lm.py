@@ -21,6 +21,9 @@ interpretable.
       --attention ring --dtype bfloat16
   python -m distributed_model_parallel_tpu.cli.lm --moe-experts 8 \
       --moe-dispatch hierarchical --moe-overlap --dcn-slices 2
+  python -m distributed_model_parallel_tpu.cli.lm --model-config \
+      kimi-linear.json --seq-len 8192 -b 2 --attention ring_flash \
+      --dtype bfloat16 --remat
 """
 
 from __future__ import annotations
@@ -67,6 +70,15 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--heads", default=4, type=int)
     p.add_argument("--ffn-dim", default=None, type=int,
                    help="default 4*dim")
+    p.add_argument("--model-config", default=None, metavar="FILE",
+                   help="build the model family named by the file's "
+                        "`model_type` from the source's own keys (a "
+                        "release's config.json; `experts_held: [first, "
+                        "past_last]` beside them for a chip that holds "
+                        "a range of the experts) in place of the GPT "
+                        "shape flags; trains "
+                        "under the sequence-parallel engine with "
+                        "--seq-shards 1")
     p.add_argument("--seq-len", default=256, type=int)
     p.add_argument("--dropout", default=0.0, type=float)
     p.add_argument("-b", "--batch-size", default=32, type=int)
@@ -182,8 +194,75 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
+# `model_type` of a --model-config file -> its configuration's builder.
+def _model_families() -> dict:
+    from distributed_model_parallel_tpu.models import kimi_linear
+
+    return {kimi_linear.MODEL_TYPE: kimi_linear.config_from_dict}
+
+
+# What --model-config replaces or cannot be combined with, by flag.
+_MODEL_CONFIG_SHAPE_FLAGS = (
+    ("--vocab-size", "vocab_size"), ("--dim", "dim"),
+    ("--layers", "layers"), ("--heads", "heads"),
+    ("--ffn-dim", "ffn_dim"), ("--dropout", "dropout"),
+    ("--moe-experts", "moe_experts"), ("--moe-every", "moe_every"),
+    ("--moe-dispatch", "moe_dispatch"), ("--moe-overlap", "moe_overlap"),
+    ("--expert-shards", "expert_shards"),
+)
+
+
+def _model_config(args, parser):
+    """The configuration `--model-config FILE` names, after the guards:
+    the file carries the shape, so the GPT shape flags and the GPT MoE
+    flags beside it are refused by name, and so is every engine that
+    has no path for the family yet."""
+    import json
+
+    for flag, dest in _MODEL_CONFIG_SHAPE_FLAGS:
+        if getattr(args, dest) != parser.get_default(dest):
+            raise SystemExit(
+                f"{flag} shapes the GPT family; --model-config "
+                f"{args.model_config} carries the model's shape — drop "
+                "the flag"
+            )
+    with open(args.model_config) as f:
+        described = json.load(f)
+    families = _model_families()
+    model_type = described.get("model_type")
+    if model_type not in families:
+        raise SystemExit(
+            f"--model-config {args.model_config}: model_type "
+            f"{model_type!r} is not built (have: "
+            f"{', '.join(sorted(families))})"
+        )
+    try:
+        cfg = families[model_type](described)
+    except (KeyError, NotImplementedError, ValueError) as e:
+        raise SystemExit(f"--model-config {args.model_config}: {e}") from e
+    missing = cfg.lm_family().seq_shards_missing
+    for flag, on, why in (
+        ("--seq-shards", args.seq_shards > 1, missing),
+        ("--plan", bool(args.plan),
+         "build_plan_engine composes GPT stages; " + (missing or "")),
+        ("--pipeline-stages", args.pipeline_stages > 1,
+         "models/gpt.split_stages cuts a GPT stack; this family's mixed "
+         "layer pattern has no stage split"),
+        ("--grad-reduction overlapped",
+         args.grad_reduction == "overlapped",
+         "the stagewise backward carries no block state"),
+    ):
+        if on and why:
+            raise SystemExit(
+                f"--model-config {args.model_config} ({model_type}) does "
+                f"not compose with {flag}: {why}"
+            )
+    return cfg
+
+
 def main(argv=None) -> dict:
-    args = build_parser().parse_args(argv)
+    parser = build_parser()
+    args = parser.parse_args(argv)
     enable_compile_cache()
     from distributed_model_parallel_tpu.cli.common import (
         setup_metrics_out,
@@ -200,6 +279,12 @@ def main(argv=None) -> dict:
         )
 
         auto_tune_lm(args)
+    model_cfg = None
+    if args.model_config:
+        # AFTER the tuner (it may write --plan / --seq-shards onto args)
+        # and before every other guard: the conflict named is this one.
+        model_cfg = _model_config(args, parser)
+        args.vocab_size = model_cfg.vocab_size  # the corpus draws from it
     plan = None
     if args.plan:
         from distributed_model_parallel_tpu.parallel.plan import (
@@ -507,7 +592,7 @@ def main(argv=None) -> dict:
             f"--seq-len {args.seq_len} not divisible by --seq-shards "
             f"{args.seq_shards}"
         )
-    cfg = GPTConfig(
+    cfg = model_cfg or GPTConfig(
         vocab_size=args.vocab_size,
         dim=args.dim,
         num_layers=args.layers,
@@ -572,16 +657,20 @@ def main(argv=None) -> dict:
             compute_dtype=compute_dtype_from_flag(args.dtype),
         )
     else:
-        engine = CausalLMSequenceParallelEngine(
-            cfg, build_optimizer(args), mesh, attention=args.attention,
-            compute_dtype=compute_dtype_from_flag(args.dtype),
-            remat=args.remat,
-            collective_matmul=args.collective_matmul,
-            grad_reduction=args.grad_reduction,
-            bucket_mb=args.bucket_mb,
-            overlap_stages=args.overlap_stages,
-            dcn_compression=args.dcn_compression,
-        )
+        try:
+            engine = CausalLMSequenceParallelEngine(
+                cfg, build_optimizer(args), mesh,
+                attention=args.attention,
+                compute_dtype=compute_dtype_from_flag(args.dtype),
+                remat=args.remat,
+                collective_matmul=args.collective_matmul,
+                grad_reduction=args.grad_reduction,
+                bucket_mb=args.bucket_mb,
+                overlap_stages=args.overlap_stages,
+                dcn_compression=args.dcn_compression,
+            )
+        except NotImplementedError as e:
+            raise SystemExit(str(e)) from e
     corpus = synthetic_corpus(
         args.vocab_size, args.corpus_tokens, seed=args.corpus_seed
     )
@@ -614,18 +703,12 @@ def main(argv=None) -> dict:
         async_save=args.async_save,
         # Recorded in the checkpoint sidecar/manifest so `cli/serve.py
         # --checkpoint` can fail fast, naming the exact field, when the
-        # serve flags disagree with the trained architecture.
-        checkpoint_extra={"gpt_config": {
-            "vocab_size": cfg.vocab_size,
-            "dim": cfg.dim,
-            "num_layers": cfg.num_layers,
-            "num_heads": cfg.num_heads,
-            "ffn_dim": cfg.ffn_dim,
-            "max_position": cfg.max_position,
-            # serve --checkpoint refuses MoE checkpoints by this field
-            # (the serving engine builds dense blocks).
-            "num_experts": cfg.num_experts,
-        }},
+        # serve flags disagree with the trained architecture: the
+        # family's own record (`gpt_config` for GPT, by whose
+        # `num_experts` serve refuses MoE checkpoints; `lm_family` with
+        # the configuration's shape for a --model-config family, which
+        # serve refuses by that field).
+        checkpoint_extra=cfg.lm_family().checkpoint_extra,
     )
     trainer = Trainer(engine, train, val, tcfg, rng=jax.random.PRNGKey(0))
     out = trainer.fit()

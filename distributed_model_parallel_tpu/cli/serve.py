@@ -267,6 +267,15 @@ def _checkpoint_guard(directory: str, name: str, cfg) -> None:
         meta = checkpoint_metadata(directory, name)
     except FileNotFoundError as e:
         raise SystemExit(str(e))
+    family = meta.get("lm_family")
+    if family:
+        raise SystemExit(
+            f"--checkpoint {directory}: the checkpoint records "
+            f"lm_family.model_type={family.get('model_type')!r} "
+            "(cli.lm --model-config); the serving engine builds GPT "
+            "decoder blocks over a paged K/V cache and has neither the "
+            "recurrent nor the latent cache that family needs"
+        )
     recorded = meta.get("gpt_config")
     if not recorded:
         return

@@ -65,6 +65,59 @@ class GPTConfig:
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
 
+    def lm_family(self):
+        """What `CausalLMSequenceParallelEngine` needs of this family
+        (`models/lm_family.py`)."""
+        from distributed_model_parallel_tpu.models.lm_family import (
+            LMFamily,
+        )
+
+        drop = L.dropout(self.dropout_rate)
+
+        def stem(params, ids, ctx, seq_index):
+            # The position slice starts at this 'seq' shard's global
+            # offset (the dense stem would give shards 1..N-1 local
+            # offsets).
+            tl = ids.shape[1]
+            pos = jax.lax.dynamic_slice_in_dim(
+                params["position"], seq_index * tl, tl, axis=0
+            )
+            return stem_apply(params, ids, self, drop, ctx, positions=pos)
+
+        return LMFamily(
+            name="gpt",
+            num_layers=self.num_layers,
+            ffn_dim=self.ffn_dim,
+            max_position=self.max_position,
+            model=partial(gpt_lm, self),
+            blocks=partial(decoder_blocks, self),
+            stem=stem,
+            head=head_apply,
+            targets=partial(lm_targets, pad_token_id=self.pad_token_id),
+            # Per-shard routing under 'seq' sharding breaks the dense
+            # capacity semantics and the moe_aux leaves never reach the
+            # differentiated loss.
+            refused=(
+                "GPTConfig.num_experts > 0 is not supported by "
+                "CausalLMSequenceParallelEngine; train MoE LMs with "
+                "parallel/expert_parallel.ExpertParallelLMEngine "
+                "(cli/lm.py --moe-experts)."
+                if self.num_experts > 0 else None
+            ),
+            # `cli/serve.py --checkpoint` fails fast on these fields
+            # when the serve flags disagree with what was trained (it
+            # refuses MoE checkpoints by `num_experts`).
+            checkpoint_extra={"gpt_config": {
+                "vocab_size": self.vocab_size,
+                "dim": self.dim,
+                "num_layers": self.num_layers,
+                "num_heads": self.num_heads,
+                "ffn_dim": self.ffn_dim,
+                "max_position": self.max_position,
+                "num_experts": self.num_experts,
+            }},
+        )
+
 
 def stem_apply(params, ids, cfg: GPTConfig, drop: L.Layer, ctx, *,
                positions=None):

@@ -257,3 +257,184 @@ def moe_encoder_layer(
         return (h, mask), {"moe": moe_state}
 
     return L.Layer(init, apply)
+
+
+# ---------------------------------------------------------------------
+# A sparse expert layer that holds a range of the experts and drops
+# nothing (the expert-parallel share of one chip).
+
+# The step's counters and how each combines over layers, shards and
+# steps (what `LMFamily.counter_reductions` hands the engine).
+COUNTERS = {
+    "moe_picks_held": "sum",       # picks that landed on a held expert
+    "moe_expert_rows_max": "max",  # the fullest held expert's rows
+    "moe_picks_dropped": "sum",    # held picks outside their expert's rows
+}
+
+
+def gated_mlp(w, x):
+    """SiLU-gated MLP: `w["w_in"]` (D, 2F) holds the gate's columns and
+    then the up projection's, `w["w_out"]` is (F, D). Weights are cast
+    to x's dtype per use."""
+    gate, up = jnp.split(x @ w["w_in"].astype(x.dtype), 2, axis=-1)
+    return (jax.nn.silu(gate) * up) @ w["w_out"].astype(x.dtype)
+
+
+def route(scores_in, router_w, bias, top_k: int, scale: float):
+    """Sigmoid router over ALL experts -> (expert ids (N, k), weights
+    (N, k) float32). The k experts are chosen by score + bias (the
+    correction bias steers the choice alone); the weights are the
+    chosen scores renormalised to sum 1, times `scale`. Float32 at
+    `highest`: on the TPU the default rounds both operands to bfloat16
+    first, which reorders near-ties among 256 scores."""
+    scores = jax.nn.sigmoid(jnp.matmul(
+        scores_in.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    ))
+    _, ids = jax.lax.top_k(scores + bias, top_k)
+    picked = jnp.take_along_axis(scores, ids, axis=-1)
+    return ids, scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
+
+
+def held_experts_feed_forward(
+    dim: int,
+    hidden_dim: int,
+    num_experts: int,
+    experts_held: tuple,
+    *,
+    top_k: int,
+    shared_hidden_dim: int,
+    routed_scale: float = 1.0,
+    init_scale: float = 0.02,
+) -> L.Layer:
+    """One shared expert plus `num_experts` routed ones of which this
+    chip holds the ids `experts_held = (first, past_last)`: it routes
+    over all of them, computes its own experts' part of the result and
+    drops no pick. A pick of an absent expert adds nothing (its weight
+    still counts in the renormalisation: the chips that hold it add
+    that part); with every expert held this is the whole layer.
+
+    No capacity (`held_experts_part`). Params: `router.w`
+    (D, num_experts), `experts.w_in` (held, D, 2F) gate then up,
+    `experts.w_out` (held, F, D), `shared` a `gated_mlp`. State:
+    `router_bias` (num_experts,), a buffer no gradient reaches and
+    nothing updates, and the step's counters (`COUNTERS`), which the
+    LM engine adds to its metrics.
+    """
+    first, past = experts_held
+    held = past - first
+    if not (0 <= first < past <= num_experts):
+        raise ValueError(
+            f"experts_held {experts_held} is no range of the "
+            f"{num_experts} experts"
+        )
+    if not 1 <= top_k <= num_experts:
+        raise ValueError(
+            f"top_k {top_k} must be in [1, num_experts {num_experts}]"
+        )
+
+    def init(key):
+        kr, ki, ko, ksi, kso = jax.random.split(key, 5)
+        normal = lambda k, shape: init_scale * jax.random.normal(k, shape)
+        params = {
+            "router": {"w": normal(kr, (dim, num_experts))},
+            "experts": {
+                "w_in": normal(ki, (held, dim, 2 * hidden_dim)),
+                "w_out": normal(ko, (held, hidden_dim, dim)),
+            },
+            "shared": {
+                "w_in": normal(ksi, (dim, 2 * shared_hidden_dim)),
+                "w_out": normal(kso, (shared_hidden_dim, dim)),
+            },
+        }
+        state = {"router_bias": jnp.zeros((num_experts,), jnp.float32)}
+        state.update({c: jnp.zeros((), jnp.float32) for c in COUNTERS})
+        return params, state
+
+    def apply(params, state, x, ctx):
+        h, mask = x
+        b, t, d = h.shape
+        flat = h.reshape(b * t, d)
+        ids, weights = route(
+            flat, params["router"]["w"],
+            jax.lax.stop_gradient(state["router_bias"]), top_k,
+            routed_scale,
+        )
+        routed, sizes, n_held, n_placed = held_experts_part(
+            params["experts"], flat, ids, weights, first
+        )
+        out = routed + gated_mlp(params["shared"], flat)
+        counters = {
+            "moe_picks_held": n_held,
+            "moe_expert_rows_max": jnp.max(sizes).astype(jnp.float32),
+            "moe_picks_dropped": n_held - n_placed,
+        }
+        return (out.reshape(b, t, d), mask), {
+            "router_bias": state["router_bias"], **counters
+        }
+
+    return L.Layer(init, apply)
+
+
+def held_experts_part(w, flat, ids, weights, first):
+    """The part of the routed result that the experts `first ..
+    first + held - 1` give, `held = w["w_in"].shape[0]`: flat (N, D),
+    ids and weights (N, k) from `route` -> (part (N, D), rows of each
+    held expert (held,), picks that landed on a held expert, those of
+    them whose row in the sorted buffer lies among its expert's rows:
+    where the sort put it, not what the mask says, so a buffer that ran
+    out or a sort that misplaced a pick would show). `first` may be
+    traced (the test that adds up all the shares maps over it).
+
+    Picks are sorted by expert, absent ones behind every held expert's
+    rows, into a buffer of N * k rows — the worst case, since a smaller
+    one would drop picks — and multiplied group by group at the cost of
+    the live rows alone."""
+    from distributed_model_parallel_tpu.ops.grouped_matmul import (
+        grouped_matmul,
+        sort_rows,
+        unsort_rows,
+    )
+
+    held, top_k = w["w_in"].shape[0], ids.shape[-1]
+    is_held = (ids >= first) & (ids < first + held)
+    group = jnp.where(is_held, ids - first, held).reshape(-1)
+    order = jnp.argsort(group, stable=True)
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype),
+        unique_indices=True,
+    )
+    sizes = jnp.sum(
+        jax.nn.one_hot(group, held + 1, dtype=jnp.int32), axis=0
+    )[:held]
+    rows = sort_rows(flat, order, inverse, top_k)
+    gate, up = jnp.split(
+        grouped_matmul(rows, w["w_in"], sizes), 2, axis=-1
+    )
+    rows = grouped_matmul(jax.nn.silu(gate) * up, w["w_out"], sizes)
+    picks = unsort_rows(rows, order, inverse).reshape(
+        flat.shape[0], top_k, flat.shape[1]
+    )
+    part = jnp.sum(
+        picks.astype(jnp.float32)
+        * jnp.where(is_held, weights, 0.0)[..., None],
+        axis=1,
+    ).astype(flat.dtype)
+    return (part, sizes, jnp.sum(is_held).astype(jnp.float32),
+            picks_placed(group, inverse, sizes))
+
+
+def picks_placed(group, inverse, sizes):
+    """How many picks of a held expert have their row among that
+    expert's rows of the sorted buffer: group (M,) the held expert of
+    each pick (`len(sizes)` for an absent one), inverse (M,) the row
+    each pick was sorted to, sizes (held,). Expert e's rows are
+    [ends[e] - sizes[e], ends[e]): what the grouped product multiplies
+    by e's weights."""
+    held = sizes.shape[0]
+    ends = jnp.cumsum(sizes)
+    own = jnp.minimum(group, held - 1)
+    placed = (group < held) & (inverse >= (ends - sizes)[own]) & (
+        inverse < ends[own]
+    )
+    return jnp.sum(placed).astype(jnp.float32)

@@ -79,6 +79,22 @@ METRIC_NAMES: Dict[str, str] = {
         "async_save)"
     ),
     "train_batches_total": "batches dispatched (counter)",
+    # The sparse expert layer's step counters (models/moe.COUNTERS),
+    # set by the Trainer at the end of an epoch to the epoch's value
+    # (gauges; the Trainer takes the names from the engine's metrics).
+    "moe_picks_held": (
+        "router picks that landed on an expert this chip holds, summed "
+        "over layers and the epoch's steps"
+    ),
+    "moe_expert_rows_max": (
+        "rows of the fullest held expert in any layer of any step of "
+        "the epoch"
+    ),
+    "moe_picks_dropped": (
+        "held picks whose row in the sorted buffer lies outside its "
+        "expert's rows, read from the permutation: 0 while there is no "
+        "capacity and the sort is right"
+    ),
     # Serving (serving/scheduler.py + engine.py).
     "serve_queued_s": "per request: submit -> admission",
     "serve_ttft_s": "per request: submit -> first token (TTFT)",

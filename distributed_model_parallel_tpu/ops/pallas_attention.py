@@ -27,7 +27,8 @@ TPU layout notes (Mosaic requires a block's last two dims to be
 Contract and scope:
 * Same contract as `dot_product_attention`: (B, T, H, Dh) tensors,
   optional (B, Tkv) key-validity mask, `causal=True` for decoder models,
-  computes f32, returns q.dtype.
+  computes f32, returns q.dtype. v (and so the output) may have another
+  width than q.k (latent attention: 192 / 128).
 * Sequence lengths must divide the block sizes (the wrapper shrinks
   blocks to fit when the sequence is shorter); lengths with no
   multiple-of-8 divisor >= 8 fall back to the XLA path — forward and
@@ -216,7 +217,7 @@ def _flash_forward(q, k, v, mask, scale, block_q, block_k, interpret,
     (B, H, Tq, 128), or None unless `need_lse` (the vjp forward) — or
     (xla_out, None) on the small-block fallback."""
     b, tq, h, dh = q.shape
-    tk = k.shape[1]
+    tk, dv = k.shape[1], v.shape[-1]
     blocks = _blocks_viable(tq, tk, block_q, block_k)
     if blocks is None:
         return dot_product_attention(
@@ -232,8 +233,9 @@ def _flash_forward(q, k, v, mask, scale, block_q, block_k, interpret,
 
     qspec = pl.BlockSpec((1, 1, bq, dh), lambda bi, hi, qi, ki: (bi, hi, qi, 0))
     kspec = pl.BlockSpec((1, 1, bk, dh), lambda bi, hi, qi, ki: (bi, hi, ki, 0))
+    vspec = pl.BlockSpec((1, 1, bk, dv), lambda bi, hi, qi, ki: (bi, hi, ki, 0))
     operands = [qt, kt, vt]
-    in_specs = [qspec, kspec, kspec]
+    in_specs = [qspec, kspec, vspec]
     if mask is not None:
         operands.append(mask.astype(jnp.int8)[:, None, :])
         in_specs.append(_whole_mask_spec(tk))
@@ -242,9 +244,9 @@ def _flash_forward(q, k, v, mask, scale, block_q, block_k, interpret,
         has_mask=mask is not None, with_lse=need_lse,
     )
     out_specs = [
-        pl.BlockSpec((1, 1, bq, dh), lambda bi, hi, qi, ki: (bi, hi, qi, 0))
+        pl.BlockSpec((1, 1, bq, dv), lambda bi, hi, qi, ki: (bi, hi, qi, 0))
     ]
-    out_shape = [jax.ShapeDtypeStruct((b, h, tq, dh), q.dtype)]
+    out_shape = [jax.ShapeDtypeStruct((b, h, tq, dv), q.dtype)]
     if need_lse:
         out_specs.append(_row_stats_spec(bq))
         out_shape.append(
@@ -259,7 +261,7 @@ def _flash_forward(q, k, v, mask, scale, block_q, block_k, interpret,
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),   # running max
             pltpu.VMEM((bq, 1), jnp.float32),   # running denominator
-            pltpu.VMEM((bq, dh), jnp.float32),  # running numerator
+            pltpu.VMEM((bq, dv), jnp.float32),  # running numerator
         ],
         interpret=interpret,
     )(*operands)
@@ -368,7 +370,7 @@ def _bwd_dkv_step(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
 def _flash_backward(q, k, v, mask, out, lse, g, scale, bq, bk,
                     interpret, causal):
     b, tq, h, dh = q.shape
-    tk = k.shape[1]
+    tk, dv = k.shape[1], v.shape[-1]
     nq, nk = tq // bq, tk // bk
 
     qt = jnp.transpose(q, (0, 2, 1, 3))
@@ -395,8 +397,17 @@ def _flash_backward(q, k, v, mask, out, lse, g, scale, bq, bk,
     kspec = pl.BlockSpec(
         (1, 1, bk, dh), lambda bi, hi, qi, ki: (bi, hi, ki, 0)
     )
+    # v and dO (and dv below) carry the value width, which latent
+    # attention makes narrower than q.k's; equal widths give the same
+    # specs as before.
+    vspec = pl.BlockSpec(
+        (1, 1, bk, dv), lambda bi, hi, qi, ki: (bi, hi, ki, 0)
+    )
+    dospec = pl.BlockSpec(
+        (1, 1, bq, dv), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
+    )
     dq_ops = [qt, kt, vt, dot, lse, delta]
-    dq_specs = [qspec, kspec, kspec, qspec, _row_stats_spec(bq),
+    dq_specs = [qspec, kspec, vspec, dospec, _row_stats_spec(bq),
                 _row_stats_spec(bq)]
     if mask3 is not None:
         dq_ops.append(mask3)
@@ -428,11 +439,17 @@ def _flash_backward(q, k, v, mask, out, lse, g, scale, bq, bk,
     kv_kspec = pl.BlockSpec(
         (1, 1, bk, dh), lambda bi, hi, ki, qi: (bi, hi, ki, 0)
     )
+    kv_vspec = pl.BlockSpec(
+        (1, 1, bk, dv), lambda bi, hi, ki, qi: (bi, hi, ki, 0)
+    )
+    kv_dospec = pl.BlockSpec(
+        (1, 1, bq, dv), lambda bi, hi, ki, qi: (bi, hi, qi, 0)
+    )
     kv_rowq = pl.BlockSpec(
         (1, 1, bq, _LANES), lambda bi, hi, ki, qi: (bi, hi, qi, 0)
     )
     dkv_ops = [qt, kt, vt, dot, lse, delta]
-    dkv_specs = [kv_qspec, kv_kspec, kv_kspec, kv_qspec, kv_rowq, kv_rowq]
+    dkv_specs = [kv_qspec, kv_kspec, kv_vspec, kv_dospec, kv_rowq, kv_rowq]
     if mask3 is not None:
         dkv_ops.append(mask3)
         # _whole_mask_spec's index map ignores the two block grid axes,
@@ -453,14 +470,14 @@ def _flash_backward(q, k, v, mask, out, lse, g, scale, bq, bk,
         dkv_kernel,
         grid=(b, h, nk, nq),
         in_specs=dkv_specs,
-        out_specs=[kv_kspec, kv_kspec],
+        out_specs=[kv_kspec, kv_vspec],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, tk, dh), k.dtype),
-            jax.ShapeDtypeStruct((b, h, tk, dh), v.dtype),
+            jax.ShapeDtypeStruct((b, h, tk, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, dh), jnp.float32),
-            pltpu.VMEM((bk, dh), jnp.float32),
+            pltpu.VMEM((bk, dv), jnp.float32),
         ],
         interpret=interpret,
     )(*dkv_ops)

@@ -326,7 +326,9 @@ class CausalLMSequenceParallelEngine:
     from later shards are fully hidden, the resident block is
     triangular (`ops/ring_attention.py`)."""
 
-    cfg: Any  # models.gpt.GPTConfig
+    # A configuration that answers `lm_family()` (models/lm_family.py):
+    # models.gpt.GPTConfig, models.kimi_linear.KimiLinearConfig.
+    cfg: Any
     optimizer: Any  # SGD | AdamW (init/update/state_shardings protocol)
     mesh: Mesh
     attention: str = "ring"
@@ -364,14 +366,6 @@ class CausalLMSequenceParallelEngine:
     dcn_compression: str = "none"
 
     def __post_init__(self):
-        from distributed_model_parallel_tpu.models.gpt import (
-            decoder_blocks,
-            gpt_lm,
-            head_apply as lm_head_apply,
-            lm_targets,
-            stem_apply as lm_stem_apply,
-        )
-
         mesh = self.mesh
         if "seq" not in mesh.axis_names:
             raise ValueError("sequence-parallel mesh needs a 'seq' axis")
@@ -399,24 +393,30 @@ class CausalLMSequenceParallelEngine:
             bucket_mb if self.grad_reduction != "monolithic"
             else MONOLITHIC_BUCKET_MB
         )
-        cfg = self.cfg
-        if getattr(cfg, "num_experts", 0) > 0:
-            # Same objection as the BERT SP engine: per-shard routing
-            # under 'seq' sharding breaks the dense capacity semantics
-            # and the moe_aux leaves never reach the differentiated
-            # loss. The MoE text path is ExpertParallelLMEngine.
+        # The one seam to the model family (`models/lm_family.py`):
+        # init, stem, blocks, head, targets. Nothing below spells a
+        # family's fields.
+        fam = self.cfg.lm_family()
+        self._family = fam
+        if fam.refused:
+            raise NotImplementedError(fam.refused)
+        if mesh.shape["seq"] > 1 and fam.seq_shards_missing:
             raise NotImplementedError(
-                "GPTConfig.num_experts > 0 is not supported by "
-                "CausalLMSequenceParallelEngine; train MoE LMs with "
-                "parallel/expert_parallel.ExpertParallelLMEngine "
-                "(cli/lm.py --moe-experts)."
+                f"{fam.name}: a mesh with 'seq' = {mesh.shape['seq']} "
+                f"is not supported: {fam.seq_shards_missing}"
+            )
+        if overlapped and fam.counters is not None:
+            raise NotImplementedError(
+                f"{fam.name}: grad_reduction='overlapped' cuts the "
+                "block stack into stagewise segments that carry no "
+                "block state, which this family's expert layers need"
             )
         if overlapped:
-            if cfg.num_layers < 2:
+            if fam.num_layers < 2:
                 raise ValueError(
                     "CausalLMSequenceParallelEngine: grad_reduction="
                     "'overlapped' splits the decoder stack into >= 2 "
-                    f"backward segments; cfg.num_layers={cfg.num_layers}"
+                    f"backward segments; cfg.num_layers={fam.num_layers}"
                 )
             from distributed_model_parallel_tpu.models.staging import (
                 resolve_overlap_segments,
@@ -424,52 +424,43 @@ class CausalLMSequenceParallelEngine:
             )
 
             n_over = resolve_overlap_segments(
-                cfg.num_layers, self.overlap_stages,
+                fam.num_layers, self.overlap_stages,
                 "CausalLMSequenceParallelEngine", noun="decoder blocks",
             )
-            over_cuts = split_points(n_over, None, cfg.num_layers)
-        self._lm_targets = partial(
-            lm_targets, pad_token_id=cfg.pad_token_id
-        )
+            over_cuts = split_points(n_over, None, fam.num_layers)
         attn_fn = partial(
             ATTENTION[self.attention], axis_name="seq", causal=True
         )
         self._matmul = _seq_matmul_policy(
-            self.collective_matmul, cfg.ffn_dim, mesh.shape["seq"]
+            self.collective_matmul, fam.ffn_dim, mesh.shape["seq"]
         )
         mm = self._matmul
         self._repl = NamedSharding(mesh, P())
         self._batch = NamedSharding(mesh, P(d_axes, ("seq",)))
         # Dense-parameter twin used ONLY for init (identical pytree).
-        self._full = gpt_lm(cfg)
-        block_list = decoder_blocks(cfg, attn_fn)
+        self._full = fam.model()
+        block_list = fam.blocks(attn_fn)
         if self.remat:
             block_list = [L.remat(b) for b in block_list]
         blocks = L.sequential(*block_list)
-        blocks_state = {str(i): {} for i in range(cfg.num_layers)}
-        drop = L.dropout(cfg.dropout_rate)
         cdt = self.compute_dtype
 
-        def forward(params, ids, ctx):
-            """Per-shard forward: local ids (Bl, Tl) -> local logits.
-            The SAME stem/head math as the dense model (shared
-            `stem_apply`/`head_apply` from models/gpt.py), with the
-            position-embedding slice made shard-aware: it starts at this
-            shard's global offset (the dense stem would give shards
-            1..N-1 local-offset positions — `models/gpt.gpt_lm` doc)."""
-            tl = ids.shape[1]
-            s_idx = lax.axis_index("seq")
-            pos = lax.dynamic_slice_in_dim(
-                params["stem"]["position"], s_idx * tl, tl, axis=0
+        def forward(params, blocks_state, ids, ctx):
+            """Per-shard forward: local ids (Bl, Tl) -> (local logits,
+            the family's step counters). The SAME stem/head math as the
+            dense model, the stem told this shard's index so that what
+            depends on position starts at the shard's global offset.
+            `blocks_state` is the blocks' non-trained buffers
+            (`TrainState.model_state["blocks"]`); the state the blocks
+            hand back carries the counters and is not kept."""
+            h, mask = fam.stem(
+                params["stem"], ids, ctx.child(0), lax.axis_index("seq")
             )
-            h, mask = lm_stem_apply(
-                params["stem"], ids, cfg, drop, ctx.child(0),
-                positions=pos,
-            )
-            (h, _), _ = blocks.apply(
+            (h, _), after = blocks.apply(
                 params["blocks"], blocks_state, (h, mask), ctx.child(1)
             )
-            return lm_head_apply(params["head"], h)
+            counters = fam.counters(after) if fam.counters else {}
+            return fam.head(params["head"], h), counters
 
         def local_sums(logits, targets):
             """Per-shard metric SUMS over this shard's tokens — the
@@ -480,6 +471,20 @@ class CausalLMSequenceParallelEngine:
             return _metrics(
                 cross_entropy(flat_logits, flat_t), flat_logits, flat_t
             )
+
+        # How each step metric combines over shards and, for the
+        # Trainer and `training/multistep.py`, over steps: the family's
+        # counters say; every other metric is a sum.
+        self.metric_reductions = dict(fam.counter_reductions)
+        over_shards = {"sum": lax.psum, "max": lax.pmax}
+
+        def reduced(m):
+            """The step's metrics over every shard."""
+            return {
+                k: over_shards[self.metric_reductions.get(k, "sum")](
+                    v, reduce_axes
+                ) for k, v in m.items()
+            }
 
         def overlap_stage_fns(ctx):
             """Per-segment closures for the stagewise backward: the SAME
@@ -495,14 +500,9 @@ class CausalLMSequenceParallelEngine:
                 def fn(p, _state, x, i=i):
                     k = 0
                     if i == 0:
-                        tl = x.shape[1]
-                        s_idx = lax.axis_index("seq")
-                        pos = lax.dynamic_slice_in_dim(
-                            p["0"]["position"], s_idx * tl, tl, axis=0
-                        )
-                        y = lm_stem_apply(
-                            p["0"], x, cfg, drop, ctx.child(0),
-                            positions=pos,
+                        y = fam.stem(
+                            p["0"], x, ctx.child(0),
+                            lax.axis_index("seq"),
                         )
                         k = 1
                     else:
@@ -514,7 +514,7 @@ class CausalLMSequenceParallelEngine:
                         k += 1
                     if i == n_over - 1:
                         h, _mask = y
-                        y = lm_head_apply(p[str(k)], h)
+                        y = fam.head(p[str(k)], h)
                     return y, {}
 
                 fns.append(fn)
@@ -573,8 +573,10 @@ class CausalLMSequenceParallelEngine:
                 )
             else:
                 def loss_fn(params):
-                    logits = forward(params, ids, ctx)
-                    m = local_sums(logits, targets)
+                    logits, counters = forward(
+                        params, ts.model_state["blocks"], ids, ctx
+                    )
+                    m = {**local_sums(logits, targets), **counters}
                     # LOCAL token-loss sum (pipeline discipline: no psum
                     # before grad).
                     return m["loss_sum"], m
@@ -609,17 +611,14 @@ class CausalLMSequenceParallelEngine:
             new_ts = TrainState(
                 params, ts.model_state, opt_state, ts.step + 1
             )
-            return new_ts, {
-                k: lax.psum(v, reduce_axes) for k, v in m.items()
-            }
+            return new_ts, reduced(m)
 
         def shard_eval(ts: TrainState, ids, targets):
-            logits = forward(
-                ts.params, ids,
+            logits, counters = forward(
+                ts.params, ts.model_state["blocks"], ids,
                 L.Context(train=False, dtype=cdt, matmul=mm),
             )
-            m = local_sums(logits, targets)
-            return {k: lax.psum(v, reduce_axes) for k, v in m.items()}
+            return reduced({**local_sums(logits, targets), **counters})
 
         donate = (0,) if self.donate else ()
         self.train_step = jax.jit(
@@ -658,8 +657,10 @@ class CausalLMSequenceParallelEngine:
         ('data', 'seq'). `labels` is ignored (the LM's targets are the
         shifted ids); the parameter keeps the engine signature-uniform
         with the classification engines."""
-        _check_seq_len(ids, self.cfg.max_position, "GPTConfig")
-        targets = self._lm_targets(ids)
+        _check_seq_len(
+            ids, self._family.max_position, type(self.cfg).__name__
+        )
+        targets = self._family.targets(ids)
         ids_arr = _place_batch((ids,), self._batch)[0]
         targets_arr = _place_batch((targets,), self._batch)[0]
         return ids_arr, targets_arr

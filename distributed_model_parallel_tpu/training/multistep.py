@@ -35,6 +35,39 @@ import jax.numpy as jnp
 from jax import lax
 
 
+_PAIR = {"sum": jnp.add, "max": jnp.maximum}
+_OVER = {"sum": jnp.sum, "max": jnp.max}
+
+
+def add_step_metrics(a: dict, b: dict, reductions=None) -> dict:
+    """Two steps' metric dicts into one. An engine's metrics are sums;
+    one whose step has another kind says so in `metric_reductions`
+    ({name: "sum" | "max"}, names not listed are sums), which the
+    caller hands in."""
+    if not reductions:
+        return jax.tree_util.tree_map(jnp.add, a, b)
+    return {
+        k: jax.tree_util.tree_map(
+            _PAIR[reductions.get(k, "sum")], a[k], b[k]
+        ) for k in a
+    }
+
+
+def _over_steps(per_step: dict, reductions=None) -> dict:
+    """Metrics stacked on a leading step axis -> one dict, by the rule
+    of `add_step_metrics`."""
+    if not reductions:
+        return jax.tree_util.tree_map(
+            lambda x: jnp.sum(x, axis=0), per_step
+        )
+    return {
+        k: jax.tree_util.tree_map(
+            lambda x, how=reductions.get(k, "sum"): _OVER[how](x, axis=0),
+            v,
+        ) for k, v in per_step.items()
+    }
+
+
 def compile_multi_step(engine: Any, k: int) -> Callable:
     """Build `fn(state, batches, lr) -> (state, summed_metrics)` running
     `k` train steps in one compiled program.
@@ -51,6 +84,7 @@ def compile_multi_step(engine: Any, k: int) -> Callable:
     """
     if k < 1:
         raise ValueError(f"steps_per_dispatch must be >= 1, got {k}")
+    reductions = getattr(engine, "metric_reductions", None)
 
     def k_steps(state, batches: Tuple, lr):
         # Leaf-wise stack of the k batch tuples -> scan operands with a
@@ -65,9 +99,7 @@ def compile_multi_step(engine: Any, k: int) -> Callable:
             return s2, m
 
         state, per_step = lax.scan(body, state, stacked)
-        return state, jax.tree_util.tree_map(
-            lambda x: jnp.sum(x, axis=0), per_step
-        )
+        return state, _over_steps(per_step, reductions)
 
     return jax.jit(k_steps, donate_argnums=(0,))
 
@@ -79,6 +111,7 @@ def compile_multi_eval(engine: Any, k: int) -> Callable:
     k=1 is a passthrough, like `compile_multi_step`."""
     if k < 1:
         raise ValueError(f"steps_per_dispatch must be >= 1, got {k}")
+    reductions = getattr(engine, "metric_reductions", None)
 
     def k_evals(state, batches: Tuple):
         stacked = jax.tree_util.tree_map(
@@ -89,9 +122,7 @@ def compile_multi_eval(engine: Any, k: int) -> Callable:
             return carry, engine.eval_step(state, *batch)
 
         _, per_step = lax.scan(body, 0, stacked)
-        return jax.tree_util.tree_map(
-            lambda x: jnp.sum(x, axis=0), per_step
-        )
+        return _over_steps(per_step, reductions)
 
     return jax.jit(k_evals)
 
@@ -108,4 +139,7 @@ def group_batches(iterator, k: int):
     return group
 
 
-__all__ = ["compile_multi_eval", "compile_multi_step", "group_batches"]
+__all__ = [
+    "add_step_metrics", "compile_multi_eval", "compile_multi_step",
+    "group_batches",
+]

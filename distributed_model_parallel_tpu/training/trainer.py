@@ -49,6 +49,7 @@ from distributed_model_parallel_tpu.training.checkpoint import (
     save_checkpoint,
 )
 from distributed_model_parallel_tpu.training.multistep import (
+    add_step_metrics,
     compile_multi_eval,
     compile_multi_step,
     group_batches,
@@ -68,6 +69,10 @@ class EpochStats:
     batch_time: float = 0.0  # avg seconds per batch, data included
     data_time: float = 0.0   # avg seconds waiting on the input pipeline
     count: int = 0
+    # What the engine's step metrics hold beside the four above (a model
+    # family's counters, `models/lm_family.LMFamily.counters`), combined
+    # over the epoch's steps as the engine's `metric_reductions` says.
+    counters: dict = dataclasses.field(default_factory=dict)
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -147,6 +152,9 @@ class Trainer:
         rng: Optional[jax.Array] = None,
     ):
         self.engine = engine
+        # {name: "sum" | "max"} of the step metrics that are not the
+        # four every engine has; {} for most engines.
+        self._reductions = getattr(engine, "metric_reductions", None) or {}
         self.train_loader = train_loader
         self.val_loader = val_loader
         self.config = config
@@ -341,13 +349,7 @@ class Trainer:
                         self.state, m_i = self.engine.train_step(
                             self.state, *b, lr
                         )
-                        metrics = (
-                            m_i
-                            if metrics is None
-                            else jax.tree_util.tree_map(
-                                jnp.add, metrics, m_i
-                            )
-                        )
+                        metrics = self._add(metrics, m_i)
             prev = n_batches
             n_group = len(placed)
             n_batches += n_group
@@ -364,11 +366,7 @@ class Trainer:
                 profiling = False
                 self._profiled = True
                 profile_at = None  # never re-arm within this epoch
-            sums = (
-                metrics
-                if sums is None
-                else jax.tree_util.tree_map(jnp.add, sums, metrics)
-            )
+            sums = self._add(sums, metrics)
             if mx.enabled:
                 # Step-time sample at dispatch granularity (boundary
                 # to boundary, prefetch included), CLOSED before the
@@ -417,7 +415,12 @@ class Trainer:
             jax.profiler.stop_trace()
             self._profiled = True
         wall = time.perf_counter() - epoch_start
-        return self._finalize(sums, n_batches, wall, data_time)
+        stats = self._finalize(sums, n_batches, wall, data_time)
+        for name, value in stats.counters.items():
+            # The model family's step counters (models/moe.COUNTERS),
+            # as the epoch leaves them.
+            mx.gauge(name, value)
+        return stats
 
     def validate(self, epoch: int) -> EpochStats:
         it = iter(self.val_loader)
@@ -443,16 +446,8 @@ class Trainer:
                 metrics = None
                 for b in placed:
                     m_i = self.engine.eval_step(self.state, *b)
-                    metrics = (
-                        m_i
-                        if metrics is None
-                        else jax.tree_util.tree_map(jnp.add, metrics, m_i)
-                    )
-            sums = (
-                metrics
-                if sums is None
-                else jax.tree_util.tree_map(jnp.add, sums, metrics)
-            )
+                    metrics = self._add(metrics, m_i)
+            sums = self._add(sums, metrics)
             n_batches += len(placed)
         if sums is not None:
             sums = jax.device_get(sums)  # value-fetch barrier, as above
@@ -594,6 +589,12 @@ class Trainer:
         fn = getattr(self.engine, "from_canonical", None)
         return fn(state) if fn is not None else state
 
+    def _add(self, sums, metrics):
+        """`metrics` onto the running `sums` (None before the first)."""
+        if sums is None:
+            return metrics
+        return add_step_metrics(sums, metrics, self._reductions)
+
     def _finalize(
         self, sums, n_batches: int, wall: float, data_time: float
     ) -> EpochStats:
@@ -608,6 +609,7 @@ class Trainer:
             batch_time=wall / n_batches,
             data_time=data_time / n_batches,
             count=int(count),
+            counters={k: float(m[k]) for k in self._reductions},
         )
 
     def _append_epoch_log(
@@ -633,6 +635,8 @@ class Trainer:
             f"val_loss {val.loss:.4f} val_acc1 {val.acc1:.3f} "
             f"time_per_batch {train.batch_time:.4f} "
             f"time_load_perbatch {train.data_time:.4f}"
+        ) + "".join(
+            f" {name} {value:g}" for name, value in train.counters.items()
         )
         self._log_print(line)
         if cfg.log_file:
