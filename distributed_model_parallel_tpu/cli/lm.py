@@ -340,8 +340,8 @@ def main(argv=None) -> dict:
             raise SystemExit(
                 f"--attention selects the 'seq'-axis distribution, "
                 f"but plan {plan.spec} has sp=1 (stages attend "
-                "locally, dense causal) — add an spN token or drop "
-                "the flag"
+                "locally; the engine picks the kernel) — add an spN "
+                "token or drop the flag"
             )
         if args.collective_matmul and plan.tp_or_sp <= 1:
             raise SystemExit(
@@ -623,6 +623,10 @@ def main(argv=None) -> dict:
             )
         except (ValueError, NotImplementedError) as e:
             raise SystemExit(f"--plan {plan.spec}: {e}") from e
+        local = getattr(engine, "local_attention", None)
+        if local is not None and jax.process_index() == 0:
+            print(f"plan {plan.spec}: the sequence is whole on a chip, "
+                  f"local attention: {local}")
     elif args.pipeline_stages > 1:
         from distributed_model_parallel_tpu.models.gpt import split_stages
         from distributed_model_parallel_tpu.parallel.pipeline import (

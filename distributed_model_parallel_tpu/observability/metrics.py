@@ -95,6 +95,13 @@ METRIC_NAMES: Dict[str, str] = {
         "expert's rows, read from the permutation: 0 while there is no "
         "capacity and the sort is right"
     ),
+    # Which attention an engine whose sequence is whole on a chip
+    # compiled into its step (ComposedPlanEngine.local_attention).
+    "train_local_attention_flash": (
+        "1 when the step attends with the flash kernels, 0 when with "
+        "the dense XLA graph (gauge, set at the end of an epoch; absent "
+        "for an engine that shards the sequence)"
+    ),
     # Serving (serving/scheduler.py + engine.py).
     "serve_queued_s": "per request: submit -> admission",
     "serve_ttft_s": "per request: submit -> first token (TTFT)",

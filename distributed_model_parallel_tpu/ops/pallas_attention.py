@@ -37,6 +37,10 @@ Contract and scope:
   runs.
 * On non-TPU backends the kernels run in Pallas interpret mode (slow,
   CI-only) so the numerics are testable on the 8-virtual-device mesh.
+* `local_causal_attention` is the attention of an engine whose sequence
+  is whole on a chip: it picks these kernels or the dense XLA graph per
+  call from the backend, the lengths and the mask's shape (the dense
+  graph off a TPU, always), so no flag has to.
 """
 
 from __future__ import annotations
@@ -556,3 +560,44 @@ def flash_attention(
             "dot_product_attention for general logit masks"
         )
     return _flash(q, k, v, mask, scale, block_q, block_k, interpret, causal)
+
+
+def _on_tpu() -> bool:
+    # The selector's own predicate, apart from `flash_attention`'s
+    # `interpret` default: a test that answers "tpu" here still runs
+    # the kernels in the interpreter.
+    return jax.default_backend() == "tpu"
+
+
+def local_attention_kind(tq: int, tk: int, mask) -> str:
+    """Which program `local_causal_attention` runs for these static
+    facts: `"flash"` on a TPU when the mask is None or a (B, Tkv)
+    key-validity mask and the default blocks tile (Tq, Tkv); `"dense"`
+    anywhere else. Off a TPU it is always `"dense"`, never the Pallas
+    interpreter: nobody deploys that, and every CPU test compiles the
+    XLA program."""
+    if (
+        _on_tpu()
+        and (mask is None or mask.ndim == 2)
+        and _blocks_viable(tq, tk, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)
+    ):
+        return "flash"
+    return "dense"
+
+
+def local_causal_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    mask: Optional[jax.Array] = None,
+    *,
+    scale: Optional[float] = None,
+) -> jax.Array:
+    """Drop-in `attention_fn` for exact causal attention over a sequence
+    held whole on one device. One algorithm, two programs for it — the
+    flash kernels or the dense XLA graph — picked per call by
+    `local_attention_kind` from what the call can observe (the backend,
+    the sequence lengths, the mask's shape)."""
+    kind = local_attention_kind(q.shape[1], k.shape[1], mask)
+    attend = flash_attention if kind == "flash" else dot_product_attention
+    return attend(q, k, v, mask, scale=scale, causal=True)

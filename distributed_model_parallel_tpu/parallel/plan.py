@@ -331,8 +331,9 @@ class ComposedPlanEngine:
             lm_targets,
             stem_apply as lm_stem_apply,
         )
-        from distributed_model_parallel_tpu.ops.attention import (
-            dot_product_attention,
+        from distributed_model_parallel_tpu.ops.pallas_attention import (
+            local_attention_kind,
+            local_causal_attention,
         )
 
         mesh = self.mesh
@@ -435,11 +436,29 @@ class ComposedPlanEngine:
             lm_targets, pad_token_id=cfg.pad_token_id
         )
         sp = plan.tp_or_sp
-        attn_fn = (
-            partial(ATTENTION[self.attention], axis_name="seq",
-                    causal=True)
-            if sp > 1 else partial(dot_product_attention, causal=True)
-        )
+        # With the sequence whole on a chip (sp == 1) `attention` — how
+        # the 'seq' axis is distributed — has nothing to say: the
+        # engine attends locally and the call itself picks the flash
+        # kernels or the dense XLA graph from what it can observe
+        # (ops/pallas_attention.local_causal_attention). Which one the
+        # step holds, "flash" | "dense" (None under sp > 1): the
+        # selector's answer at cfg.max_position until a step is traced,
+        # then what that trace picked at the batch's own length.
+        self.local_attention = None
+        if sp > 1:
+            attn_fn = partial(
+                ATTENTION[self.attention], axis_name="seq", causal=True
+            )
+        else:
+            self.local_attention = local_attention_kind(
+                cfg.max_position, cfg.max_position, None
+            )
+
+            def attn_fn(q, k, v, mask=None):
+                self.local_attention = local_attention_kind(
+                    q.shape[1], k.shape[1], mask
+                )
+                return local_causal_attention(q, k, v, mask)
         self._matmul = _seq_matmul_policy(
             self.collective_matmul and sp > 1, cfg.ffn_dim, sp
         )

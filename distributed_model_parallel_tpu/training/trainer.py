@@ -420,6 +420,9 @@ class Trainer:
             # The model family's step counters (models/moe.COUNTERS),
             # as the epoch leaves them.
             mx.gauge(name, value)
+        local = getattr(self.engine, "local_attention", None)
+        if local is not None:
+            mx.gauge("train_local_attention_flash", float(local == "flash"))
         return stats
 
     def validate(self, epoch: int) -> EpochStats:

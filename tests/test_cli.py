@@ -1102,6 +1102,37 @@ def test_lm_cli_composed_plan_e2e(tmp_path, monkeypatch):
     assert len(result["history"]) == 1
 
 
+def test_lm_cli_plan_says_which_local_attention(
+    tmp_path, monkeypatch, capsys
+):
+    """A plan whose sequence is whole on a chip (`fsdp4`): the CLI
+    prints the engine's pick once beside the plan, and the Trainer sets
+    the gauge. Off a TPU both say dense: the compiled step is the one
+    the plan parities pin."""
+    from distributed_model_parallel_tpu.cli import lm
+    from distributed_model_parallel_tpu.observability import metrics
+
+    monkeypatch.chdir(tmp_path)
+    registry = metrics.MetricsRegistry(enabled=True)
+    metrics.set_metrics(registry)
+    try:
+        result = lm.main([
+            "--plan", "fsdp4", "--remat",
+            "--dim", "32", "--layers", "2", "--heads", "4",
+            "--ffn-dim", "64", "--seq-len", "32",
+            "-b", "8", "--epochs", "1", "--steps-per-epoch", "2",
+            "--corpus-tokens", "4096", "--lr", "1e-3",
+        ])
+    finally:
+        metrics.set_metrics(None)
+    assert len(result["history"]) == 1
+    out = capsys.readouterr().out
+    assert out.count("local attention: dense") == 1
+    assert "plan fsdp4" in out
+    assert registry._gauges["train_local_attention_flash"].value == 0.0
+    assert "train_local_attention_flash" in metrics.METRIC_NAMES
+
+
 @pytest.mark.slow
 def test_lm_cli_plan_now_legal_combos(tmp_path, monkeypatch):
     """Combos the pre-plan guards refused are legal under a plan that

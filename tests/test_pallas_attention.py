@@ -1,6 +1,8 @@
 """Pallas flash-attention kernel tests (interpret mode on the CPU mesh;
 the same kernel compiles natively on TPU)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,8 +11,11 @@ import pytest
 from distributed_model_parallel_tpu.ops.attention import (
     dot_product_attention,
 )
+from distributed_model_parallel_tpu.ops import pallas_attention
 from distributed_model_parallel_tpu.ops.pallas_attention import (
     flash_attention,
+    local_attention_kind,
+    local_causal_attention,
 )
 
 B, T, H, DH = 2, 256, 4, 32
@@ -235,4 +240,114 @@ def test_flash_dh128_matches_xla():
     ))(k)
     np.testing.assert_allclose(
         np.asarray(g1), np.asarray(g2), rtol=2e-4, atol=2e-5
+    )
+
+
+# ------------------------------------------- the kernel under L.remat
+
+
+def _remat_attention(attention_fn):
+    """`attention_fn(q, k, v, mask)` as a layer under `L.remat`, the way
+    an engine with `remat=True` runs it: the backward pass recomputes
+    the forward kernel."""
+    from distributed_model_parallel_tpu.models import layers as L
+
+    layer = L.remat(L.Layer(
+        lambda rng: ({}, {}),
+        lambda p, s, x, ctx: (attention_fn(*x), s),
+    ))
+
+    def loss(q, k, v, mask):
+        out, _ = layer.apply({}, {}, (q, k, v, mask), L.Context(train=True))
+        return jnp.sum(jnp.square(out.astype(jnp.float32))), out
+
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))
+
+
+@pytest.mark.parametrize("heads,dh,t,dtype,rtol,atol", [
+    # GPT-2 XL's attention as plan fsdp4 runs it on one chip: 25 heads
+    # of 64, a padding mask, bf16, remat (gpt2xl_train_fsdp4).
+    (25, 64, 128, jnp.bfloat16, 1e-1, 1e-1),
+    (25, 64, 128, jnp.float32, 2e-4, 2e-5),
+    (4, 32, 256, jnp.bfloat16, 1e-1, 1e-1),
+], ids=["xl-25x64-bf16", "xl-25x64-f32", "4x32-bf16"])
+def test_causal_masked_under_remat_matches_dense(
+    heads, dh, t, dtype, rtol, atol
+):
+    """Kernel against dense, causal with a padding mask, forward and
+    all three gradients, both under `L.remat`."""
+    rng = np.random.RandomState(31)
+    mk = lambda: jnp.asarray(
+        rng.randn(2, t, heads, dh).astype(np.float32), dtype
+    )
+    q, k, v = mk(), mk(), mk()
+    # a padding mask: each row valid up to its own length
+    mask = jnp.arange(t)[None, :] < jnp.asarray([[t], [t - 37]])
+    ((_, got), got_g) = _remat_attention(
+        functools.partial(flash_attention, causal=True)
+    )(q, k, v, mask)
+    ((_, want), want_g) = _remat_attention(
+        functools.partial(dot_product_attention, causal=True)
+    )(q, k, v, mask)
+    assert got.dtype == dtype
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        rtol=rtol, atol=atol,
+    )
+    for g, w, name in zip(got_g, want_g, "qkv"):
+        assert g.dtype == dtype
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), np.asarray(w, np.float32),
+            rtol=rtol, atol=atol, err_msg=f"grad wrt {name}",
+        )
+
+
+# ------------------- the selector: local_causal_attention (ISSUE 31)
+
+
+def test_local_attention_off_tpu_is_the_dense_graph_bit_for_bit():
+    """On the CPU backend the selector IS
+    `dot_product_attention(causal=True)`: the same values to the bit,
+    no `pallas_call` in the jaxpr (never the interpreter), whatever the
+    shape."""
+    q, k, v, mask = _qkv(seed=41, t=64)
+    got = local_causal_attention(q, k, v, mask)
+    want = dot_product_attention(q, k, v, mask, causal=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert local_attention_kind(64, 64, mask) == "dense"
+    assert local_attention_kind(1024, 1024, None) == "dense"
+    jaxpr = str(jax.make_jaxpr(
+        jax.grad(lambda q: jnp.sum(local_causal_attention(q, k, v, mask)))
+    )(q))
+    assert "pallas_call" not in jaxpr
+
+
+@pytest.mark.parametrize("t,mask_kind,want", [
+    (64, "keys", "flash"),
+    (64, "none", "flash"),
+    (17, "keys", "dense"),   # a prime length: no tiling
+    (64, "logits", "dense"),  # a (B, 1, Tq, Tkv) mask: not the kernel's
+], ids=["tileable-keymask", "tileable-nomask", "prime", "4d-mask"])
+def test_local_attention_on_tpu_picks_from_shapes(
+    monkeypatch, t, mask_kind, want
+):
+    """With the backend predicate saying "tpu" (the kernels still run in
+    the interpreter here): a tileable length under no mask or a (B, Tkv)
+    mask traces the kernels, forward and both backward; a prime length
+    and a 4-D logit mask take the dense graph. Both agree with dense."""
+    monkeypatch.setattr(pallas_attention, "_on_tpu", lambda: True)
+    q, k, v, keys = _qkv(seed=43, t=t)
+    mask = {
+        "keys": keys, "none": None,
+        "logits": jnp.broadcast_to(keys[:, None, None, :], (B, 1, t, t)),
+    }[mask_kind]
+    jaxpr = str(jax.make_jaxpr(jax.grad(
+        lambda q: jnp.sum(local_causal_attention(q, k, v, mask) ** 2)
+    ))(q))
+    assert local_attention_kind(t, t, mask) == want
+    assert jaxpr.count("pallas_call") == (3 if want == "flash" else 0)
+    np.testing.assert_allclose(
+        np.asarray(local_causal_attention(q, k, v, mask)),
+        np.asarray(dot_product_attention(q, k, v, mask, causal=True)),
+        rtol=1e-5, atol=1e-5,
     )

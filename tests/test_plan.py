@@ -514,3 +514,88 @@ def test_state_partition_specs_shapes_match_state():
     )
     assert any("data" in (s[0] or ()) if len(s) else False
                for s in fs_specs if s != P())
+
+
+# ------------------------- local attention under sp == 1 (ISSUE 31)
+
+
+def _fsdp_two_steps(compute_dtype):
+    """Two steps of `fsdp4` with remat at TINY widths: the losses, and
+    the norm of the parameters' change over the first step (SGD: lr
+    times the gradient norm). Also the engine, for its field, and the
+    number of kernels its traced step holds."""
+    eng = build_plan_engine(
+        TINY, SGD(), "fsdp4", donate=False, remat=True,
+        compute_dtype=compute_dtype,
+    )
+    ts0 = eng.init_state(jax.random.PRNGKey(0))
+    ids_s, tg_s = eng.shard_batch(_ids(seed=11))
+    ts, losses = ts0, []
+    moved = None
+    for _ in range(2):
+        ts, m = eng.train_step(ts, ids_s, tg_s, jnp.float32(LR))
+        losses.append(float(m["loss_sum"]) / float(m["count"]))
+        if moved is None:
+            moved = float(jnp.sqrt(sum(
+                jnp.sum(jnp.square(a - b)) for a, b in zip(
+                    jax.tree_util.tree_leaves(ts.params),
+                    jax.tree_util.tree_leaves(ts0.params),
+                )
+            )))
+    kernels = str(jax.make_jaxpr(eng.train_step)(
+        ts0, ids_s, tg_s, jnp.float32(LR)
+    )).count("pallas_call")
+    return eng, losses, moved, kernels
+
+
+@pytest.mark.parametrize("compute_dtype,rtol", [
+    (None, 1e-5), (jnp.bfloat16, 5e-3),
+], ids=["f32", "bf16"])
+def test_fsdp_step_with_the_kernel_forced_matches_dense(
+    monkeypatch, compute_dtype, rtol
+):
+    """The one place a CPU test runs the kernels through a plan, and it
+    says so: with the selector's backend predicate patched to "tpu"
+    (the kernels run in the interpreter) an fsdp4 step with remat
+    attends with flash, and its losses and gradient norm are the dense
+    step's — to float32 rounding in float32, inside the flash tests'
+    band in bfloat16."""
+    from distributed_model_parallel_tpu.ops import pallas_attention
+
+    dense, dense_losses, dense_moved, n = _fsdp_two_steps(compute_dtype)
+    assert dense.local_attention == "dense" and n == 0
+    monkeypatch.setattr(pallas_attention, "_on_tpu", lambda: True)
+    flash, flash_losses, flash_moved, n = _fsdp_two_steps(compute_dtype)
+    # forward, the remat's forward, and the two backward kernels
+    assert flash.local_attention == "flash" and n == 4
+    np.testing.assert_allclose(flash_losses, dense_losses, rtol=rtol)
+    np.testing.assert_allclose(flash_moved, dense_moved, rtol=rtol)
+    assert dense_moved > 0
+
+
+def test_local_attention_field_says_what_the_step_holds(monkeypatch):
+    """`ComposedPlanEngine.local_attention`: None where the sequence is
+    sharded (the `attention` argument rules there); otherwise the
+    selector's answer — "dense" off a TPU; on one, "flash" at a length
+    the kernels tile and "dense" at one they cannot, re-decided when a
+    step is traced at the batch's own length."""
+    from distributed_model_parallel_tpu.ops import pallas_attention
+
+    assert build_plan_engine(
+        TINY, SGD(), "pp2xsp2xdp2", donate=False
+    ).local_attention is None
+    for spec in ("fsdp4", "dp8", "pp2xdp2"):
+        assert build_plan_engine(
+            TINY, SGD(), spec, donate=False
+        ).local_attention == "dense"
+    monkeypatch.setattr(pallas_attention, "_on_tpu", lambda: True)
+    eng = build_plan_engine(TINY, SGD(), "dp2", donate=False,
+                            force_composed=True)
+    assert eng.local_attention == "flash"  # at max_position = 16
+    # a batch of 13 tokens a sequence has no tiling: the trace says so
+    ids = _ids(seed=3)[:, :13]
+    jax.eval_shape(
+        eng.train_step, eng.init_state(jax.random.PRNGKey(0)),
+        *eng.shard_batch(ids), jnp.float32(LR),
+    )
+    assert eng.local_attention == "dense"
