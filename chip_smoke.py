@@ -197,7 +197,10 @@ def barrier_leg(smoke: Smoke, kind: str) -> None:
     import jax
     import jax.numpy as jnp
 
-    from bench import peak_bf16_flops  # the one peak table; unknown = error
+    # the one peak table; unknown = error
+    from distributed_model_parallel_tpu.runtime.platform import (
+        peak_bf16_flops,
+    )
 
     n = smoke.w.barrier_n
     with smoke.leg("barrier") as (_, report):

@@ -61,32 +61,21 @@ class Combo:
     # MoE dispatch (`ops/wire_codec.py`, rule dcn-compressed-payload).
     dcn_compression: str = "none"
 
-    # Tuner-searched reducer knobs (`tuning/`): an explicit bucket cap
-    # (None = this module's BUCKET_MB, keeping every pre-existing combo
-    # name and ledger row byte-stable) and an explicit stagewise
-    # segment count (0 = the engines' auto default).
-    bucket_mb: Optional[float] = None
-    overlap_stages: int = 0
-
-    # Paged serving knobs (engine == "serve", ISSUE 15): page_size
-    # None keeps the PR 7 contiguous slot cache (every pre-existing
-    # serve combo name and ledger row byte-stable); set = the
-    # block-paged decode step. prefill_chunk shapes the HOST ingest
-    # loop only (same compiled decode step) and rides the name for the
-    # tuner's plan identity.
+    # Paged serving (engine == "serve", ISSUE 15): page_size None
+    # keeps the PR 7 contiguous slot cache (every pre-existing serve
+    # combo name byte-stable); set = the block-paged decode step.
     page_size: Optional[int] = None
-    prefill_chunk: int = 0
 
     # Quantized decode arithmetic (engine == "serve", ISSUE 16): None
     # keeps the f32 projections (every pre-existing serve combo name
-    # and ledger row byte-stable); "bf16"/"int8" opt the decode
+    # byte-stable); "bf16"/"int8" opt the decode
     # projection GEMMs into `ops/quant_matmul.py` (rule
     # decode-quantized-matmul).
     compute_dtype: Optional[str] = None
 
     # Speculative decoding (engine == "serve", ISSUE 18): 0 keeps the
-    # plain decode step (every pre-existing serve combo name and
-    # ledger row byte-stable); k > 0 lowers the VERIFY step instead —
+    # plain decode step (every pre-existing serve combo name
+    # byte-stable); k > 0 lowers the VERIFY step instead —
     # the (slots, k+1) chunk-shaped pass rule spec-verify-step pins at
     # one decode step's ring inventory. Requires page_size (rollback
     # truncates the block table).
@@ -96,13 +85,12 @@ class Combo:
     # `parse_plan` spec string (e.g. "pp2xsp2xdp2", or the scheduled
     # "pp2-1f1bxdp4" / "pp2-int2xdp2" forms, ISSUE 20) the builder
     # lowers through ComposedPlanEngine. None everywhere else (every
-    # pre-existing combo name and ledger row stays byte-stable).
+    # pre-existing combo name stays byte-stable).
     plan: Optional[str] = None
 
     # Pipeline fill depth for plan combos (ISSUE 20): 0 keeps the
     # engine default (M = pp * V — every pre-existing plan combo name
-    # and ledger row byte-stable); set = the tuner's M knob, which the
-    # bubble-factor compute fold (`cost.add_plan_compute`) prices.
+    # byte-stable); set = the engine's `num_microbatches`.
     num_microbatches: int = 0
 
     @property
@@ -122,14 +110,8 @@ class Combo:
             bits.append(f"M{self.num_microbatches}")
         if self.dcn_compression != "none":
             bits.append(f"wire-{self.dcn_compression}")
-        if self.bucket_mb is not None:
-            bits.append(f"b{self.bucket_mb:g}")
-        if self.overlap_stages:
-            bits.append(f"seg{self.overlap_stages}")
         if self.page_size is not None:
             bits.append(f"pg{self.page_size}")
-        if self.prefill_chunk:
-            bits.append(f"ck{self.prefill_chunk}")
         if self.model != "mlp":
             bits.append(self.model)
         if self.collective_matmul:
@@ -270,10 +252,8 @@ def _bucket_plan(leaves, bucket_mb: float, pad_multiple: int):
     return tuple(out)
 
 
-def _reducer_plans(model, grad_reduction: str, bucket_mb: float,
-                   ici_size: int, dcn_size: int = 1,
-                   dcn_compression: str = "none",
-                   overlap_stages: int = 0):
+def _reducer_plans(model, grad_reduction: str, ici_size: int,
+                   dcn_size: int = 1, dcn_compression: str = "none"):
     """Per-segment bucket plans + segment count for a staged model —
     one segment for 'bucketed', split_points segments for
     'overlapped', one WHOLE-TREE bucket per dtype for compressed
@@ -303,17 +283,17 @@ def _reducer_plans(model, grad_reduction: str, bucket_mb: float,
         return plans, 0, state_shapes
     if grad_reduction == "bucketed":
         plans = (_bucket_plan(
-            jax.tree_util.tree_leaves(p_aval), bucket_mb, pad_mult
+            jax.tree_util.tree_leaves(p_aval), BUCKET_MB, pad_mult
         ),)
         return plans, 0, state_shapes
     if grad_reduction == "overlapped":
         n = staging.resolve_overlap_segments(
-            len(model.parts.blocks), overlap_stages, "lint"
+            len(model.parts.blocks), 0, "lint"
         )
         cuts = staging.split_points(n, None, len(model.parts.blocks))
         plans = tuple(
             _bucket_plan(
-                jax.tree_util.tree_leaves(sp), bucket_mb, pad_mult
+                jax.tree_util.tree_leaves(sp), BUCKET_MB, pad_mult
             )
             for sp in staging.partition_tree(p_aval, cuts)
         )
@@ -587,7 +567,6 @@ def _build_data_engine(combo: Combo, devices):
         model = staged_mlp(width=128 if combo.engine == "fsdp" else 32)
     cdt = jnp.bfloat16 if combo.bf16 else None
     kwargs = dict(donate=True, compute_dtype=cdt)
-    bmb = BUCKET_MB if combo.bucket_mb is None else combo.bucket_mb
     full_leaf_shapes: Tuple = ()
     if combo.engine == "dp":
         from distributed_model_parallel_tpu.parallel.data_parallel import (
@@ -602,7 +581,7 @@ def _build_data_engine(combo: Combo, devices):
 
         eng = DDPEngine(
             model, SGD(), mesh, grad_reduction=combo.grad_reduction,
-            bucket_mb=bmb, overlap_stages=combo.overlap_stages,
+            bucket_mb=BUCKET_MB,
             dcn_compression=combo.dcn_compression, **kwargs,
         )
     else:  # fsdp
@@ -616,8 +595,7 @@ def _build_data_engine(combo: Combo, devices):
         min_elems = 64
         eng = FSDPEngine(
             model, SGD(), mesh, min_shard_elems=min_elems,
-            grad_reduction=combo.grad_reduction, bucket_mb=bmb,
-            overlap_stages=combo.overlap_stages,
+            grad_reduction=combo.grad_reduction, bucket_mb=BUCKET_MB,
             dcn_compression=combo.dcn_compression, **kwargs,
         )
         from jax.sharding import PartitionSpec as P
@@ -639,9 +617,8 @@ def _build_data_engine(combo: Combo, devices):
         full_leaf_shapes = tuple(shapes)
 
     plans, n_seg, state_shapes = _reducer_plans(
-        model, combo.grad_reduction, bmb, facts["ici_size"],
+        model, combo.grad_reduction, facts["ici_size"],
         facts["dcn_size"], combo.dcn_compression,
-        combo.overlap_stages,
     )
     ts = eng.init_state(jax.random.PRNGKey(0))
     im, lb = eng.shard_batch(*image_batch(16 * (s // 2 or 1)))
@@ -803,11 +780,9 @@ def _build_sp_lm(combo: Combo, devices):
     )
     facts = _mesh_facts(mesh)
     cfg = _gpt_cfg()
-    bmb = BUCKET_MB if combo.bucket_mb is None else combo.bucket_mb
     eng = CausalLMSequenceParallelEngine(
         cfg, SGD(), mesh, donate=True,
-        grad_reduction=combo.grad_reduction, bucket_mb=bmb,
-        overlap_stages=combo.overlap_stages,
+        grad_reduction=combo.grad_reduction, bucket_mb=BUCKET_MB,
         collective_matmul=combo.collective_matmul,
         dcn_compression=combo.dcn_compression,
     )
@@ -824,9 +799,8 @@ def _build_sp_lm(combo: Combo, devices):
     # expectation builder serves it like the image engines (one copy
     # of the monolithic-compressed/bucketed/overlapped plan logic).
     plans, n_seg, _ = _reducer_plans(
-        gpt_lm(cfg), combo.grad_reduction, bmb,
+        gpt_lm(cfg), combo.grad_reduction,
         facts["ici_size"], facts["dcn_size"], combo.dcn_compression,
-        combo.overlap_stages,
     )
     dcn_records = (
         jaxpr_ppermute_records(eng.train_step, ts, ids, tg,
@@ -1177,9 +1151,7 @@ def _build_plan(combo: Combo, devices):
     chunks = plan.pp * plan.virtual_stages
     if cfg.num_layers % chunks:
         # Deep-pipeline specs (pp8 at S8) and interleaved ones need a
-        # chunk-divisible stack; widen the proxy to pp*V layers — the
-        # same proxy-fits-the-grid compromise as space._BUCKET_GRID's
-        # sub-MB values. `cost.plan_combo_compute_s` mirrors this.
+        # chunk-divisible stack; widen the proxy to pp*V layers.
         import dataclasses as _dc
 
         cfg = _dc.replace(cfg, num_layers=chunks)
@@ -1232,9 +1204,7 @@ _BUILDERS: dict = {
 
 def lower_combo(combo: Combo, devices=None):
     """Lower one combo through its builder: (LintTarget, compiled HLO
-    text, mesh). Shared by the rule driver (`lint_combo`) and the cost
-    engine (`observability/cost.combo_cost`) so both judge the SAME
-    lowered program."""
+    text, mesh) — what the rule driver (`lint_combo`) judges."""
     import jax
 
     devices = list(devices if devices is not None else jax.devices())
@@ -1333,10 +1303,8 @@ def full_matrix() -> List[Combo]:
     combos.append(Combo("plan", 8, plan="pp2xsp2xfsdp2"))
     # Scheduled tick programs (ISSUE 20): the 1f1b 3-axis plan, the
     # interleaved V=2 plan over the fsdp per-parameter layout, and the
-    # plangate sched cell's gpipe/1f1b twins at M=4 (M just above pp)
-    # — plan-wire-fabric pins the per-schedule static ppermute count,
-    # and the M4 rows are what bench.py --plan-microbench reconciles
-    # its schedule column against.
+    # gpipe/1f1b twins at M=4 (M just above pp) — plan-wire-fabric
+    # pins the per-schedule static ppermute count.
     combos.append(Combo("plan", 8, plan="pp2-1f1bxsp2xdp2"))
     combos.append(Combo("plan", 8, plan="pp2-int2xfsdp4"))
     combos.append(

@@ -107,11 +107,6 @@ def build_parser() -> argparse.ArgumentParser:
                              "surface (cli/lm.py --plan)")
     add_grad_reduction_flags(parser)
     add_checkpoint_flags(parser)
-    from distributed_model_parallel_tpu.tuning.apply import (
-        add_auto_tune_flags,
-    )
-
-    add_auto_tune_flags(parser)
     parser.add_argument("--max-restarts", default=0, type=int,
                         help="fail-fast elastic mode: restart from the "
                              "per-epoch checkpoint up to N times on "
@@ -152,17 +147,6 @@ def main(argv=None) -> dict:
             )
         if not os.path.exists(args.finetune):
             raise SystemExit(f"--finetune: no such file {args.finetune!r}")
-    if args.auto_tune:
-        # BEFORE the knob guards below: the tuner writes the chosen
-        # knobs onto args and an inconsistent plan must still hit
-        # every existing fail-fast check. Needs the device world, so
-        # the (idempotent) backend init moves up.
-        from distributed_model_parallel_tpu.tuning.apply import (
-            auto_tune_data_parallel,
-        )
-
-        initialize_backend()
-        auto_tune_data_parallel(args)
     _plan = None
     if args.plan:
         from distributed_model_parallel_tpu.parallel.plan import (

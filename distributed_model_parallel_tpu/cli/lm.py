@@ -156,7 +156,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "overlaps the partial dot already on hand "
                         "(same math; requires --ffn-dim divisible by "
                         "--seq-shards)")
-    p.add_argument("--plan", default=None, metavar="SPEC|auto",
+    p.add_argument("--plan", default=None, metavar="SPEC",
                    help="composed ParallelPlan spec (parallel/plan.py, "
                         "ISSUE 19/20): one declarative mesh "
                         "factorization — tokens ppN/spN/dpN/fsdpN "
@@ -167,15 +167,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "build_plan_engine (degenerate specs route to "
                         "the single-axis engines). Replaces the "
                         "per-axis flags (--pipeline-stages, "
-                        "--seq-shards); 'auto' lets --auto-tune pick "
-                        "the spec from the plan family's search space")
+                        "--seq-shards)")
     add_grad_reduction_flags(p)
     add_checkpoint_flags(p)
-    from distributed_model_parallel_tpu.tuning.apply import (
-        add_auto_tune_flags,
-    )
-
-    add_auto_tune_flags(p)
     p.add_argument("--dtype", default="float32",
                    choices=("float32", "bfloat16"))
     p.add_argument("--remat", action="store_true")
@@ -270,19 +264,9 @@ def main(argv=None) -> dict:
 
     setup_metrics_out(args.metrics_out)  # fail fast on a bad directory
     initialize_backend()
-    if args.auto_tune:
-        # BEFORE the knob guards below: the tuner writes the chosen
-        # knobs onto args, and an inconsistent plan must still hit
-        # every existing fail-fast check.
-        from distributed_model_parallel_tpu.tuning.apply import (
-            auto_tune_lm,
-        )
-
-        auto_tune_lm(args)
     model_cfg = None
     if args.model_config:
-        # AFTER the tuner (it may write --plan / --seq-shards onto args)
-        # and before every other guard: the conflict named is this one.
+        # Before every other guard: the conflict named is this one.
         model_cfg = _model_config(args, parser)
         args.vocab_size = model_cfg.vocab_size  # the corpus draws from it
     plan = None
@@ -291,12 +275,6 @@ def main(argv=None) -> dict:
             parse_plan,
         )
 
-        if args.plan == "auto":
-            raise SystemExit(
-                "--plan auto rides the tuner: add --auto-tune search "
-                "(or --auto-tune PLAN.json) to pick the spec from the "
-                "plan family's search space"
-            )
         try:
             plan = parse_plan(args.plan)
         except ValueError as e:
@@ -326,7 +304,7 @@ def main(argv=None) -> dict:
             raise SystemExit(
                 f"plan {plan.spec}: the CLI's expert surface is "
                 "--moe-experts/--moe-dispatch (experts ride the data "
-                "fabric); the plan's ep field is the engine/tuner "
+                "fabric); the plan's ep field is the engine's "
                 "surface — drop the ep token"
             )
         if args.moe_experts > 0:

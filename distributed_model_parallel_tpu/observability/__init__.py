@@ -1,13 +1,8 @@
 """Observability: the span tracer (`trace.py` — host-side runtime
-timeline, Chrome trace export), the metrics registry (`metrics.py` —
-counters/gauges/histograms with streaming quantiles, Prometheus +
-JSON export, the ONE percentile rule), the static cost engine
-(`cost.py` — shared alpha-beta constants, closed-form composition
-formulas, and the per-combo predictor `tools/costgate` gates against
-`experiments/cost_ledger.json`), and the measured half that closes
-the loop: trace attribution (`attribution.py`), constant calibration
-from measured rows (`calibrate.py`), and the unified run report
-(`report.py`, `tools/obsreport`). INTERNALS.md §13–§14."""
+timeline, Chrome trace export; the benchmark reads its spans) and the
+metrics registry (`metrics.py` — counters/gauges/histograms with
+streaming quantiles, Prometheus + JSON export, the ONE percentile
+rule). Imports nothing else of the package. INTERNALS.md §13–§14."""
 
 from distributed_model_parallel_tpu.observability.metrics import (  # noqa: F401,E501
     MetricsRegistry,

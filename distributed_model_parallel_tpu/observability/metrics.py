@@ -7,10 +7,10 @@ things they did" (step-time / fetch-stall / checkpoint-blocked
 histograms in the Trainer, per-request queued/TTFT/per-token latency
 histograms plus goodput and occupancy in the serving path, snapshot vs
 background-write in the checkpoint writer). It is also the ONE home
-for percentile math: the serving scheduler's latency report and
-bench.py's p50/p99 columns both route through `exact_quantile`, so
-there is exactly one interpolation rule in the tree (pinned equal to
-`numpy.percentile`'s default linear rule on canned latencies).
+for percentile math: the serving scheduler's latency report routes
+through `exact_quantile`, so there is exactly one interpolation rule
+in the package (pinned equal to `numpy.percentile`'s default linear
+rule on canned latencies).
 
 Design constraints, same priority order as `trace.py`:
 
@@ -42,8 +42,7 @@ META-CHECK scans call sites with `scan_emitted_names` and fails
 collection naming any stray), so the exposition surface can never
 silently grow an undocumented series.
 
-No jax, no numpy: importable everywhere, including the jax-free
-analysis/report layers and the writer thread.
+No jax, no numpy: importable everywhere, including the writer thread.
 """
 
 from __future__ import annotations
@@ -427,7 +426,7 @@ class MetricsRegistry:
 
     def to_json(self) -> dict:
         """The machine twin of the exposition — what `--metrics-out`
-        writes and `tools/obsreport --metrics` ingests."""
+        writes."""
         with self._lock:
             return {
                 "counters": {

@@ -1,8 +1,7 @@
 """Host-side span tracer — nested spans + counters, Chrome trace export.
 
-The repo's perf story so far is STATIC (hlolint pins what the compiled
-program asks the network for; the cost engine prices it); this module is
-the RUNTIME half: what the host loops actually spent their time on.
+hlolint pins what the compiled program asks the network for (STATIC);
+this module is the RUNTIME half: what the host loops actually spent their time on.
 PyTorch's DDP is explained in the paper through its bucketed Reducer
 *timeline* — this is the instrument that lets our loops draw the same
 picture (Trainer phases, serving admission→prefill→decode→eviction,

@@ -19,6 +19,31 @@ _DEFAULT_CACHE_DIR = os.path.join(
     ".jax_cache",
 )
 
+# Peak bf16 matmul TFLOP/s per chip by TPU generation (public numbers);
+# MFU is measured FLOP/s divided by this. A device_kind that matches no
+# key is an error, never a default.
+PEAK_BF16_TFLOPS = {
+    "v4": 275.0,
+    "v5 lite": 197.0,
+    "v5e": 197.0,
+    "v5p": 459.0,
+    "v6 lite": 918.0,
+    "v6e": 918.0,
+}
+
+
+def peak_bf16_flops(device_kind: str) -> float:
+    kind = device_kind.lower()
+    for key, tflops in sorted(
+        PEAK_BF16_TFLOPS.items(), key=lambda kv: -len(kv[0])
+    ):
+        if key in kind:
+            return tflops * 1e12
+    raise ValueError(
+        f"no bf16 peak known for device_kind {device_kind!r}; add it to "
+        "PEAK_BF16_TFLOPS with its source before reporting an MFU"
+    )
+
 
 def force_cpu(n_devices: int = 8):
     """Force the cpu platform with >= n_devices virtual devices; returns

@@ -34,7 +34,7 @@ jax.config.update("jax_enable_compilation_cache", False)
 # Tier-1 budget guard: experiment sweeps (experiments/) time whole training
 # schedules and must only ever run under the `slow` marker. A test module
 # that imports experiments/ without marking every one of its tests slow
-# would silently blow the 870 s tier-1 window, so collection fails loudly.
+# would silently blow the tier-1 window, so collection fails loudly.
 _EXPERIMENTS_IMPORT = re.compile(
     r"^\s*(?:from|import)\s+experiments\b", re.MULTILINE
 )
@@ -71,8 +71,8 @@ def pytest_collection_modifyitems(config, items):
     # .span/.counter/.instant/.complete/.observe/.inc/.gauge) must
     # appear in the documented registries
     # (observability/metrics.py: TRACE_EVENT_NAMES / METRIC_NAMES) —
-    # an undocumented series is invisible to obsreport and to the
-    # exposition surface's consumers. Pure source scan, no items
+    # an undocumented series is invisible to the exposition surface's
+    # consumers. Pure source scan, no items
     # needed, so it runs on every collection; import is jax-free by
     # the metrics module's contract.
     from distributed_model_parallel_tpu.observability.metrics import (
@@ -88,27 +88,6 @@ def pytest_collection_modifyitems(config, items):
             + "; ".join(
                 f"{name} at {', '.join(sites)}"
                 for name, sites in sorted(strays.items())
-            )
-        )
-
-    # Tuner-knob META-CHECK: every knob the auto-tuner's search space
-    # enumerates (tuning/space.py SPACES) must correspond to a real
-    # CLI flag under cli/ AND a real engine dataclass field under
-    # parallel/ — a tuner searching over a phantom knob would emit
-    # plans nobody can apply. Literal source scan, jax-free by the
-    # space module's contract, runs on every collection.
-    from distributed_model_parallel_tpu.tuning.space import (
-        scan_knob_surface,
-    )
-
-    stray_knobs = scan_knob_surface()
-    if stray_knobs:
-        raise pytest.UsageError(
-            "every tuner knob must map to a real engine/CLI "
-            "parameter (tuning/space.py SPACES): "
-            + "; ".join(
-                f"{knob}: {', '.join(missing)}"
-                for knob, missing in sorted(stray_knobs.items())
             )
         )
 

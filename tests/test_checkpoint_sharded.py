@@ -144,14 +144,10 @@ def test_fsdp_reshard_restore_bit_exact(tmp_path, target):
     _assert_trees_equal(_host_tree(state), _host_tree(placed))
 
 
-@pytest.mark.slow
 def test_fsdp_reshard_post_restore_trajectory_twin(tmp_path):
     """3-step post-restore trajectory at S=8 == the same 3 steps from
     the un-checkpointed state placed at S=8 directly — the checkpoint
-    round trip adds exactly nothing. `slow` (two FSDP train-step
-    compiles); tier-1 twin: test_fsdp_reshard_restore_bit_exact pins
-    the restored bytes and test_async_save_does_not_block_next_step
-    runs a post-save step."""
+    round trip adds exactly nothing."""
     rng = np.random.RandomState(0)
     batches = [
         (

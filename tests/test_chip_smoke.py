@@ -1,6 +1,6 @@
 """chip_smoke.py and the start-up code it guards: the no-chip refusal,
-the CPU rehearsal of every leg, where the compile cache goes, and a
-single host starting without a rendezvous.
+the CPU rehearsal of every leg, the peak table its MFU divides by, where
+the compile cache goes, and a single host starting without a rendezvous.
 """
 
 import os
@@ -107,6 +107,26 @@ def test_a_failing_leg_fails_the_run(tmp_path, capsys, monkeypatch):
     assert "leg barrier: PASS" in out
     assert "leg train" not in out and "leg serve" not in out
     assert '"ok"' not in out
+
+
+# ------------------------------------------------ the peak an MFU divides by
+
+
+@pytest.mark.parametrize("kind, tflops", [
+    ("TPU v5 lite", 197.0),  # as JAX reports a v5e (chip run, PR 21)
+    ("TPU v5e", 197.0), ("TPU v5p", 459.0), ("TPU v4", 275.0),
+])
+def test_known_device_kinds_have_a_peak(kind, tflops):
+    assert platform.peak_bf16_flops(kind) == tflops * 1e12
+
+
+def test_unknown_device_kind_is_an_error():
+    """An MFU against a guessed peak is worse than none: a device the
+    table does not know raises, naming it."""
+    with pytest.raises(ValueError, match="TPU v9 hyper"):
+        platform.peak_bf16_flops("TPU v9 hyper")
+    with pytest.raises(ValueError, match="cpu"):
+        platform.peak_bf16_flops("cpu")
 
 
 # --------------------------------------------------- compile cache placement

@@ -679,13 +679,9 @@ def test_serve_cli_tp_collective_matmul():
     assert result["serving"]["collective_matmul"] is True
 
 
-@pytest.mark.slow
 def test_serve_cli_sp():
     """--layout sp drives the full serving entry point: ring-attention
-    prefill + online-softmax decode over the 'seq'-sharded cache.
-    `slow` (tier-1 budget); tier-1 twins: tests/test_serving.py::
-    test_decode_matches_dense_sp (the engine math) and
-    test_serve_cli_replicated (the entry point)."""
+    prefill + online-softmax decode over the 'seq'-sharded cache."""
     from distributed_model_parallel_tpu.cli import serve
 
     result = serve.main([
@@ -809,17 +805,11 @@ def test_serve_cli_paged_prefix(tmp_path):
     assert "serve_prefix_hits_total" in exported["counters"]
 
 
-@pytest.mark.slow
 def test_serve_cli_sampling_greedy_bitstable():
     """--temperature 0 (the default) is bit-stable: the sampled-path
     flags left at their defaults produce byte-identical tokens to a
     plain greedy run, and a --temperature run is deterministic for a
-    fixed --seed (per-slot PRNG lanes, serving/sampling.py). `slow`
-    (tier-1 budget); tier-1 twins: tests/test_serving_paged.py::
-    test_sampling_greedy_default_bit_stable +
-    test_sampling_deterministic_per_slot_lane (the engine-level pins
-    on the same sampler) and test_serving_flag_guards (the CLI flag
-    surface)."""
+    fixed --seed (per-slot PRNG lanes, serving/sampling.py)."""
     from distributed_model_parallel_tpu.cli import serve
 
     base = [
@@ -1014,8 +1004,6 @@ def test_lm_cli_plan_flag_guards():
 
     with pytest.raises(SystemExit, match="bad plan token"):
         lm.main(["--plan", "zz4"])
-    with pytest.raises(SystemExit, match="rides the tuner"):
-        lm.main(["--plan", "auto"])  # auto without --auto-tune search
     with pytest.raises(SystemExit, match="IS the mesh factorization"):
         lm.main(["--plan", "pp2xdp4", "--pipeline-stages", "2"])
     with pytest.raises(SystemExit, match="IS the mesh factorization"):
@@ -1046,9 +1034,26 @@ def test_lm_cli_plan_flag_guards():
     with pytest.raises(SystemExit, match="seq"):
         lm.main(["--plan", "sp4xdp2", "--seq-len", "30",
                  "-b", "8", "--corpus-tokens", "4096"])
-    # --plan is mutually exclusive with --auto-tune owning the knobs
-    with pytest.raises(SystemExit, match="--plan"):
-        lm.main(["--plan", "dp8", "--auto-tune", "search"])
+
+
+@pytest.mark.parametrize("cli, argv, said", [
+    ("lm", ["--auto-tune", "search"], "unrecognized arguments"),
+    ("lm", ["--plan", "auto"], "bad plan token"),
+    ("data_parallel", ["--auto-tune", "search"],
+     "unrecognized arguments"),
+], ids=["lm --auto-tune search", "lm --plan auto",
+        "data_parallel --auto-tune search"])
+def test_removed_tuner_switches_are_refused(cli, argv, said, capsys):
+    """No model of speed sets a knob: the tuner's switches are gone
+    from both training parsers, refused as any unknown flag or plan
+    token is, before a backend starts."""
+    from distributed_model_parallel_tpu.cli import lm
+
+    main = {"lm": lm.main, "data_parallel": data_parallel.main}[cli]
+    with pytest.raises(SystemExit) as exc:
+        main(argv)
+    assert exc.value.code not in (0, None)
+    assert said in str(exc.value.code) + capsys.readouterr().err
 
 
 def test_lm_cli_scheduled_plan_guards():
@@ -1133,15 +1138,10 @@ def test_lm_cli_plan_says_which_local_attention(
     assert "train_local_attention_flash" in metrics.METRIC_NAMES
 
 
-@pytest.mark.slow
 def test_lm_cli_plan_now_legal_combos(tmp_path, monkeypatch):
     """Combos the pre-plan guards refused are legal under a plan that
     licenses them: --microbatches with a ppN plan (the composed tick
-    loop's M), and ring attention knobs with an spN plan. `slow`
-    (tier-1 budget: two composed CLI mains); tier-1 twin:
-    test_lm_cli_composed_plan_e2e (the same build_plan_engine CLI
-    path) + test_lm_cli_plan_flag_guards (the refusal side of the
-    same guard block)."""
+    loop's M), and ring attention knobs with an spN plan."""
     from distributed_model_parallel_tpu.cli import lm
 
     monkeypatch.chdir(tmp_path)
@@ -1187,12 +1187,6 @@ def test_data_parallel_cli_plan_guards():
         data_parallel.main([
             "--plan", "dp64", "--model", "tinycnn",
             "-type", "Synthetic",
-        ])
-    with pytest.raises(SystemExit, match="--plan"):
-        data_parallel.main([
-            "--engine", "ddp", "--plan", "dp8",
-            "--auto-tune", "search",
-            "--model", "tinycnn", "-type", "Synthetic",
         ])
 
 

@@ -182,14 +182,10 @@ def test_lm_loss_fn_binds_pad_id():
     np.testing.assert_allclose(float(bound), float(explicit))
 
 
-@pytest.mark.slow
 def test_causal_lm_sequence_parallel_matches_dense():
     """CausalLMSequenceParallelEngine (data=2, seq=4) follows the SAME
     trajectory as a dense jit LM step: per-shard next-token loss sums +
-    one grad psum equal the dense mean-loss gradient exactly. `slow`
-    (tier-1 budget); tier-1 twin:
-    test_sequence_parallel.test_sequence_parallel_engine_matches_dense_dp
-    (the same engine-vs-dense parity on the encoder stack)."""
+    one grad psum equal the dense mean-loss gradient exactly."""
     from distributed_model_parallel_tpu.parallel.sequence_parallel import (
         CausalLMSequenceParallelEngine,
     )

@@ -30,8 +30,7 @@ GOLDEN_JSON = os.path.join(
 
 def build_golden_registry() -> MetricsRegistry:
     """The exact canned series the committed exposition goldens pin
-    (also the --metrics side of the obsreport pre-gate inputs; the
-    generator that wrote the goldens invoked this builder)."""
+    (the generator that wrote the goldens invoked this builder)."""
     reg = MetricsRegistry(enabled=True)
     for v in (0.02, 0.02, 0.02, 0.02):
         reg.observe("train_step_s", v)
@@ -53,8 +52,8 @@ def build_golden_registry() -> MetricsRegistry:
 
 def test_exact_quantile_matches_numpy_percentile():
     """The shared rule is bit-equal to numpy's default linear method —
-    the regression pin that let the scheduler and bench.py drop their
-    private numpy calls."""
+    the regression pin that let the scheduler drop its private numpy
+    calls."""
     rng = random.Random(0)
     for n in (1, 2, 3, 5, 17, 100):
         xs = [rng.uniform(0.0, 50.0) for _ in range(n)]
@@ -231,6 +230,24 @@ def test_every_emitted_name_is_documented():
     """Unit twin of the conftest META-CHECK: scanning the package for
     span/counter/metric emission sites finds no undocumented name."""
     assert metrics.scan_emitted_names() == {}
+
+
+@pytest.mark.parametrize("name", sorted(metrics.METRIC_NAMES))
+def test_documented_metric_is_emitted(name):
+    """The other direction: no dead name in the registry. A tiny run of
+    the program with the registry on (`test_observability.py` makes
+    them, once per process) leaves each documented series behind."""
+    import test_observability as runs
+
+    assert any(
+        name in series
+        for run in (
+            runs.trainer_epoch, runs.fsdp_plan_epoch,
+            runs.held_experts_epoch, runs.paged_drain,
+            runs.speculative_drain,
+        )
+        for series in run().metrics.values()
+    )
 
 
 def test_scanner_catches_a_stray(tmp_path):

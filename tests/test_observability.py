@@ -1,26 +1,24 @@
 """Observability subsystem (INTERNALS.md §13): the span tracer's
 nesting/export contract against a committed Chrome-trace golden file
-(deterministic clock injected — no wall time in any assertion), the
-static cost engine's closed-form predictions pinned for hand-computed
-combos, the costgate's regression/missing-row/tolerance semantics as
-pure-function tests, and a Trainer-phase-timing smoke on the virtual
-mesh."""
+(deterministic clock injected — no wall time in any assertion), every
+documented trace event held to a run of the program that emits it, the
+Trainer's and the scheduler's telemetry on the virtual mesh, and the
+package's layering as a source scan."""
 
+import dataclasses
+import functools
 import json
 import os
+import tempfile
 
+import jax
 import numpy as np
 import pytest
 
-from distributed_model_parallel_tpu.observability import (
-    cost,
-    metrics,
-    trace,
-)
-from distributed_model_parallel_tpu.observability.costgate import (
-    gate_check,
-    make_ledger,
-)
+from distributed_model_parallel_tpu.models.gpt import GPTConfig
+from distributed_model_parallel_tpu.observability import metrics, trace
+from distributed_model_parallel_tpu.serving.engine import ServingEngine
+from distributed_model_parallel_tpu.serving.scheduler import Request
 
 GOLDEN = os.path.join(
     os.path.dirname(__file__), "golden", "chrome_trace.json"
@@ -114,259 +112,60 @@ def test_tracer_thread_safety_and_thread_tracks():
     assert {e["tid"] for e in events} <= set(range(5))
 
 
-# -------------------------------------------------------- cost engine
+# --------------------------------- tiny runs with both instruments on
+#
+# What the program emits is read from its own runs: each run below is
+# made once per process with a fresh tracer and registry on, and
+# `test_documented_span_is_emitted` here and
+# `test_metrics.py::test_documented_metric_is_emitted` hold every name
+# of the two registries to the union of what the runs left behind.
+# (`scan_emitted_names` holds the other direction: emitted, so
+# documented.)
 
 
-def test_cost_flat_ring_hand_computed():
-    # 100 MB over a flat 64-ring, 161 unfused ops (the scaling64 §3a
-    # shape): beta = 2*63/64 * 100e6/100e9 = 1.96875 ms; alpha =
-    # 161 * 2*63 * 1us = 20.286 ms.
-    got = cost.ring_all_reduce_s(100e6, 64, n_ops=161)
-    assert got == pytest.approx(0.00196875 + 0.020286, rel=1e-12)
-    # Bucketed (one fused op) keeps the beta, drops alpha to one ring.
-    got = cost.ring_all_reduce_s(100e6, 64, n_ops=1)
-    assert got == pytest.approx(0.00196875 + 0.000126, rel=1e-12)
+@dataclasses.dataclass(frozen=True)
+class Observed:
+    events: list   # the tracer's Chrome events
+    metrics: dict  # the registry's JSON export
+    result: object  # what the run returned
+
+    def names(self) -> set:
+        return {e["name"] for e in self.events}
 
 
-def test_cost_hierarchical_two_level_hand_computed():
-    # 100 MB over 2 x 32 dcn x ici, 4 buckets: ici beta 2*31/32 *
-    # 100e6/100e9 = 1.9375 ms; dcn beta 2*(1/2) * (100e6/32)/25e9 =
-    # 0.125 ms; alpha 4 * (2*31*1us + 2*1*10us) = 0.328 ms.
-    got = cost.two_level_all_reduce_s(100e6, 32, 2, n_buckets=4)
-    assert got == pytest.approx(
-        0.0019375 + 0.000125 + 0.000328, rel=1e-12
+def observed(run) -> Observed:
+    tracer = trace.Tracer(enabled=True)
+    reg = metrics.MetricsRegistry(enabled=True)
+    trace.set_tracer(tracer)
+    metrics.set_metrics(reg)
+    try:
+        result = run()
+    finally:
+        trace.set_tracer(None)
+        metrics.set_metrics(None)
+    return Observed(tracer.to_chrome()["traceEvents"], reg.to_json(),
+                    result)
+
+
+def _fit(engine, batches, **config):
+    from distributed_model_parallel_tpu.training.trainer import (
+        Trainer,
+        TrainerConfig,
     )
 
-
-def test_cost_int8_wire_hand_computed():
-    # Same combo on the int8 wire: the dcn leg quarters (0.03125 ms)
-    # and each of the 4 buckets pays one extra sidecar hop pair per
-    # payload hop: alpha = 4 * (2*31*1us + 2*2*1*10us) = 0.408 ms.
-    got = cost.two_level_all_reduce_s(
-        100e6, 32, 2, n_buckets=4, wire="int8"
-    )
-    assert got == pytest.approx(
-        0.0019375 + 0.00003125 + 0.000408, rel=1e-12
-    )
+    with tempfile.TemporaryDirectory() as tmp:
+        cfg = TrainerConfig(
+            epochs=1, print_freq=1, save_best=False,
+            checkpoint_dir=tmp, log_dir=tmp, **config,
+        )
+        trainer = Trainer(engine, batches, None, cfg,
+                          rng=jax.random.PRNGKey(0))
+        return observed(trainer.fit)
 
 
-def test_cost_moe_exchange_flat_vs_hierarchical():
-    # The §3c MoE shape: 12.5M bf16 elements over 2 x 32. The
-    # hierarchical exchange drops (K-1)*I = 32 dcn hops to 1 and keeps
-    # the dcn bytes equal — so it must be strictly cheaper.
-    elems = 12_500_000
-    flat = cost.flat_all_to_all_s(elems, 2, 32, 2)
-    hier = cost.hierarchical_all_to_all_s(elems, 2, 32, 2)
-    assert hier < flat
-    # int8 wire quarters only the dcn leg of the bf16 payload.
-    hier_int8 = cost.hierarchical_all_to_all_s(
-        elems, 2, 32, 2, wire="int8"
-    )
-    dcn_leg = (1 / 2) * elems * 2 / cost.BW_DCN_EFFECTIVE
-    assert hier - hier_int8 == pytest.approx(dcn_leg / 2, rel=1e-9)
-
-
-def test_cost_plan_bubble_factor_hand_computed():
-    """The scheduled-plan bubble (ISSUE 20): (VM+pp-1)/(VM) with V
-    only counting for the interleaved schedule and M defaulting to
-    pp*V — so gpipe/1f1b twins at one M share a bubble and the
-    interleaved twin's is strictly smaller; pp=1 has no bubble."""
-    assert cost.plan_bubble_factor(1) == 1.0
-    assert cost.plan_bubble_factor(2) == pytest.approx(1.5)  # M=pp
-    assert cost.plan_bubble_factor(2, "gpipe", 1, 4) \
-        == pytest.approx(1.25)
-    assert cost.plan_bubble_factor(2, "1f1b", 1, 4) \
-        == pytest.approx(1.25)
-    assert cost.plan_bubble_factor(2, "interleaved", 2, 4) \
-        == pytest.approx(1.125)
-    # default M = pp*V for interleaved: (pp*V*V... ) = (8+1)/8
-    assert cost.plan_bubble_factor(2, "interleaved", 2) \
-        == pytest.approx(1.125)
-
-
-def test_cost_composed_plan_step_schedule_terms():
-    """`composed_plan_step_s` stays byte-stable for pre-ISSUE-20
-    callers (gpipe defaults price the old M+pp-1 wire ticks) and the
-    scheduled closed form honestly prices MORE wire ticks
-    (2MV + 2(pp-1)) while the compute term folds the bubble — the
-    cross-schedule win lives in the lowered tier where comm is
-    schedule-symmetric."""
-    args = (2, 1, 4, 1_000_000, 4, 128, 64, 1000, 8, 8, 1)
-    base = cost.composed_plan_step_s(*args)
-    assert base == cost.composed_plan_step_s(
-        *args, schedule="gpipe", virtual_stages=1,
-        num_microbatches=0, compute_s=0.0,
-    )
-    sched = cost.composed_plan_step_s(
-        *args, schedule="1f1b", num_microbatches=4,
-    )
-    assert sched > cost.composed_plan_step_s(*args, num_microbatches=4)
-    # the compute fold is compute_s * bubble, additively
-    with_c = cost.composed_plan_step_s(
-        *args, schedule="1f1b", num_microbatches=4, compute_s=1.0,
-    )
-    assert with_c - sched == pytest.approx(
-        cost.plan_bubble_factor(2, "1f1b", 1, 4), rel=1e-9,
-    )
-
-
-def test_predict_collectives_walker_hand_computed():
-    """The HLO walker's per-kind pricing on a hand-built module: one
-    ring hop within 'ici', one all-reduce crossing 'dcn'."""
-    from distributed_model_parallel_tpu.analysis.collectives import (
-        MeshModel,
-        classify_instruction,
-    )
-    from distributed_model_parallel_tpu.analysis.hlo import (
-        Buffer,
-        Instruction,
-    )
-
-    mesh = MeshModel(
-        axis_names=("dcn", "ici"),
-        shape=(2, 4),
-        coords={
-            i: (i // 4, i % 4) for i in range(8)
-        },
-    )
-    hop = Instruction(
-        name="cp.1", op="collective-permute",
-        buffers=(Buffer("f32", (1024,)),), refs=frozenset(),
-        op_name="", computation="main",
-        source_target_pairs=((0, 1), (1, 2), (2, 3), (3, 0)),
-    )
-    ar = Instruction(
-        name="ar.1", op="all-reduce",
-        buffers=(Buffer("f32", (256,)),), refs=frozenset(),
-        op_name="", computation="main",
-        replica_groups=((0, 4), (1, 5), (2, 6), (3, 7)),
-    )
-    cols = [
-        classify_instruction(hop, mesh),
-        classify_instruction(ar, mesh),
-    ]
-    out = cost.predict_collectives(cols, mesh, dcn_axis="dcn")
-    # hop: 4096 B within {ici} -> alpha 1us, beta 4096/100e9.
-    # ar: 1024 B across {dcn} (group 2) -> alpha 2*1*10us, beta
-    #     2*(1/2)*1024/25e9.
-    assert out.n_collectives == 2
-    assert out.alpha_s == pytest.approx(1e-6 + 2e-5, rel=1e-12)
-    assert out.beta_s == pytest.approx(
-        4096 / 100e9 + 1024 / 25e9, rel=1e-12
-    )
-    assert out.bytes_by_fabric == {"ici": 4096, "dcn": 1024}
-
-
-def test_combo_cost_row_shape():
-    """One cheap op-level combo through the real lower+classify+predict
-    path (the costgate pre-gate's unit of work)."""
-    from distributed_model_parallel_tpu.analysis.lint import Combo
-
-    row = cost.combo_cost(Combo("cm_ag", 2))
-    assert row["predicted_step_s"] > 0
-    assert row["n_collectives"] >= 1
-    assert set(row) >= {
-        "predicted_step_s", "alpha_s", "beta_s", "n_collectives",
-        "bytes_by_fabric",
-    }
-
-
-# ----------------------------------------------------------- costgate
-
-
-def _ledger(rows):
-    return make_ledger(rows, tolerance=0.05)
-
-
-def test_costgate_regression_detected_and_named():
-    ledger = _ledger({"ddp/S4/bucketed": {"predicted_step_s": 1e-3}})
-    fails = gate_check(
-        ledger, {"ddp/S4/bucketed": {"predicted_step_s": 1.2e-3}}
-    )
-    assert len(fails) == 1
-    assert "ddp/S4/bucketed" in fails[0]
-    assert "regressed" in fails[0]
-
-
-def test_costgate_tolerance_boundary():
-    ledger = _ledger({"x": {"predicted_step_s": 1e-3}})
-    # Within tolerance (exactly +5%) passes; just past it fails.
-    assert gate_check(ledger, {"x": {"predicted_step_s": 1.05e-3}}) \
-        == []
-    assert gate_check(ledger, {"x": {"predicted_step_s": 1.06e-3}})
-    # Improvements always pass.
-    assert gate_check(ledger, {"x": {"predicted_step_s": 0.5e-3}}) \
-        == []
-
-
-def test_costgate_missing_row_fails_for_new_combo():
-    ledger = _ledger({"x": {"predicted_step_s": 1e-3}})
-    fails = gate_check(
-        ledger,
-        {"x": {"predicted_step_s": 1e-3},
-         "new/S2": {"predicted_step_s": 1e-3}},
-    )
-    assert len(fails) == 1 and "new/S2" in fails[0] \
-        and "no ledger row" in fails[0]
-    # The pre-gate's name check catches combos that were not lowered.
-    fails = gate_check(
-        ledger, {"x": {"predicted_step_s": 1e-3}},
-        require_rows_for=["x", "unlowered/S8"],
-    )
-    assert len(fails) == 1 and "unlowered/S8" in fails[0]
-
-
-def test_costgate_subset_update_refuses_drifted_constants(tmp_path):
-    """A --filter/--pregate --update onto a ledger priced under
-    different constants must refuse BEFORE lowering anything: merging
-    would keep the un-lowered rows at the old physics while stamping
-    the file with the new constants."""
-    from distributed_model_parallel_tpu.observability import costgate
-
-    ledger = _ledger({"x": {"predicted_step_s": 1e-3}})
-    ledger["constants"]["alpha_hop_s"] = 123.0
-    path = tmp_path / "ledger.json"
-    path.write_text(json.dumps(ledger))
-    rc = costgate.main([
-        "--update", "--filter", "cm_ag/S2", "--ledger", str(path),
-    ])
-    assert rc == 2
-    # The refusal left the drifted ledger untouched.
-    assert json.loads(path.read_text()) == ledger
-
-
-def test_costgate_constants_drift_fails():
-    ledger = _ledger({"x": {"predicted_step_s": 1e-3}})
-    ledger["constants"]["alpha_hop_s"] = 2e-6
-    fails = gate_check(ledger, {"x": {"predicted_step_s": 1e-3}})
-    assert len(fails) == 1 and "alpha_hop_s" in fails[0]
-
-
-def test_committed_ledger_covers_the_full_matrix():
-    """The acceptance pin: experiments/cost_ledger.json carries a row
-    for EVERY combo in the hlolint matrix, under the current
-    constants."""
-    from distributed_model_parallel_tpu.analysis.lint import full_matrix
-    from distributed_model_parallel_tpu.observability.costgate import (
-        DEFAULT_LEDGER,
-        load_ledger,
-    )
-
-    ledger = load_ledger(DEFAULT_LEDGER)
-    assert gate_check(
-        ledger, {}, require_rows_for=[c.name for c in full_matrix()]
-    ) == []
-
-
-# ------------------------------------------- trainer + serving smokes
-
-
-def test_trainer_phase_spans_smoke(tmp_path, devices):
-    """Trainer phase timing on the virtual mesh: one tiny epoch with a
-    sharded async checkpoint must leave fetch/step/sync spans plus the
-    checkpoint-blocked / snapshot / background-write trio."""
-    import jax
-
+@functools.cache
+def trainer_epoch() -> Observed:
+    """Two batches through the Trainer, then a sharded async save."""
     from distributed_model_parallel_tpu.models.tinycnn import tiny_cnn
     from distributed_model_parallel_tpu.parallel.data_parallel import (
         DataParallelEngine,
@@ -376,160 +175,173 @@ def test_trainer_phase_spans_smoke(tmp_path, devices):
         make_mesh,
     )
     from distributed_model_parallel_tpu.training.optim import SGD
-    from distributed_model_parallel_tpu.training.trainer import (
-        Trainer,
-        TrainerConfig,
+
+    mesh = make_mesh(MeshSpec(data=2), devices=jax.devices()[:2])
+    engine = DataParallelEngine(tiny_cnn(10), SGD(), mesh)
+    rng = np.random.RandomState(0)
+    batches = [
+        (
+            rng.rand(8, 8, 8, 3).astype(np.float32),
+            rng.randint(0, 10, 8).astype(np.int32),
+        )
+        for _ in range(2)
+    ]
+    return _fit(engine, batches, save_last=True,
+                checkpoint_format="sharded", async_save=True)
+
+
+def _lm_batches(vocab, batch, seq_len):
+    ids = np.random.RandomState(0).randint(
+        1, vocab, size=(batch, seq_len)
+    ).astype(np.int32)
+    return [(ids, ids)] * 2
+
+
+@functools.cache
+def fsdp_plan_epoch() -> Observed:
+    """A plan whose sequence is whole on a chip: the engine says which
+    local attention its step holds."""
+    from distributed_model_parallel_tpu.parallel.plan import (
+        build_plan_engine,
     )
+    from distributed_model_parallel_tpu.training.optim import SGD
 
-    tracer = trace.Tracer(enabled=True)
-    trace.set_tracer(tracer)
-    reg = metrics.MetricsRegistry(enabled=True)
-    metrics.set_metrics(reg)
-    try:
-        mesh = make_mesh(MeshSpec(data=2), devices=devices[:2])
-        engine = DataParallelEngine(tiny_cnn(10), SGD(), mesh)
-        rng = np.random.RandomState(0)
-        batches = [
-            (
-                rng.rand(8, 8, 8, 3).astype(np.float32),
-                rng.randint(0, 10, 8).astype(np.int32),
-            )
-            for _ in range(2)
-        ]
-        cfg = TrainerConfig(
-            epochs=1, print_freq=1, save_best=False, save_last=True,
-            checkpoint_format="sharded", async_save=True,
-            checkpoint_dir=str(tmp_path), log_dir=str(tmp_path),
-        )
-        trainer = Trainer(engine, batches, None, cfg,
-                          rng=jax.random.PRNGKey(0))
-        trainer.fit()
-        names = {
-            e["name"] for e in tracer.to_chrome()["traceEvents"]
-        }
-        assert {
-            "fetch", "step", "sync", "checkpoint_blocked",
-            "ckpt_snapshot", "ckpt_background_write",
-        } <= names
-        # The metrics registry mirrors the phases as distributions
-        # (tentpole wiring: step-time / fetch / checkpoint-blocked
-        # histograms plus the checkpoint writer pair).
-        exported = reg.to_json()
-        assert {
-            "train_fetch_s", "train_step_s",
-            "train_checkpoint_blocked_s", "ckpt_snapshot_s",
-            "ckpt_background_write_s",
-        } <= set(exported["histograms"])
-        assert reg.histogram("train_step_s").count == 2
-        assert exported["counters"]["train_batches_total"] == 2
-        # And the REAL CPU-mesh trace renders through obsreport: the
-        # attribution covers the trainer+checkpoint phases, the
-        # residual is finite, and the measured-vs-predicted row keys
-        # on a live ledger combo (acceptance: the report pipeline
-        # works on an actual run, not just the canned golden).
-        from distributed_model_parallel_tpu.observability import (
-            attribution,
-            report,
-        )
-        from distributed_model_parallel_tpu.observability.costgate import (
-            DEFAULT_LEDGER,
-            load_ledger,
-        )
-
-        chrome = tracer.to_chrome()
-        attr = attribution.attribute(chrome)
-        assert {"fetch", "step", "sync", "checkpoint_blocked"} <= {
-            p.name for p in attr.phases
-        }
-        assert 0.0 <= attr.residual_share < 1.0
-        rendered = report.render_report(
-            chrome, metrics=exported, ledger=load_ledger(DEFAULT_LEDGER),
-            combos=["ddp/S4/dcn2/bucketed"],
-        )
-        assert "unattributed residual" in rendered
-        assert "ddp/S4/dcn2/bucketed" in rendered
-        assert "train_step_s" in rendered
-    finally:
-        trace.set_tracer(None)
-        metrics.set_metrics(None)
+    engine = build_plan_engine(
+        SERVE_CFG, SGD(), "fsdp2", devices=jax.devices()[:2],
+        min_shard_elems=64,
+    )
+    return _fit(engine, _lm_batches(SERVE_CFG.vocab_size, 4, 16))
 
 
-def test_serving_telemetry_and_request_spans(devices):
+@functools.cache
+def held_experts_epoch() -> Observed:
+    """The family whose sparse layer holds a range of the experts, two
+    layers of it, behind the engine `cli.lm --model-config` builds."""
+    from test_kimi_linear import TOY, engine_for
+
+    from distributed_model_parallel_tpu.models import kimi_linear
+
+    cfg = kimi_linear.config_from_dict({**TOY, "num_hidden_layers": 2})
+    return _fit(engine_for(cfg), _lm_batches(cfg.vocab_size, 2, 32))
+
+
+SERVE_CFG = GPTConfig(
+    vocab_size=32, dim=16, num_layers=1, num_heads=2, ffn_dim=32,
+    max_position=16, dropout_rate=0.0,
+)
+PAGED = dict(num_slots=2, max_len=16, prefill_len=8, page_size=4,
+             prefill_chunk=4)
+
+
+def _drain(eng, params, reqs, **kw) -> Observed:
+    def run():
+        sched = eng.run(params, reqs, **kw)
+        sched.latency_report()  # the goodput gauge is set here
+        return sched
+
+    return observed(run)
+
+
+def _prompt(seed, n):
+    return np.random.RandomState(seed).randint(
+        1, SERVE_CFG.vocab_size, size=n
+    ).astype(np.int32)
+
+
+@functools.cache
+def contiguous_drain() -> Observed:
+    eng = ServingEngine(
+        SERVE_CFG, None, layout="replicated", num_slots=2, max_len=16,
+        prefill_len=4,
+    )
+    params = eng.init_params(jax.random.PRNGKey(0))
+    reqs = [
+        Request(rid=i, prompt=_prompt(i, 3), max_new_tokens=3)
+        for i in range(3)
+    ]
+    return _drain(eng, params, reqs)
+
+
+@functools.cache
+def paged_drain() -> Observed:
+    """Chunked prefill over pages with the prefix cache: more requests
+    than slots, and the third repeats the first's prompt."""
+    eng = ServingEngine(SERVE_CFG, prefix_cache=True, **PAGED)
+    params = eng.init_params(jax.random.PRNGKey(0))
+    seven = _prompt(1, 7)
+    reqs = [
+        Request(rid="a", prompt=seven, max_new_tokens=4),
+        Request(rid="b", prompt=_prompt(2, 3), max_new_tokens=3),
+        Request(rid="c", prompt=seven, max_new_tokens=3),
+    ]
+    return _drain(eng, params, reqs)
+
+
+@functools.cache
+def speculative_drain() -> Observed:
+    target = ServingEngine(SERVE_CFG, speculative_k=2, **PAGED)
+    draft = ServingEngine(SERVE_CFG, **PAGED)
+    params = target.init_params(jax.random.PRNGKey(0))
+    dparams = draft.init_params(jax.random.PRNGKey(7))
+    reqs = [
+        Request(rid=i, prompt=_prompt(i, 3 + i), max_new_tokens=4)
+        for i in range(3)
+    ]
+    return _drain(target, params, reqs, draft=draft,
+                  draft_params=dparams)
+
+
+@pytest.mark.parametrize("name", sorted(metrics.TRACE_EVENT_NAMES))
+def test_documented_span_is_emitted(name):
+    """No dead name in the registry of trace events: a run of the
+    program leaves each one behind."""
+    runs = (trainer_epoch, contiguous_drain, paged_drain,
+            speculative_drain)
+    assert any(name in run().names() for run in runs)
+
+
+def test_trainer_epoch_counts_its_batches_and_its_save():
+    """The registry mirrors the Trainer's phases as distributions: one
+    sample a batch, one save that held the loop, its two halves."""
+    hist = trainer_epoch().metrics["histograms"]
+    assert hist["train_step_s"]["count"] == 2
+    assert hist["train_fetch_s"]["count"] == 2
+    assert hist["train_checkpoint_blocked_s"]["count"] == 1
+    assert hist["ckpt_snapshot_s"]["count"] == 1
+    assert hist["ckpt_background_write_s"]["count"] >= 1
+    assert trainer_epoch().metrics["counters"]["train_batches_total"] == 2
+
+
+def test_serving_telemetry_and_request_spans():
     """Scheduler telemetry: goodput / mean occupancy in the report and
-    the per-request queued/prefill/decode spans plus the per-step
-    occupancy counter in the trace."""
-    import jax
-
-    from distributed_model_parallel_tpu.models.gpt import GPTConfig
-    from distributed_model_parallel_tpu.serving.engine import (
-        ServingEngine,
+    the per-request queued/prefill/decode spans in the trace."""
+    run = contiguous_drain()
+    sched = run.result
+    rep = sched.latency_report()
+    assert rep["requests"] == 3
+    assert rep["decode_steps"] == len(sched.step_occupancy) > 0
+    assert 0 < rep["mean_batch_occupancy"] <= 2
+    assert 0 < rep["goodput"] <= 1
+    # goodput IS occupancy over capacity (each active slot yields
+    # one token per step).
+    assert rep["goodput"] == pytest.approx(
+        rep["mean_batch_occupancy"] / 2, abs=1e-3
     )
-    from distributed_model_parallel_tpu.serving.scheduler import (
-        Request,
+    # One queued+prefill+decode trio per finished request, each on
+    # its own named track.
+    queued = [e for e in run.events if e["name"] == "queued"]
+    assert len(queued) == len({e["tid"] for e in queued}) == 3
+    # Per-request histograms through the scheduler, goodput as a
+    # gauge, generated tokens as a counter.
+    exported = run.metrics
+    assert exported["histograms"]["serve_ttft_s"]["count"] == 3
+    assert exported["histograms"]["serve_token_s"]["count"] == sum(
+        len(f.tokens) - 1 for f in sched.finished
     )
-
-    tracer = trace.Tracer(enabled=True)
-    trace.set_tracer(tracer)
-    reg = metrics.MetricsRegistry(enabled=True)
-    metrics.set_metrics(reg)
-    try:
-        cfg = GPTConfig(
-            vocab_size=32, dim=16, num_layers=1, num_heads=2,
-            ffn_dim=32, max_position=16, dropout_rate=0.0,
-        )
-        eng = ServingEngine(
-            cfg, None, layout="replicated", num_slots=2, max_len=16,
-            prefill_len=4,
-        )
-        params = eng.init_params(jax.random.PRNGKey(0))
-        rng = np.random.RandomState(0)
-        reqs = [
-            Request(rid=i, prompt=rng.randint(1, 32, size=3),
-                    max_new_tokens=3)
-            for i in range(3)
-        ]
-        sched = eng.run(params, reqs)
-        rep = sched.latency_report()
-        assert rep["requests"] == 3
-        assert rep["decode_steps"] == len(sched.step_occupancy) > 0
-        assert 0 < rep["mean_batch_occupancy"] <= 2
-        assert 0 < rep["goodput"] <= 1
-        # goodput IS occupancy over capacity (each active slot yields
-        # one token per step).
-        assert rep["goodput"] == pytest.approx(
-            rep["mean_batch_occupancy"] / 2, abs=1e-3
-        )
-        events = tracer.to_chrome()["traceEvents"]
-        names = {e["name"] for e in events}
-        assert {
-            "prefill", "decode_step", "queued", "decode",
-            "batch_occupancy",
-        } <= names
-        # One queued+prefill+decode trio per finished request, each on
-        # its own named track.
-        assert sum(1 for e in events if e["name"] == "queued") == 3
-        assert len({
-            e["tid"] for e in events if e["name"] == "queued"
-        }) == 3
-        # Serving metrics wiring: per-request histograms through the
-        # scheduler, per-call histograms through the engine, goodput /
-        # occupancy as gauges, generated tokens as a counter.
-        exported = reg.to_json()
-        assert {
-            "serve_queued_s", "serve_ttft_s", "serve_token_s",
-            "serve_prefill_s", "serve_decode_step_s",
-        } <= set(exported["histograms"])
-        assert exported["histograms"]["serve_ttft_s"]["count"] == 3
-        assert exported["histograms"]["serve_token_s"]["count"] == sum(
-            len(f.tokens) - 1 for f in sched.finished
-        )
-        assert exported["gauges"]["serve_goodput"] == rep["goodput"]
-        assert exported["counters"]["serve_tokens_total"] == sum(
-            len(f.tokens) for f in sched.finished
-        ) == rep["generated_tokens"]
-    finally:
-        trace.set_tracer(None)
-        metrics.set_metrics(None)
+    assert exported["gauges"]["serve_goodput"] == rep["goodput"]
+    assert exported["counters"]["serve_tokens_total"] == sum(
+        len(f.tokens) for f in sched.finished
+    ) == rep["generated_tokens"]
 
 
 def test_scheduler_request_spans_coherent_under_injected_clock():
@@ -687,3 +499,79 @@ def test_progress_print_never_measures_its_own_readback_stall(
     finally:
         trace.set_tracer(None)
         metrics.set_metrics(None)
+
+
+# ------------------------------------------------------------ layering
+#
+# cli -> {training, serving} -> parallel -> {models, ops} -> runtime;
+# observability (trace, metrics) imports nothing of the package and is
+# imported by any; analysis is a test-time tool that may import
+# engines; the benchmark sits outside and reads the tracer.
+
+PACKAGE = "distributed_model_parallel_tpu"
+RUNTIME_LAYERS = (
+    "runtime", "ops", "models", "data", "parallel", "training",
+    "checkpointing", "serving", "observability",
+)
+# What a runtime layer never imports: the layers above it, the
+# benchmark, and the modules that priced, gated, fitted, reported or
+# tuned speed (PR 32 deleted them).
+ABOVE_THE_RUNTIME = (
+    f"{PACKAGE}.analysis", f"{PACKAGE}.cli", f"{PACKAGE}.tuning",
+    "benchmark", "bench",
+) + tuple(
+    f"{PACKAGE}.observability.{gone}"
+    for gone in ("cost", "costgate", "calibrate", "attribution", "report")
+)
+
+
+def imports_of(subpackage):
+    """{imported module: [file:line, ...]} over the sub-package's
+    source, imports inside functions included."""
+    import ast
+
+    root = os.path.join(
+        os.path.dirname(os.path.dirname(__file__)), PACKAGE, subpackage
+    )
+    found = {}
+    for dirpath, _dirs, files in os.walk(root):
+        for fn in sorted(f for f in files if f.endswith(".py")):
+            path = os.path.join(dirpath, fn)
+            with open(path) as f:
+                tree = ast.parse(f.read())
+            for node in ast.walk(tree):
+                if isinstance(node, ast.Import):
+                    modules = [a.name for a in node.names]
+                elif isinstance(node, ast.ImportFrom):
+                    assert node.level == 0, f"{path}: relative import"
+                    # `from pkg import sub` names pkg.sub as well
+                    modules = [node.module] + [
+                        f"{node.module}.{a.name}" for a in node.names
+                    ]
+                else:
+                    continue
+                for module in modules:
+                    found.setdefault(module, []).append(
+                        f"{os.path.relpath(path, root)}:{node.lineno}"
+                    )
+    return found
+
+
+def _under(module, prefixes):
+    return any(module == p or module.startswith(p + ".") for p in prefixes)
+
+
+@pytest.mark.parametrize("subpackage", RUNTIME_LAYERS + ("analysis",))
+def test_package_imports_point_one_way(subpackage):
+    found = imports_of(subpackage)
+    assert found, "the scan read no import at all"
+    own = (f"{PACKAGE}.observability",)
+
+    def refused(module):
+        if subpackage == "analysis":
+            return _under(module, (f"{PACKAGE}.cli",))
+        if subpackage == "observability" and not _under(module, own):
+            return _under(module, (PACKAGE,) + ABOVE_THE_RUNTIME)
+        return _under(module, ABOVE_THE_RUNTIME)
+
+    assert {m: at for m, at in found.items() if refused(m)} == {}

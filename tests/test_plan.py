@@ -192,88 +192,6 @@ def test_composed_2x2x2_matches_dense_trajectory():
     _run_parity("pp2xsp2xdp2")
 
 
-@pytest.mark.slow
-def test_composed_dp_only_matches_dense_trajectory():
-    """The pure-data composed program (no stage wire, no seq ring —
-    the degenerate tick loop) is still exactly dense. `slow` (one more
-    composed compile); tier-1 twin:
-    test_composed_2x2x2_matches_dense_trajectory — the same tick
-    program with all three axes live."""
-    _run_parity("dp8")
-
-
-@pytest.mark.slow
-def test_composed_fsdp_matches_dense_trajectory():
-    """ZeRO-3 on the plan's data axis: 1/dp params + moments with the
-    plan_fsdp_gather materialization, same trajectory as dense. `slow`
-    (tier-1 budget); tier-1 twins:
-    test_composed_2x2x2_matches_dense_trajectory (the same tick
-    program) + test_checkpoint_sharded's cross-plan reshard test,
-    which restores onto fsdp4 and runs a finite composed-fsdp
-    train_step in tier-1."""
-    _run_parity("pp2xfsdp4")
-
-
-@pytest.mark.slow
-def test_degenerate_composed_matches_forced_composed():
-    """Both sides of the degenerate map agree: the single-axis SP
-    engine and the force_composed ComposedPlanEngine produce the same
-    loss for the same plan, params, and batch. `slow` (two extra
-    engine compiles); tier-1 twins:
-    test_degenerate_plans_route_to_single_axis_engines (the routing
-    contract) + test_composed_2x2x2_matches_dense_trajectory (both
-    sides are separately pinned against the SAME dense baseline)."""
-    ids = _ids(seed=3)
-    losses = []
-    for force in (False, True):
-        eng = build_plan_engine(
-            TINY, SGD(), "sp2", donate=False, force_composed=force,
-        )
-        ts = eng.init_state(jax.random.PRNGKey(0))
-        ids_s, tg_s = eng.shard_batch(ids)
-        _, m = eng.train_step(ts, ids_s, tg_s, jnp.float32(LR))
-        losses.append(float(m["loss_sum"]) / float(m["count"]))
-    np.testing.assert_allclose(losses[0], losses[1], rtol=1e-5)
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("spec", [
-    "fsdp8", "pp2xdp4", "sp2xdp4", "pp4xdp2", "sp4xdp2",
-    "pp2xfsdp2", "sp2xfsdp4", "pp2xsp2xfsdp2", "pp2xsp4",
-])
-def test_plan_parity_sweep(spec):
-    """Full composed-plan parity sweep: every remaining factorization
-    of the 8-device world follows the dense trajectory. `slow`
-    (tier-1 budget: ~9 composed compiles); tier-1 twin:
-    test_composed_2x2x2_matches_dense_trajectory — the 3-axis case of
-    the same _run_parity assertion (the fsdp and degenerate cases ride
-    this sweep and test_composed_fsdp_matches_dense_trajectory in the
-    slow lane)."""
-    _run_parity(spec)
-
-
-@pytest.mark.slow
-def test_composed_plan_num_microbatches_above_pp():
-    """M > S: extra microbatches drain through the same tick program
-    (M + S - 1 ticks) without changing the math. `slow` (one more
-    composed compile); tier-1 twin:
-    test_composed_2x2x2_matches_dense_trajectory — the M == S case of
-    the same tick loop."""
-    eng = build_plan_engine(
-        TINY, SGD(), "pp2xdp2", num_microbatches=4, donate=False,
-    )
-    ts = eng.init_state(jax.random.PRNGKey(0))
-    ids = _ids(seed=5)
-    ids_s, tg_s = eng.shard_batch(ids)
-    step, params, opt_state, *_ = _dense_step_fn(TINY, ids)
-    ts, m = eng.train_step(ts, ids_s, tg_s, jnp.float32(LR))
-    _, _, dense_loss = step(params, opt_state)
-    np.testing.assert_allclose(
-        float(m["loss_sum"]) / float(m["count"]), float(dense_loss),
-        rtol=1e-5,
-    )
-
-
 # ------------------------------------------- scheduled plans (ISSUE 20)
 
 
@@ -362,25 +280,6 @@ def test_composed_1f1b_matches_dense_trajectory():
     trajectory — losses, token counts, final params, eval — at
     rtol 1e-5."""
     _run_parity("pp2-1f1bxsp2xdp2")
-
-
-@pytest.mark.slow
-def test_composed_interleaved_matches_dense_trajectory():
-    """Interleaved V=2 (two virtual stages per device, M=4 default)
-    follows the dense trajectory. `slow` (one more composed compile);
-    tier-1 twin: test_composed_1f1b_matches_dense_trajectory — the
-    same table-driven tick program with V=1 tables."""
-    _run_parity("pp2-int2xdp2")
-
-
-@pytest.mark.slow
-def test_composed_1f1b_fsdp_matches_dense_trajectory():
-    """1F1B over the per-parameter fsdp layout: scheduled per-block
-    gathers compose with ZeRO-3 sharding and stay exactly dense.
-    `slow` (tier-1 budget); tier-1 twins:
-    test_composed_1f1b_matches_dense_trajectory (the schedule) +
-    test_fsdp_per_parameter_layout (the layout)."""
-    _run_parity("pp2-1f1bxfsdp4")
 
 
 def test_1f1b_bit_identical_to_gpipe_twin():

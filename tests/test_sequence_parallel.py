@@ -449,15 +449,11 @@ def test_ring_flash_no_mask(sp_mesh):
     )
 
 
-@pytest.mark.slow
 def test_ring_flash_kernel_path_multihop(sp_mesh):
     """Shapes large enough that every hop runs the PALLAS kernels
     (interpret mode here): the LSE merge and the rotating dk/dv
     delivery are exercised with the production per-hop core, not the
-    dense fallback. `slow` (tier-1 budget); tier-1 twins:
-    test_causal_flash_matches_dense + the ring_flash cases of the
-    forward/gradient parity sweeps above (same merge math on the
-    fallback core)."""
+    dense fallback."""
     from distributed_model_parallel_tpu.ops.ring_attention import (
         ring_flash_attention,
     )

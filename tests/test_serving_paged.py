@@ -20,9 +20,6 @@ The load-bearing pins:
   skipped), a divergent prompt resumes ingestion at the first
   unmatched page, and a write into a shared page copies first
   (copy-on-write), with the original sequence unperturbed.
-
-S=4 sweeps are `slow` (tier-1 budget) with named tier-1 twins, per the
-budget-rebalance convention.
 """
 
 import numpy as np
@@ -171,26 +168,19 @@ def test_paged_decode_matches_dense_replicated(dense):
     _assert_paged_decode_parity(eng, dense)
 
 
-@pytest.mark.slow
 def test_paged_decode_matches_dense_page2(dense):
     """page_size=2: a 5-token prompt spans 3 pages at PREFILL time
-    already, and decode crosses a page boundary every other step.
-    `slow` (tier-1 budget); tier-1 twin:
-    test_paged_decode_matches_dense_replicated (page_size=4, same
-    gather/write/scatter path with >= 3-page straddles by step 4)."""
+    already, and decode crosses a page boundary every other step."""
     eng = ServingEngine(
         CFG, num_slots=4, max_len=16, prefill_len=8, page_size=2
     )
     _assert_paged_decode_parity(eng, dense)
 
 
-@pytest.mark.parametrize("s", [
-    2, pytest.param(4, marks=pytest.mark.slow),
-])
+@pytest.mark.parametrize("s", [2, 4])
 def test_paged_decode_matches_dense_tp(s, dense, devices):
     """TP paged: pool heads-sharded over 'model', block-table gathers
-    local per shard. S=4 is `slow`; its tier-1 twin is the S=2 case on
-    the same code path."""
+    local per shard."""
     mesh = make_mesh(MeshSpec(data=1, model=s), devices=devices[:s])
     eng = ServingEngine(
         CFG, mesh, layout="tp", num_slots=4, max_len=16, prefill_len=8,
@@ -199,17 +189,14 @@ def test_paged_decode_matches_dense_tp(s, dense, devices):
     _assert_paged_decode_parity(eng, dense)
 
 
-@pytest.mark.parametrize("s", [
-    2, pytest.param(4, marks=pytest.mark.slow),
-])
+@pytest.mark.parametrize("s", [2, 4])
 def test_paged_decode_matches_dense_tp_collective_matmul(
     s, dense, devices,
 ):
     """Opted-in decode rings over the PAGED cache: the ring projections
     and the block-table gathers compose without touching each other's
     math (the HLO side — identical 4L(S-1) tagged permute chain — is
-    the serve/S2/pg8/cm hlolint combo). S=4 is `slow`; tier-1 twin:
-    the S=2 case."""
+    the serve/S2/pg8/cm hlolint combo)."""
     mesh = make_mesh(MeshSpec(data=1, model=s), devices=devices[:s])
     eng = ServingEngine(
         CFG, mesh, layout="tp", num_slots=4, max_len=16, prefill_len=8,
@@ -218,14 +205,11 @@ def test_paged_decode_matches_dense_tp_collective_matmul(
     _assert_paged_decode_parity(eng, dense)
 
 
-@pytest.mark.parametrize("s", [
-    2, pytest.param(4, marks=pytest.mark.slow),
-])
+@pytest.mark.parametrize("s", [2, 4])
 def test_paged_decode_matches_dense_sp(s, dense, devices):
     """SP paged: each shard owns a contiguous slice of EVERY page's
     positions; the per-shard partial attentions merge via the exact
-    online-softmax recurrence. S=4 is `slow`; tier-1 twin: the S=2
-    case."""
+    online-softmax recurrence."""
     mesh = make_mesh(MeshSpec(data=1, seq=s), devices=devices[:s])
     eng = ServingEngine(
         CFG, mesh, layout="sp", num_slots=4, max_len=16, prefill_len=8,
@@ -326,15 +310,10 @@ def test_unaligned_chunk_ingest_logit_parity(dense):
         positions[0] += 1
 
 
-@pytest.mark.slow
 def test_chunked_lifts_prefill_len_cap(dense):
     """Chunked ingestion walks the prompt in place, so a prompt longer
     than the monolithic prefill_len pad serves fine (up to
-    max_len - 1). `slow` (tier-1 budget); tier-1 twins:
-    test_chunked_prefill_matches_monolithic_and_contiguous (the
-    chunked run loop) and test_paged_spec_and_engine_guards (the
-    cap/guard surface); the >prefill_len admission path also runs in
-    the serving_admission bench leg."""
+    max_len - 1)."""
     params, next_logits = dense
     long_prompt = np.random.RandomState(5).randint(
         1, CFG.vocab_size, size=12

@@ -25,10 +25,6 @@ The load-bearing pins:
 * **Guards** — engine- and CLI-level misconfigurations (non-paged
   draft, sp layout, k without pages, lockstep mismatches, draft flags
   without k, negative arrival knobs) fail loudly before any compile.
-* **Pricing units** — the cost closed forms (`
-  speculative_expected_tokens`, `serve_verify_compute_s`,
-  `serve_speculative_token_s`, `serve_speculative_request_s`) match
-  hand-computed values and refuse out-of-domain inputs.
 
 S=4 layout sweeps are `slow` (tier-1 budget) with named tier-1 twins,
 per the budget-rebalance convention.
@@ -42,7 +38,7 @@ import pytest
 import jax
 
 from distributed_model_parallel_tpu.models.gpt import GPTConfig
-from distributed_model_parallel_tpu.observability import cost, metrics
+from distributed_model_parallel_tpu.observability import metrics
 from distributed_model_parallel_tpu.runtime.mesh import (
     MeshSpec,
     make_mesh,
@@ -610,60 +606,3 @@ def test_synthetic_arrivals_deterministic_and_bursty():
     np.testing.assert_array_equal(
         serve.synthetic_arrivals(args0), np.zeros(4)
     )
-
-
-# --------------------------------------------------------- cost units
-
-
-def test_cost_speculative_expected_tokens():
-    assert cost.speculative_expected_tokens(0.7, 0) == 1.0
-    assert cost.speculative_expected_tokens(1.0, 4) == 5.0
-    # Hand-computed: acc 0.5, k 2 -> 1 + 0.5 + 0.25.
-    assert cost.speculative_expected_tokens(0.5, 2) == pytest.approx(
-        1.75
-    )
-    assert cost.speculative_expected_tokens(0.0, 3) == 1.0
-    with pytest.raises(ValueError, match=r"\[0, 1\]"):
-        cost.speculative_expected_tokens(1.2, 2)
-
-
-def test_cost_verify_step_is_decode_at_widened_batch():
-    """The verify roofline IS the decode roofline at m = slots*(k+1):
-    one closed form, no second set of constants to drift."""
-    assert cost.serve_verify_compute_s(
-        2, 16, 32, 4, speculative_k=3
-    ) == cost.serve_decode_compute_s(2, 16, 32, 16)
-
-
-def test_cost_speculative_token_hand_computed():
-    # (k * ratio * decode + verify) / E(acc, k)
-    # = (2 * 0.5 * 1.0 + 1.1) / 1.75 = 1.2 at acc 0.5, ratio 0.5.
-    got = cost.serve_speculative_token_s(
-        1.0, 1.1, 2, accept_rate=0.5, draft_cost_ratio=0.5
-    )
-    assert got == pytest.approx(2.1 / 1.75)
-    # Defaults come from COMPUTE_CONSTANTS (the ledger drift-checks
-    # them): acc 0.7, ratio 0.5.
-    e = cost.speculative_expected_tokens(
-        cost.SPEC_MODEL_ACCEPT, 2
-    )
-    assert cost.serve_speculative_token_s(1.0, 1.1, 2) \
-        == pytest.approx((2 * 0.5 * 1.0 + 1.1) / e)
-    with pytest.raises(ValueError, match="k >= 1"):
-        cost.serve_speculative_token_s(1.0, 1.1, 0)
-
-
-def test_cost_speculative_request_validates_and_prices():
-    with pytest.raises(ValueError, match="k >= 1"):
-        cost.serve_speculative_request_s(8, 16, 64, 4, 4, 0)
-    with pytest.raises(ValueError, match="paged"):
-        cost.serve_speculative_request_s(8, 16, 64, 0, 4, 2)
-    s = cost.serve_speculative_request_s(8, 16, 64, 4, 4, 2)
-    assert s > 0
-    # A perfect-accept override amortizes strictly better than the
-    # model default (0.7) at the same shapes.
-    tok_model = cost.serve_speculative_token_s(1e-6, 1.2e-6, 2)
-    tok_perfect = cost.serve_speculative_token_s(
-        1e-6, 1.2e-6, 2, accept_rate=1.0
-    )
-    assert tok_perfect < tok_model
