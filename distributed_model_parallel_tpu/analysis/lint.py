@@ -1124,7 +1124,10 @@ def _build_plan(combo: Combo, devices):
     trace-level inventory from `jaxpr_collective_records` — because
     every contract here is a named-axis one: the plan_wire ppermute
     rides ('stage',), the kv_ring/cm rings ride ('seq',), and the
-    fused plan_grad psum spans all three axes in one rendezvous."""
+    plan_grad reduction is one fused psum over all three axes — or,
+    for an fsdp plan, per-block plan_fsdp_gather all-gathers and
+    their float32 reduce-scatters over ('data',) alone, with one psum
+    over the axes that are left (`plan_fsdp` says which contract)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1180,6 +1183,7 @@ def _build_plan(combo: Combo, devices):
         plan_collective_records=records,
         plan_schedule=plan.schedule,
         plan_virtual=plan.virtual_stages,
+        plan_fsdp=plan.fsdp,
         n_param_leaves=_n_param_leaves(ts),
         **_mesh_facts(mesh),
     )

@@ -183,6 +183,9 @@ class LintTarget:
     # one scan rather than unrolling per-tick programs).
     plan_schedule: str = "gpipe"
     plan_virtual: int = 1
+    # Whether the plan shards parameters over 'data' (ISSUE 33): keys
+    # which gradient contract plan-grad-fabric holds the step to.
+    plan_fsdp: bool = False
 
     # rule_id -> reason; the finding is reported but not counted
     # (module docstring).
@@ -1249,15 +1252,22 @@ def _plan_seq_fabric(ctx: LintContext) -> List[Finding]:
 
 
 @rule(
-    id="plan-grad-fabric", severity="error", source="ISSUE 19",
+    id="plan-grad-fabric", severity="error", source="ISSUE 19, 33",
     contract=(
-        "A composed plan reduces gradients as ONE fused psum over "
-        "the full ('stage', 'data', 'seq') tuple under the "
+        "A composed plan WITHOUT fsdp reduces gradients as ONE fused "
+        "psum over the full ('stage', 'data', 'seq') tuple under the "
         "`plan_grad` scope (complementary stage pieces + seq "
         "partials + data replicas in a single rendezvous — never a "
-        "per-axis cascade), and the FSDP weight materialization — "
-        "when the plan shards — is `plan_fsdp_gather`-scoped "
-        "all-gathers over ('data',) only."
+        "per-axis cascade). An fsdp plan never all-reduces what it "
+        "keeps a 1/dp of: its weight materialization is "
+        "`plan_fsdp_gather`-scoped all-gathers over ('data',) only, "
+        "per block; the gather's transpose — a block's gradient — "
+        "is a float32 `reduce_scatter` over ('data',) only under "
+        "the same scope; under `plan_grad` the leaves gathered once "
+        "(stem, head, the blocks' vectors) reduce-scatter in float32 "
+        "over ('data',) only, and what is left is one psum over the "
+        "'stage'/'seq' axes that exist (shards) or over the full "
+        "tuple (the leaves fsdp left replicated)."
     ),
     applies=lambda t: t.engine == "plan",
 )
@@ -1273,21 +1283,63 @@ def _plan_grad_fabric(ctx: LintContext) -> List[Finding]:
             "no plan_grad-scoped collectives traced — the "
             "fused-reduction pin was not checked",
         )]
+    full = ("data", "seq", "stage")
+    # What the data-axis reduce-scatter leaves to sum over shards.
+    rest = tuple(sorted(
+        ax for ax, ways in t.plan_axes
+        if ax in ("stage", "seq") and ways > 1
+    ))
     out = []
     for prim, axes, dt, scope, elems in grads:
-        if prim != "psum" or tuple(sorted(axes)) != (
-            "data", "seq", "stage"
-        ):
+        axes_s = tuple(sorted(axes))
+        if not t.plan_fsdp:
+            if prim != "psum" or axes_s != full:
+                out.append(ctx.finding(
+                    "plan-grad-fabric",
+                    f"plan_grad {prim} over {tuple(axes)} — the "
+                    "gradient reduction is one fused psum over "
+                    "('stage', 'data', 'seq')",
+                ))
+        elif prim == "reduce_scatter":
+            if tuple(axes) != ("data",) or dt != "f32":
+                out.append(ctx.finding(
+                    "plan-grad-fabric",
+                    f"plan_grad reduce_scatter of {dt} over "
+                    f"{tuple(axes)} — an fsdp plan reduce-scatters "
+                    "float32 over ('data',) only",
+                ))
+        elif prim != "psum" or axes_s not in (full, rest):
             out.append(ctx.finding(
                 "plan-grad-fabric",
-                f"plan_grad {prim} over {tuple(axes)} — the gradient "
-                "reduction is one fused psum over "
-                "('stage', 'data', 'seq')",
+                f"plan_grad {prim} over {tuple(axes)} — beside its "
+                "reduce-scatters an fsdp plan sums over "
+                f"{rest or '(nothing)'} (shards) or over ('stage', "
+                "'data', 'seq') (replicated leaves), never over "
+                "'data' apart",
             ))
-    for prim, axes, dt, scope, elems in t.plan_collective_records:
-        if not _scope_word("plan_fsdp_gather", scope):
-            continue
-        if prim != "all_gather" or tuple(axes) != ("data",):
+    gathers = [
+        r for r in t.plan_collective_records
+        if _scope_word("plan_fsdp_gather", r[3])
+    ]
+    if t.plan_fsdp and not any(
+        r[0] == "reduce_scatter" for r in gathers
+    ):
+        out.append(ctx.finding(
+            "plan-grad-fabric",
+            "an fsdp plan traced no plan_fsdp_gather reduce_scatter "
+            "— its block gradients do not leave the backward scan "
+            "as the per-block gather's transpose",
+        ))
+    for prim, axes, dt, scope, elems in gathers:
+        if prim == "reduce_scatter" and t.plan_fsdp:
+            if tuple(axes) != ("data",) or dt != "f32":
+                out.append(ctx.finding(
+                    "plan-grad-fabric",
+                    f"plan_fsdp_gather reduce_scatter of {dt} over "
+                    f"{tuple(axes)} — a block's gradient reduces in "
+                    "float32 over ('data',) only",
+                ))
+        elif prim != "all_gather" or tuple(axes) != ("data",):
             out.append(ctx.finding(
                 "plan-grad-fabric",
                 f"plan_fsdp_gather {prim} over {tuple(axes)} — the "

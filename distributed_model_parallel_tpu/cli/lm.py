@@ -605,6 +605,10 @@ def main(argv=None) -> dict:
         if local is not None and jax.process_index() == 0:
             print(f"plan {plan.spec}: the sequence is whole on a chip, "
                   f"local attention: {local}")
+        exchange = getattr(engine, "fsdp_exchange", None)
+        if exchange is not None and jax.process_index() == 0:
+            print(f"plan {plan.spec}: parameters 1/{plan.dp} over 'data', "
+                  f"{exchange}")
     elif args.pipeline_stages > 1:
         from distributed_model_parallel_tpu.models.gpt import split_stages
         from distributed_model_parallel_tpu.parallel.pipeline import (

@@ -50,11 +50,19 @@ single-axis engines' building blocks verbatim:
           per-replica sums over 'data'), so ONE fused psum over
           ('stage', 'data', 'seq') (scope `plan_grad`) divided by the
           global valid-token count reproduces the dense mean-loss
-          gradient exactly. `fsdp=True` additionally shards parameters
-          and optimizer moments 1/dp at rest (`fsdp.fsdp_specs` over
-          'data'), all-gathers them on entry (scope `fsdp_gather`) and
-          slices each device's own shard after reduction — ZeRO-3 on
-          the plan's data axis.
+          gradient exactly. `fsdp=True` instead shards parameters and
+          optimizer moments 1/dp at rest (`fsdp.fsdp_specs` over
+          'data') and never holds the model or its gradient whole —
+          ZeRO-3 on the plan's data axis (ISSUE 33): the block scan
+          all-gathers each block's matrices as it reaches them, one
+          block ahead and in the compute dtype (scope
+          `plan_fsdp_gather`), the step differentiates with respect
+          to the 1/dp rows, so each block's gradient leaves the
+          backward scan as the gather's transpose, a float32
+          reduce-scatter over 'data', while the block before it
+          computes; stem, head and the blocks' vectors gather once a
+          step and reduce-scatter under `plan_grad`; what is left to
+          sum is 'stage' and 'seq', over shards.
   ep    — experts ride the data axes: an `ep > 1` plan routes through
           `ExpertParallelLMEngine`'s hierarchical dispatch (the EP x DP
           composition that engine already is). The manual composed
@@ -467,24 +475,31 @@ class ComposedPlanEngine:
         self._batch = NamedSharding(mesh, P(("data",), ("seq",)))
         # Dense-parameter twin: init AND the canonical checkpoint form.
         self._full = gpt_lm(cfg)
-        block_list = decoder_blocks(cfg, attn_fn)
-        if self.remat:
-            block_list = [L.remat(b) for b in block_list]
         # With num_experts == 0 (enforced above) every decoder block is
         # the same encoder_layer module — one shared apply over stacked
         # per-block params is exact.
-        block_apply = block_list[0].apply
+        block = decoder_blocks(cfg, attn_fn)[0]
+        block_apply = (L.remat(block) if self.remat else block).apply
         Lps = cfg.num_layers // S  # blocks per stage (uniform)
         drop = L.dropout(cfg.dropout_rate)
         cdt = self.compute_dtype
-        wire_dt = jnp.dtype(cdt) if cdt is not None else jnp.float32
+        wire_dt = jnp.dtype(cdt if cdt is not None else jnp.float32)
         V = cfg.vocab_size
         D = cfg.dim
         reduce_axes = ("stage", "data", "seq")
         self._reduce_axes = reduce_axes
 
         fsdp = plan.fsdp
+        # Where an fsdp plan materializes its parameters and reduces
+        # their gradients, in words for a log line (None without
+        # fsdp: the parameters are replicated and whole gradients meet
+        # in the one fused psum).
+        self.fsdp_exchange = None
         if fsdp:
+            self.fsdp_exchange = (
+                f"all-gather {wire_dt.name} per block, one ahead; "
+                "reduce-scatter float32"
+            )
             from distributed_model_parallel_tpu.parallel.fsdp import (
                 fsdp_specs,
             )
@@ -523,10 +538,9 @@ class ComposedPlanEngine:
                 return None
 
             def _gather_leaf(leaf, spec, off=0):
-                """ZeRO-3 weight materialization: all-gather one 1/dp
-                leaf over 'data'. `off` shifts the sharded dim past
-                leading stack/chunk axes (the per-block gather adds
-                two)."""
+                """All-gather one 1/dp leaf over 'data', float32 as
+                it rests. `off` shifts the sharded dim past leading
+                stack/chunk axes."""
                 d = _sharded_dim(spec)
                 if d is None:
                     return leaf
@@ -534,30 +548,170 @@ class ComposedPlanEngine:
                     leaf, "data", axis=d + off, tiled=True
                 )
 
-            # Per-parameter layout note: fsdp_specs is shape-driven
-            # and every decoder block has identical leaf shapes, so
-            # one block's spec tree describes them all — the per-block
-            # gather in gather_stage_mat reuses it on the chunk-sliced
-            # stacked rows.
-            block_pspecs = pspecs["blocks"]["0"]
+            def _in_scan(spec):
+                """Which block leaves gather per block inside the
+                scan: the matrices fsdp shards (all but a thousandth
+                of a block's bytes). The vectors of every block gather
+                once beside stem and head: a dozen small collectives
+                in the scan's body buy nothing, and XLA merges their
+                reductions with the matrices' into one all-reduce."""
+                return _sharded_dim(spec) is not None and len(spec) >= 2
 
-            def slice_grads(grads):
-                """Each device keeps its own 1/dp of the fully-reduced
-                gradient — local slice, no collective."""
-                idx = lax.axis_index("data")
+            @partial(jax.custom_vjp, nondiff_argnums=(1,))
+            def _gather_matrices(mats, dims):
+                """All-gather one block's matrices over 'data' (mats[i]
+                is 1/dp along dims[i]) as ONE collective in the
+                COMPUTE dtype. One: XLA:TPU keeps a single
+                asynchronous all-gather in flight, and of four to a
+                block it hides the two small ones behind the block's
+                products and runs the two large ones on their own —
+                so each matrix travels sharded-dim-first as rows of
+                one buffer per row width (a GPT block's matrices all
+                have `dim` for it). Compute dtype: the block casts
+                every matrix to the activation dtype at the product
+                (`L._proj`), so casting ahead of the gather is
+                bit-identical forward and halves its bytes. The
+                transpose converts each device's cotangent to float32
+                BEFORE it reduces — the sum is the parameters'
+                precision whatever the wire carried forward — and
+                reduce-scatters each matrix over a leading axis of dp
+                slabs: scattered along a minor dimension whose shard
+                does not fill the chip's tiles, XLA:TPU all-reduces
+                the whole matrix and slices it."""
+                rows = []
+                for m, d in zip(mats, dims):
+                    x = jnp.moveaxis(m, d, 0)
+                    x = x if cdt is None else x.astype(cdt)
+                    rows.append(x.reshape(x.shape[0], -1))
+                out = [None] * len(mats)
+                for width in sorted({r.shape[1] for r in rows}):
+                    group = [
+                        i for i, r in enumerate(rows)
+                        if r.shape[1] == width
+                    ]
+                    slabs = lax.all_gather(
+                        jnp.concatenate([rows[i] for i in group]),
+                        "data", axis=0, tiled=False,
+                    )
+                    at = 0
+                    for i in group:
+                        m, d, n = mats[i], dims[i], rows[i].shape[0]
+                        rest = m.shape[:d] + m.shape[d + 1:]
+                        out[i] = jnp.moveaxis(
+                            slabs[:, at:at + n].reshape(
+                                (n_dp * n,) + rest
+                            ),
+                            0, d,
+                        )
+                        at += n
+                return tuple(out)
 
-                def slice_leaf(leaf, spec):
-                    d = _sharded_dim(spec)
-                    if d is None:
-                        return leaf
-                    block = leaf.shape[d] // n_dp
-                    return lax.dynamic_slice_in_dim(
-                        leaf, idx * block, block, axis=d
+            def _gather_matrices_fwd(mats, dims):
+                return _gather_matrices(mats, dims), None
+
+            def _gather_matrices_bwd(dims, _, cts):
+                def reduce(ct, d):
+                    sh = ct.shape
+                    slabs = jnp.moveaxis(
+                        ct.astype(jnp.float32).reshape(
+                            sh[:d] + (n_dp, sh[d] // n_dp) + sh[d + 1:]
+                        ),
+                        d, 0,
+                    )
+                    return lax.psum_scatter(
+                        slabs, "data", scatter_dimension=0, tiled=False
                     )
 
-                return jax.tree_util.tree_map(
-                    slice_leaf, grads, pspecs
-                )
+                return (tuple(
+                    reduce(ct, d) for ct, d in zip(cts, dims)
+                ),)
+
+            _gather_matrices.defvjp(
+                _gather_matrices_fwd, _gather_matrices_bwd
+            )
+
+            # Per-parameter layout note: fsdp_specs is shape-driven
+            # and every decoder block has identical leaf shapes, so
+            # one block's spec tree describes them all.
+            block_pspecs = pspecs["blocks"]["0"]
+
+            def gather_block(shard):
+                """One block's matrices, whole, from this device's
+                1/dp rows (scope `plan_fsdp_gather`). Under autodiff
+                the transpose is that block's float32 reduce-scatter
+                over 'data'."""
+                leaves, treedef = jax.tree_util.tree_flatten(shard)
+                specs = treedef.flatten_up_to(block_pspecs)
+                picked = [
+                    i for i, spec in enumerate(specs) if _in_scan(spec)
+                ]
+                with jax.named_scope(GATHER_SCOPE):
+                    whole = _gather_matrices(
+                        tuple(leaves[i] for i in picked),
+                        tuple(_sharded_dim(specs[i]) for i in picked),
+                    )
+                for i, leaf in zip(picked, whole):
+                    leaves[i] = leaf
+                return jax.tree_util.tree_unflatten(treedef, leaves)
+
+            def _block_and_gather(params, state, x, ctx):
+                """Block j from its gathered params beside the gather
+                of block j+1: the scan body of an fsdp plan."""
+                pb, nxt = params
+                # Tie block j+1's rows to block j's input: the gather
+                # is issued when block j starts, not earlier. Without
+                # the tie the gathers depend on nothing a tick
+                # computes, and autodiff's partial evaluation hoists
+                # ALL of them out of the tick loop into a scan of
+                # their own — the whole model gathered ahead of the
+                # forward pass again.
+                h, mask = x
+                nxt, h = lax.optimization_barrier((nxt, h))
+                y, _ = block.apply(pb, {}, (h, mask), ctx)
+                return (y, gather_block(nxt)), state
+
+            # Under remat the gather sits INSIDE the checkpoint with
+            # the block: jax.checkpoint stages its body into the
+            # differentiated scan, and a gather left outside it runs
+            # in a scan of its own ahead of the whole forward pass.
+            # (The recomputation's gather has no reader and is dead
+            # code; the gathered block itself is the body's input and
+            # is saved.)
+            block_and_gather = L.Layer(None, _block_and_gather)
+            if self.remat:
+                block_and_gather = L.remat(block_and_gather)
+            block_and_gather = block_and_gather.apply
+
+            # What the data-axis reduce-scatter leaves to sum: the
+            # other mesh axes that exist (a size-1 axis still costs an
+            # all-reduce pass over its operand on the device).
+            rest_axes = tuple(
+                ax for ax in ("stage", "seq") if int(mesh.shape[ax]) > 1
+            )
+
+            def reduce_rest(x):
+                return lax.psum(x, rest_axes) if rest_axes else x
+
+            def reduce_whole(x, spec, off=0):
+                """A gradient held whole (its leaf was gathered once,
+                outside the scans): reduce-scatter over 'data' onto
+                this device's rows, then what is left. `off` shifts
+                the sharded dim past a leading stack axis."""
+                d = _sharded_dim(spec)
+                if d is None:
+                    return lax.psum(x, reduce_axes)
+                return reduce_rest(lax.psum_scatter(
+                    x, "data", scatter_dimension=d + off, tiled=True
+                ))
+
+            def reduce_block(x, spec):
+                """A stacked (layers, ...) block gradient: a matrix
+                left the backward scan already reduce-scattered over
+                'data'; a vector is whole (gathered once for every
+                block), as is a leaf fsdp left replicated."""
+                if _in_scan(spec):
+                    return reduce_rest(x)
+                return reduce_whole(x, spec, 1)
         else:
             state_specs = P()
             # The manifest seam still declares the full layout for
@@ -573,9 +727,6 @@ class ComposedPlanEngine:
                 self.optimizer.state_shardings(repl_specs, P()),
                 P(),
             )
-            _gather_leaf = None
-            block_pspecs = None
-            slice_grads = lambda g: g  # noqa: E731
 
         def gather_stage_mat(params, n_virtual):
             """This device's execution bundle {stem, chunks, head}:
@@ -583,11 +734,12 @@ class ComposedPlanEngine:
             STACKED block params for the logical chunks v*S + s_idx
             this stage runs (n_virtual=1 is the gpipe stage slice;
             the interleaved train path passes the plan's
-            virtual_stages). For fsdp plans the all-gather happens
-            per-BLOCK, after the chunk slice — each device
-            materializes only the blocks it executes (scope
-            `plan_fsdp_gather`) instead of the whole stack; stem and
-            head gather whole."""
+            virtual_stages). For fsdp plans `chunks` STAYS 1/dp over
+            'data': the block scan gathers each block as it reaches
+            it (`scan_blocks`), and the step differentiates with
+            respect to these sharded rows. Stem and head, used once a
+            step, gather whole here (scope `plan_fsdp_gather`);
+            `finish_grads` reduce-scatters their gradients."""
             n_chunk_layers = cfg.num_layers // (S * n_virtual)
             s_idx = lax.axis_index("stage")
             stacked = stack_block_params(
@@ -604,39 +756,91 @@ class ComposedPlanEngine:
                 ])
 
             chunks = jax.tree_util.tree_map(chunk_rows, stacked)
-            if not fsdp:
-                return {
-                    "stem": params["stem"], "chunks": chunks,
-                    "head": params["head"],
-                }
-            with jax.named_scope(GATHER_SCOPE):
-                chunks = jax.tree_util.tree_map(
-                    # The (chunk, layer) axes sit ahead of the leaf's
-                    # own dims: the sharded dim moved by 2.
-                    lambda lf, sp: _gather_leaf(lf, sp, 2),
-                    chunks, block_pspecs,
-                )
-                stem = jax.tree_util.tree_map(
-                    _gather_leaf, params["stem"], pspecs["stem"]
-                )
-                head = jax.tree_util.tree_map(
-                    _gather_leaf, params["head"], pspecs["head"]
-                )
+            stem, head = params["stem"], params["head"]
+            if fsdp:
+                with jax.named_scope(GATHER_SCOPE):
+                    chunks = jax.tree_util.tree_map(
+                        # The (chunk, layer) axes sit ahead of the
+                        # leaf's own dims: the sharded dim moved by 2.
+                        lambda leaf, spec: (
+                            leaf if _in_scan(spec)
+                            else _gather_leaf(leaf, spec, 2)
+                        ),
+                        chunks, block_pspecs,
+                    )
+                    stem = jax.tree_util.tree_map(
+                        _gather_leaf, stem, pspecs["stem"]
+                    )
+                    head = jax.tree_util.tree_map(
+                        _gather_leaf, head, pspecs["head"]
+                    )
             return {"stem": stem, "chunks": chunks, "head": head}
+
+        def scan_blocks(rows, blk_ids, x, block_ctx):
+            """Run the blocks whose stacked params are `rows` (n, ...)
+            over the carry `x` = (h, mask), one shared block apply
+            under the dense Context.child chain. For an fsdp plan the
+            matrices of `rows` are this device's 1/dp: the scan
+            gathers ONE block AHEAD — the gathered block rides the
+            carry, the body runs block j from it while the all-gather
+            of block j+1 is in flight. Under autodiff that carry's
+            cotangent is block j+1's whole gradient, reduce-scattered
+            over 'data' while block j's backward pass computes: the
+            prefetch forward IS the overlap backward. The last block
+            runs after the scan (nothing is left to gather beside
+            it)."""
+
+            def run(apply, pb, x, j):
+                y, _ = apply(pb, {}, x, block_ctx.child(j))
+                return y
+
+            if not fsdp:
+                def blk(x, sl):
+                    pb, j = sl
+                    return run(block_apply, pb, x, j), None
+
+                x, _ = lax.scan(blk, x, (rows, blk_ids))
+                return x
+
+            def row(sl):
+                return jax.tree_util.tree_map(lambda r: r[sl], rows)
+
+            def blk(carry, sl):
+                x, pb = carry
+                nxt, j = sl
+                return run(block_and_gather, (pb, nxt), x, j), None
+
+            carry = (x, gather_block(row(0)))
+            if blk_ids.shape[0] > 1:
+                carry, _ = lax.scan(
+                    blk, carry, (row(slice(1, None)), blk_ids[:-1])
+                )
+            x, last = carry
+            return run(block_apply, last, x, blk_ids[-1])
 
         def finish_grads(g_mat, n_virtual, n_global):
             """Shared gradient post-processing for EVERY schedule:
-            scatter the per-chunk block grads back into the full
-            stacked form (zeros off-chunk — exactly the transpose of
-            the chunk slice), ONE fused psum over ('stage', 'data',
-            'seq') on {stem, stacked blocks, head} (scope
-            `plan_grad`), the dense mean-loss normalization, then
-            unstack to the canonical per-block tree (and the fsdp
-            1/dp slice)."""
+            the per-chunk block grads back in the full stacked form
+            (zeros off-chunk — exactly the transpose of the chunk
+            slice), the reduction (scope `plan_grad`), the dense
+            mean-loss normalization, then unstack to the canonical
+            per-block tree. A plan without fsdp holds whole gradients
+            and reduces them with ONE fused psum over ('stage',
+            'data', 'seq'). An fsdp plan's block gradients arrive
+            from the backward scan already summed over 'data' and
+            1/dp (the per-block gather's transpose); stem and head
+            are reduce-scattered here; what is left to sum is the
+            'stage' and 'seq' axes that exist, over shards. Every
+            operand and sum is float32."""
             n_chunk_layers = cfg.num_layers // (S * n_virtual)
             s_idx = lax.axis_index("stage")
 
             def scatter(leaf):
+                if fsdp and S * n_virtual == 1:
+                    # The one chunk IS the stack: no zero-filled copy.
+                    # (Left to fsdp plans: the others keep the program
+                    # they lowered to before ISSUE 33.)
+                    return leaf[0]
                 full = jnp.zeros(
                     (cfg.num_layers,) + leaf.shape[2:], leaf.dtype
                 )
@@ -655,13 +859,26 @@ class ComposedPlanEngine:
                 "head": g_mat["head"],
             }
             with jax.named_scope(GRAD_SCOPE):
-                g = jax.tree_util.tree_map(
-                    lambda x: lax.psum(x, reduce_axes), g
-                )
+                if fsdp:
+                    g = {
+                        "stem": jax.tree_util.tree_map(
+                            reduce_whole, g["stem"], pspecs["stem"]
+                        ),
+                        "blocks": jax.tree_util.tree_map(
+                            reduce_block, g["blocks"], block_pspecs
+                        ),
+                        "head": jax.tree_util.tree_map(
+                            reduce_whole, g["head"], pspecs["head"]
+                        ),
+                    }
+                else:
+                    g = jax.tree_util.tree_map(
+                        lambda x: lax.psum(x, reduce_axes), g
+                    )
             g = jax.tree_util.tree_map(
                 lambda x: x / jnp.maximum(n_global, 1.0), g
             )
-            grads = {
+            return {
                 "stem": g["stem"],
                 "blocks": {
                     str(j): jax.tree_util.tree_map(
@@ -671,7 +888,6 @@ class ComposedPlanEngine:
                 },
                 "head": g["head"],
             }
-            return slice_grads(grads)
 
         def run_ticks(mat, ids, targets, step, train):
             """The gpipe fill-drain tick program on ONE device
@@ -714,11 +930,12 @@ class ComposedPlanEngine:
                 ),
                 lax.axis_index("seq"),
             )
-            # This stage's uniform Lps-block slice, already cut (and
-            # for fsdp, gathered per-block) by gather_stage_mat's
-            # n_virtual=1 layout; finish_grads scatters grads back to
-            # exactly these rows (zeros elsewhere), so the fused
-            # stage-psum reassembles the dense gradient.
+            # This stage's uniform Lps-block slice, already cut (for
+            # fsdp still 1/dp: scan_blocks gathers) by
+            # gather_stage_mat's n_virtual=1 layout; finish_grads
+            # scatters grads back to exactly these rows (zeros
+            # elsewhere), so the stage-psum reassembles the dense
+            # gradient.
             my_blocks = jax.tree_util.tree_map(
                 lambda x: x[0], mat["chunks"]
             )
@@ -782,15 +999,8 @@ class ComposedPlanEngine:
                 # back to the (benign) stem mask there so attention
                 # never sees a fully-masked row.
                 mask = jnp.where(is_first | ~valid, mask0, mask_in)
-                block_ctx = ctx.child(1)
-
-                def blk(x, sl):
-                    pb, j = sl
-                    y, _ = block_apply(pb, {}, x, block_ctx.child(j))
-                    return y, None
-
-                (h, mask), _ = lax.scan(
-                    blk, (h, mask), (my_blocks, blk_ids)
+                h, mask = scan_blocks(
+                    my_blocks, blk_ids, (h, mask), ctx.child(1)
                 )
                 # Head on EVERY device; only the last stage's logits
                 # reach the loss/wire.
@@ -1005,17 +1215,8 @@ class ComposedPlanEngine:
                         mat_["chunks"],
                     )
                     blk_ids = l * Lpc + jnp.arange(Lpc)
-                    block_ctx = ctx.child(1)
-
-                    def blk(x, sl):
-                        pb, j = sl
-                        y, _ = block_apply(
-                            pb, {}, x, block_ctx.child(j)
-                        )
-                        return y, None
-
-                    (h, mask), _ = lax.scan(
-                        blk, (h, mask), (cp, blk_ids)
+                    h, mask = scan_blocks(
+                        cp, blk_ids, (h, mask), ctx.child(1)
                     )
                     logits = lm_head_apply(mat_["head"], h)
                     y_pad = jnp.where(

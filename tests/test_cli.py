@@ -1134,6 +1134,13 @@ def test_lm_cli_plan_says_which_local_attention(
     out = capsys.readouterr().out
     assert out.count("local attention: dense") == 1
     assert "plan fsdp4" in out
+    # ...and where an fsdp plan gathers and reduces (ISSUE 33), from
+    # the engine's own field: a log tells the per-block program from
+    # the whole-model one without a trace
+    assert out.count(
+        "plan fsdp4: parameters 1/4 over 'data', all-gather float32 per "
+        "block, one ahead; reduce-scatter float32"
+    ) == 1
     assert registry._gauges["train_local_attention_flash"].value == 0.0
     assert "train_local_attention_flash" in metrics.METRIC_NAMES
 

@@ -698,3 +698,188 @@ def test_lm_train_step_lowers_without_sort_or_top_k(build):
     found = _SORT_OPS.search(text)
     assert found is None, found.group(0)
     assert "compare" in text     # the rank's pass is there
+
+
+# ---------------------- an fsdp plan's collectives, block by block
+# ISSUE 33: a plan with fsdp gathers each block inside the block scan
+# (one ahead) and differentiates with respect to its 1/dp rows, so a
+# block's gradient leaves the backward scan as the gather's transpose,
+# a float32 reduce-scatter over 'data'. Numerics cannot see a whole
+# float32 gradient all-reduced and sliced afterwards (the result is
+# the same and the chip pays twice the bytes, exposed), so the traced
+# step and its lowering are pinned.
+
+# Widths at which no leaf's 1/2 or 1/4 has another leaf's whole shape
+# (vectors of 32, 96, 80): the pins below tell the two apart by shape.
+_PLAN_CFG = dict(vocab_size=61, dim=32, num_layers=4, num_heads=4,
+                 ffn_dim=80, max_position=16, dropout_rate=0.0)
+
+
+def _plan_step(spec, compute_dtype=None):
+    """(engine, traced collectives, StableHLO text) of one train step
+    of `spec` at toy widths. A traced collective is (primitive, axis
+    names, operand shape, result shape, dtype, scans around it)."""
+    from distributed_model_parallel_tpu.analysis.lint import _subjaxprs
+    from distributed_model_parallel_tpu.models.gpt import GPTConfig
+    from distributed_model_parallel_tpu.parallel.plan import (
+        build_plan_engine, parse_plan,
+    )
+
+    plan = parse_plan(spec)
+    eng = build_plan_engine(
+        GPTConfig(**_PLAN_CFG), SGD(), plan, donate=False,
+        force_composed=True, compute_dtype=compute_dtype,
+        min_shard_elems=16,
+        devices=jax.devices()[: plan.num_devices],
+    )
+    ts = eng.init_state(jax.random.PRNGKey(0))
+    ids = np.random.RandomState(0).randint(
+        1, 61, size=(4 * plan.dp * plan.pp, 16)
+    ).astype(np.int32)
+    args = (ts, *eng.shard_batch(ids), jnp.float32(0.1))
+    names = {"all_gather": "axis_name", "reduce_scatter": "axis_name",
+             "psum": "axes"}
+    found, seen = [], set()
+
+    def walk(jaxpr, depth):
+        if id(jaxpr) in seen:
+            return
+        seen.add(id(jaxpr))
+        for eqn in jaxpr.eqns:
+            key = names.get(eqn.primitive.name)
+            if key is not None:
+                axes = eqn.params[key]
+                axes = axes if isinstance(axes, tuple) else (axes,)
+                for v, o in zip(eqn.invars, eqn.outvars):
+                    found.append((
+                        eqn.primitive.name, axes, tuple(v.aval.shape),
+                        tuple(o.aval.shape), str(v.aval.dtype), depth,
+                    ))
+            inner = depth + (eqn.primitive.name == "scan")
+            for p in eqn.params.values():
+                for sub in _subjaxprs(p):
+                    walk(sub, inner)
+
+    walk(jax.make_jaxpr(eng.train_step)(*args).jaxpr, 0)
+    return eng, found, eng.train_step.lower(*args).as_text()
+
+
+def _block_leaves(eng):
+    """{whole per-block shape: 1/dp shape} of the block leaves fsdp
+    shards, matrices and vectors apart."""
+    from jax.sharding import PartitionSpec as P
+
+    specs = eng.state_partition_specs().params["blocks"]["0"]
+    avals = jax.eval_shape(
+        eng._full.init, jax.ShapeDtypeStruct((2,), jnp.uint32)
+    )[0]["blocks"]["0"]
+    dp = eng.plan.dp
+    matrices, vectors = {}, {}
+    for spec, aval in zip(
+        jax.tree_util.tree_leaves(
+            specs, is_leaf=lambda x: isinstance(x, P)
+        ),
+        jax.tree_util.tree_leaves(avals),
+    ):
+        d = [i for i, part in enumerate(spec) if part is not None]
+        assert d, "min_shard_elems=16 shards every block leaf here"
+        shard = tuple(
+            n // dp if i == d[0] else n for i, n in enumerate(aval.shape)
+        )
+        (matrices if aval.ndim >= 2 else vectors)[aval.shape] = shard
+    return matrices, vectors
+
+
+_MLIR_TENSOR = re.compile(r"tensor<((?:\d+x)*)(\w+)>")
+
+
+def _stablehlo_operands(text, op):
+    """[(dims, element type)] of every `stablehlo.<op>` operand."""
+    out = []
+    lines = text.splitlines()
+    for i, line in enumerate(lines):
+        if f'"stablehlo.{op}"' not in line:
+            continue
+        if line.rstrip().endswith("({"):
+            # a reduction carries its region; the types close it
+            line = next(
+                ln for ln in lines[i:] if ln.lstrip().startswith("}) : (")
+            )
+        sig = line[line.rindex(" : (") + 4:]
+        for dims, el in _MLIR_TENSOR.findall(sig[:sig.index(") -> ")]):
+            out.append((tuple(int(n) for n in dims.split("x") if n), el))
+    return out
+
+
+@pytest.mark.parametrize("compute_dtype", [None, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("spec", ["fsdp4", "pp2xfsdp2", "pp2-1f1bxfsdp2"])
+def test_fsdp_plan_gathers_and_reduces_block_by_block(spec, compute_dtype):
+    eng, found, text = _plan_step(spec, compute_dtype)
+    matrices, vectors = _block_leaves(eng)
+    whole = set(matrices) | set(vectors)
+    assert eng.fsdp_exchange is not None
+
+    # a leaf's whole shape, alone or stacked as the engine stacks it:
+    # (layers, ...) or (virtual chunks, layers a chunk, ...)
+    layers, chunks = _PLAN_CFG["num_layers"], eng.plan.virtual_stages
+    stacks = ((), (layers,),
+              (chunks, layers // (eng.plan.pp * chunks)))
+
+    def ends_whole(shape):
+        return any(shape == st + w for st in stacks for w in whole)
+
+    # nothing a block keeps 1/dp of is all-reduced whole — in the
+    # trace, and in what it lowered to
+    for prim, axes, shape, _, _, _ in found:
+        assert not (prim == "psum" and "data" in axes
+                    and ends_whole(shape)), (axes, shape)
+    for dims, _ in _stablehlo_operands(text, "all_reduce"):
+        assert not ends_whole(dims), dims
+    # the matrices: gathered INSIDE the block scan, one block's worth
+    # (never (layers, ...)), in the compute dtype; each one's gradient
+    # reduce-scattered inside the backward scan, float32, 'data' alone
+    wire = "bfloat16" if compute_dtype is not None else "float32"
+    scatters = [f for f in found if f[0] == "reduce_scatter"]
+    assert scatters and all(
+        axes == ("data",) and dt == "float32"
+        for _, axes, _, _, dt, _ in scatters
+    )
+    # (one collective a block: the matrices travel as rows of one
+    # buffer, `dim` wide, so its size is the block's, not the stack's)
+    in_scan = [f for f in found if f[0] == "all_gather" and f[5] > 0]
+    block_elems = sum(int(np.prod(w)) for w in matrices)
+    assert in_scan and all(
+        int(np.prod(f[3])) == block_elems for f in in_scan
+    )
+    assert all(f[4] == wire and f[1] == ("data",) for f in in_scan)
+    for shard in matrices.values():
+        assert any(
+            out == shard and depth > 0
+            for _, _, _, out, _, depth in scatters
+        ), shard
+    # every vector: whole for all blocks from one gather outside the
+    # scans, reduce-scattered once, float32
+    for shard in vectors.values():
+        assert any(
+            out[-len(shard):] == shard and depth == 0
+            for _, _, _, out, _, depth in scatters
+        ), shard
+    # the lowering keeps the precision: no reduce_scatter of bf16
+    lowered = _stablehlo_operands(text, "reduce_scatter")
+    assert lowered and {el for _, el in lowered} == {"f32"}
+
+
+def test_plan_without_fsdp_keeps_its_one_fused_psum():
+    eng, found, text = _plan_step("dp4")
+    assert eng.fsdp_exchange is None
+    assert {f[0] for f in found} == {"psum"}
+    assert {f[1] for f in found} == {("stage", "data", "seq")}
+    assert not _stablehlo_operands(text, "reduce_scatter")
+    assert not _stablehlo_operands(text, "all_gather")
+    # the whole (layers, ...) stacks meet in it
+    layers = _PLAN_CFG["num_layers"]
+    assert any(
+        len(dims) == 3 and dims[0] == layers
+        for dims, _ in _stablehlo_operands(text, "all_reduce")
+    )

@@ -1138,6 +1138,95 @@ def test_plan_grad_fires_on_partial_axis_psum():
     assert found2 and "plan_fsdp_gather" in found2[0].message
 
 
+# An fsdp plan's traced inventory as ISSUE 33 leaves it (pp2 x sp2 x
+# fsdp2): per-block gathers and their float32 reduce-scatters over
+# ('data',) under plan_fsdp_gather; under plan_grad the leaves
+# gathered once reduce-scatter, shards sum over what is left, and a
+# leaf fsdp left replicated sums over everything.
+FSDP_PLAN_RECORDS = (
+    ("ppermute", ("stage",), "f32", "jit(f)/plan_wire/ppermute", 64),
+    ("ppermute", ("stage",), "f32",
+     "jit(f)/transpose(plan_wire)/ppermute", 64),
+    ("all_gather", ("data",), "bf16",
+     "jit(f)/while/body/plan_fsdp_gather/all_gather", 64),
+    ("reduce_scatter", ("data",), "f32",
+     "jit(f)/transpose/while/body/plan_fsdp_gather/reduce_scatter", 64),
+    ("reduce_scatter", ("data",), "f32",
+     "jit(f)/plan_grad/reduce_scatter", 64),
+    ("psum", ("stage", "seq"), "f32", "jit(f)/plan_grad/psum", 64),
+    ("psum", ("stage", "data", "seq"), "f32",
+     "jit(f)/plan_grad/psum", 8),
+)
+
+
+def _fsdp_records(*swap):
+    """FSDP_PLAN_RECORDS with the records whose (primitive, scope
+    word) match replaced: swap = ((prim, word), new record | None)."""
+    out = []
+    for r in FSDP_PLAN_RECORDS:
+        for (prim, word), new in swap:
+            if r[0] == prim and word in r[3]:
+                r = new
+                break
+        if r is not None:
+            out.append(r)
+    return tuple(out)
+
+
+@pytest.mark.hlo_rule("plan-grad-fabric", "positive")
+@pytest.mark.parametrize("swap,says", [
+    # the whole gradient all-reduced over 'data' and sliced after:
+    # what ISSUE 33 removed
+    ((("psum", "plan_grad"),
+      ("psum", ("data",), "f32", "jit(f)/plan_grad/psum", 64)),
+     "never over 'data' apart"),
+    # a bfloat16 reduction is a different result, not a faster one
+    ((("reduce_scatter", "plan_fsdp_gather"),
+      ("reduce_scatter", ("data",), "bf16",
+       "jit(f)/transpose/plan_fsdp_gather/reduce_scatter", 64)),
+     "float32 over ('data',) only"),
+    ((("reduce_scatter", "plan_grad"),
+      ("reduce_scatter", ("data", "seq"), "f32",
+       "jit(f)/plan_grad/reduce_scatter", 64)),
+     "float32 over ('data',) only"),
+    # the blocks gathered but differentiated whole: no transpose of
+    # the per-block gather in the backward scan
+    ((("reduce_scatter", "plan_fsdp_gather"), None),
+     "do not leave the backward scan"),
+    ((("all_gather", "plan_fsdp_gather"),
+      ("all_gather", ("stage",), "bf16",
+       "jit(f)/plan_fsdp_gather/all_gather", 64)),
+     "rides ('data',) only"),
+], ids=["data_psum", "bf16_block_reduction", "scatter_off_data",
+        "no_block_reduce_scatter", "gather_off_data"])
+def test_plan_grad_fsdp_contract_fires(swap, says):
+    t = plan_target(
+        plan_fsdp=True, plan_collective_records=_fsdp_records(swap)
+    )
+    found = check("plan-grad-fabric", t, module([]), MESH8)
+    assert found and any(says in f.message for f in found)
+
+
+@pytest.mark.hlo_rule("plan-grad-fabric", "negative")
+@pytest.mark.parametrize("axes,records", [
+    ((("stage", 2), ("data", 2), ("seq", 2)), FSDP_PLAN_RECORDS),
+    # fsdp4 alone: nothing is left to sum beside the reduce-scatters
+    ((("stage", 1), ("data", 4), ("seq", 1)), tuple(
+        r for r in FSDP_PLAN_RECORDS
+        if r[0] != "ppermute" and r[1] != ("stage", "seq")
+    )),
+], ids=["pp2xsp2xfsdp2", "fsdp4"])
+def test_plan_grad_fsdp_contract_clean(axes, records):
+    t = plan_target(
+        plan_fsdp=True, plan_axes=axes, plan_collective_records=records
+    )
+    assert check("plan-grad-fabric", t, module([]), MESH8) == []
+    # ...and the same inventory under a plan WITHOUT fsdp is a
+    # per-axis cascade: the one-fused-psum contract still stands there
+    t2 = plan_target(plan_axes=axes, plan_collective_records=records)
+    assert check("plan-grad-fabric", t2, module([]), MESH8)
+
+
 @pytest.mark.hlo_rule("plan-grad-fabric", "negative")
 def test_plan_grad_fused_psum_and_data_gather_clean():
     t = plan_target(plan_collective_records=(
