@@ -13,6 +13,21 @@ tokens/sec and p50/p99 per-token legs, as JSON on stdout.
       --layout tp --model-shards 4 --collective-matmul
   python -m distributed_model_parallel_tpu.cli.serve \
       --layout sp --seq-shards 4 --max-len 512
+  python -m distributed_model_parallel_tpu.cli.serve \
+      --model-config benchmark/configs/jamba2-3b.json \
+      --compute-dtype bf16 --num-slots 32 --max-len 8192 \
+      --page-size 64 --prefill-chunk 512 \
+      --prompt-len-min 256 --prompt-len-max 4096 --max-new-tokens 64
+
+`--model-config FILE` serves another family than GPT: FILE carries the
+release's own keys (its `config.json`; `model_type` picks the family,
+`torch_dtype` the dtype the weights rest in) and takes the place of the
+GPT-shaping flags, which are refused beside it. Weights are random from
+`--seed`. A family that keeps a recurrent state per slot
+(`model_type: jamba`) is served from the page pool with chunked
+prefill only (`--page-size` and `--prefill-chunk` are required) and
+refuses `--prefix-cache`, `--speculative-k`, `--layout tp|sp` by name,
+each with the mechanism that is missing.
 
 The parser carries the shared training flags (grad reduction, pipeline
 stages) so a pasted training launch line fails fast with an explanation
@@ -57,6 +72,15 @@ def build_parser() -> argparse.ArgumentParser:
                         "layout; fails fast naming the mismatch when "
                         "the checkpoint's recorded model config "
                         "disagrees with the serve flags")
+    p.add_argument("--model-config", default=None, metavar="FILE",
+                   help="serve the model FILE describes instead of a "
+                        "GPT: a JSON object of the release's own keys "
+                        "(model_type jamba: models/jamba.py). Replaces "
+                        "--vocab-size/--dim/--layers/--heads/--ffn-dim, "
+                        "which are refused beside it; random weights "
+                        "from --seed (no --checkpoint loader for such a "
+                        "family yet). What the family cannot run is "
+                        "refused by name: see the module docstring")
     p.add_argument("--vocab-size", default=256, type=int)
     p.add_argument("--dim", default=128, type=int)
     p.add_argument("--layers", default=4, type=int)
@@ -200,6 +224,58 @@ def build_parser() -> argparse.ArgumentParser:
                         "stage wires)")
     add_grad_reduction_flags(p)
     return p
+
+
+# `model_type` of a --model-config file -> its configuration's builder.
+def _model_families() -> dict:
+    from distributed_model_parallel_tpu.models import jamba
+
+    return {jamba.MODEL_TYPE: jamba.config_from_dict}
+
+
+# What --model-config replaces or cannot be combined with, by flag.
+_MODEL_CONFIG_SHAPE_FLAGS = (
+    ("--vocab-size", "vocab_size"), ("--dim", "dim"),
+    ("--layers", "layers"), ("--heads", "heads"),
+    ("--ffn-dim", "ffn_dim"),
+)
+
+
+def _model_config(args, parser):
+    """The configuration `--model-config FILE` names, after the guards
+    `cli.lm --model-config` has: the file carries the shape, so the GPT
+    shape flags beside it are refused by name, and so are the
+    checkpoint flags (their loaders read a GPT tree)."""
+    for flag, dest in _MODEL_CONFIG_SHAPE_FLAGS:
+        if getattr(args, dest) != parser.get_default(dest):
+            raise SystemExit(
+                f"{flag} shapes the GPT family; --model-config "
+                f"{args.model_config} carries the model's shape — drop "
+                "the flag"
+            )
+    for flag, value in (("--checkpoint", args.checkpoint),
+                        ("--speculative-draft", args.speculative_draft)):
+        if value:
+            raise SystemExit(
+                f"--model-config {args.model_config} does not compose "
+                f"with {flag}: the checkpoint loaders restore a GPT "
+                "parameter tree; this family serves random weights from "
+                "--seed"
+            )
+    with open(args.model_config) as f:
+        described = json.load(f)
+    families = _model_families()
+    model_type = described.get("model_type")
+    if model_type not in families:
+        raise SystemExit(
+            f"--model-config {args.model_config}: model_type "
+            f"{model_type!r} is not built (have: "
+            f"{', '.join(sorted(families))})"
+        )
+    try:
+        return families[model_type](described)
+    except (KeyError, NotImplementedError, ValueError) as e:
+        raise SystemExit(f"--model-config {args.model_config}: {e}") from e
 
 
 def synthetic_trace(args) -> list:
@@ -374,9 +450,14 @@ def _draft_config(args, target_cfg) -> "tuple[GPTConfig, str | None]":
 
 
 def main(argv=None) -> dict:
-    args = build_parser().parse_args(argv)
+    parser = build_parser()
+    args = parser.parse_args(argv)
     enable_compile_cache()
     check_serving_args(args)
+    model_cfg = None
+    if args.model_config:
+        model_cfg = _model_config(args, parser)
+        args.vocab_size = model_cfg.vocab_size  # the synthetic prompts
     from distributed_model_parallel_tpu.cli.common import (
         setup_metrics_out,
     )
@@ -410,7 +491,7 @@ def main(argv=None) -> dict:
                else f"--prefill-len {prompt_cap}")
         )
     initialize_backend()
-    cfg = GPTConfig(
+    cfg = model_cfg or GPTConfig(
         vocab_size=args.vocab_size,
         dim=args.dim,
         num_layers=args.layers,
@@ -447,20 +528,26 @@ def main(argv=None) -> dict:
             ),
             devices=devices[:shards],
         )
-    engine = ServingEngine(
-        cfg, mesh,
-        layout=args.layout,
-        num_slots=args.num_slots,
-        max_len=args.max_len,
-        prefill_len=args.prefill_len,
-        collective_matmul=args.collective_matmul,
-        compute_dtype=serve_compute_dtype(args),
-        page_size=args.page_size or None,
-        num_pages=args.kv_pages or None,
-        prefill_chunk=args.prefill_chunk or None,
-        prefix_cache=args.prefix_cache,
-        speculative_k=args.speculative_k,
-    )
+    try:
+        engine = ServingEngine(
+            cfg, mesh,
+            layout=args.layout,
+            num_slots=args.num_slots,
+            max_len=args.max_len,
+            prefill_len=args.prefill_len,
+            collective_matmul=args.collective_matmul,
+            compute_dtype=serve_compute_dtype(args),
+            page_size=args.page_size or None,
+            num_pages=args.kv_pages or None,
+            prefill_chunk=args.prefill_chunk or None,
+            prefix_cache=args.prefix_cache,
+            speculative_k=args.speculative_k,
+        )
+    except ValueError as e:
+        if model_cfg is None:
+            raise
+        # a family's refusals, by the option's name
+        raise SystemExit(f"--model-config {args.model_config}: {e}") from e
     draft_engine = draft_params = None
     if args.speculative_k:
         draft_cfg, draft_ckpt = _draft_config(args, cfg)
@@ -626,6 +713,7 @@ def main(argv=None) -> dict:
     out = {
         "serving": {
             "layout": args.layout,
+            "model_config": args.model_config,
             "checkpoint": args.checkpoint,
             "shards": shards,
             "collective_matmul": args.collective_matmul,

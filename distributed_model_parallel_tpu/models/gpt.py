@@ -118,6 +118,60 @@ class GPTConfig:
             }},
         )
 
+    def serving_family(self):
+        """What `ServingEngine` needs of this family
+        (`models/lm_family.ServingFamily`): every layer holds pages of
+        all its heads, nothing holds state, nothing is missing."""
+        from distributed_model_parallel_tpu.models.lm_family import (
+            LayerCache,
+            ServingFamily,
+        )
+
+        if self.dim % self.num_heads:
+            raise ValueError(
+                f"dim {self.dim} not divisible by heads {self.num_heads}"
+            )
+        top = self.max_position - 1
+
+        def head_row(params, h, row):
+            return jax.lax.dynamic_index_in_dim(
+                head_apply(params["head"], h)[0], row, axis=0,
+                keepdims=False,
+            )
+
+        return ServingFamily(
+            name="gpt",
+            vocab_size=self.vocab_size,
+            max_position=self.max_position,
+            model=partial(gpt_lm, self),
+            blocks=lambda attention_fn, state_fn: decoder_blocks(
+                self, attention_fn
+            ),
+            decode_stem=lambda params, tokens, positions, dtype:
+                decode_stem(
+                    params["stem"], tokens, jnp.clip(positions, 0, top),
+                    dtype,
+                ),
+            chunk_stem=lambda params, ids, start, dtype: chunk_stem(
+                params["stem"], ids, start, dtype
+            ),
+            prefill_stem=lambda params, ids, offset, dtype: prefill_stem(
+                params["stem"], ids, offset, dtype
+            ),
+            verify_stem=lambda params, tokens, positions, dtype:
+                verify_stem(params["stem"], tokens, positions, dtype),
+            head=lambda params, h: head_apply(params["head"], h),
+            head_row=head_row,
+            layers=(LayerCache(
+                kv_heads=self.num_heads,
+                head_dim=self.dim // self.num_heads,
+            ),) * self.num_layers,
+            ring_widths={
+                "qkv width (3*dim)": 3 * self.dim, "dim": self.dim,
+                "ffn_dim": self.ffn_dim,
+            },
+        )
+
 
 def stem_apply(params, ids, cfg: GPTConfig, drop: L.Layer, ctx, *,
                positions=None):
@@ -137,6 +191,77 @@ def stem_apply(params, ids, cfg: GPTConfig, drop: L.Layer, ctx, *,
         h = h.astype(ctx.dtype)
     h, _ = drop.apply({}, {}, h, ctx)
     return h, mask
+
+
+# ------------------------------------------------ serving's stems
+#
+# One per kind of step of `serving/engine.ServingEngine`; each embeds
+# tokens at positions the dense `stem_apply` cannot express.
+
+
+def decode_stem(stem_params, tokens, positions, dtype):
+    """One-token stem: word embedding of each slot's incoming token plus
+    ITS OWN position row — the dense `gpt.stem_apply` broadcasts one
+    shared position slice over the batch, which cannot express a ragged
+    (mixed-position) decode batch, so the gather is per-slot here.
+    tokens/positions (slots,) -> h (slots, 1, dim)."""
+    h = jnp.take(stem_params["word"], tokens, axis=0)[:, None, :]
+    pos = jnp.take(stem_params["position"], positions, axis=0)[:, None, :]
+    h = h + pos
+    if dtype is not None:
+        h = h.astype(dtype)
+    return h
+
+
+def chunk_stem(stem_params, ids, start, dtype):
+    """Chunked-prefill stem: (1, T) ids embedded at global positions
+    start + [0, T) with PER-TOKEN position gathers (clipped — padding
+    rows past the chunk's valid length may index beyond the table;
+    their outputs are discarded). `prefill_stem`'s dynamic_slice would
+    CLAMP the whole slice when start + T overruns the table, silently
+    shifting every position row — the per-token gather cannot."""
+    t = ids.shape[1]
+    pos_ids = jnp.clip(
+        start + jnp.arange(t), 0, stem_params["position"].shape[0] - 1
+    )
+    h = jnp.take(stem_params["word"], ids, axis=0) \
+        + jnp.take(stem_params["position"], pos_ids, axis=0)[None]
+    if dtype is not None:
+        h = h.astype(dtype)
+    return h
+
+
+def verify_stem(stem_params, tokens, positions, dtype):
+    """Speculative verify stem: each slot's (T,) token span embedded at
+    ITS OWN positions `positions[s] + [0, T)` — the batched cousin of
+    `chunk_stem` (same clipped per-token position gathers; padding rows
+    past the table are discarded by the verify masks) crossed with
+    `decode_stem`'s per-slot raggedness. tokens (slots, T),
+    positions (slots,) -> h (slots, T, dim)."""
+    t = tokens.shape[1]
+    pos_ids = jnp.clip(
+        positions[:, None] + jnp.arange(t)[None, :],
+        0, stem_params["position"].shape[0] - 1,
+    )
+    h = jnp.take(stem_params["word"], tokens, axis=0) \
+        + jnp.take(stem_params["position"], pos_ids, axis=0)
+    if dtype is not None:
+        h = h.astype(dtype)
+    return h
+
+
+def prefill_stem(stem_params, ids, offset, dtype):
+    """Prompt stem over (B, T) ids starting at global position `offset`
+    (0 for the dense layouts; the shard's global offset under 'seq'
+    sharding, mirroring the SP training engines)."""
+    t = ids.shape[1]
+    pos = jax.lax.dynamic_slice_in_dim(
+        stem_params["position"], offset, t, axis=0
+    )
+    h = jnp.take(stem_params["word"], ids, axis=0) + pos[None]
+    if dtype is not None:
+        h = h.astype(dtype)
+    return h
 
 
 def head_apply(params, h):

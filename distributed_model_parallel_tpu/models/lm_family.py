@@ -6,12 +6,20 @@ stem, the list of blocks given an attention function, the head, the
 targets. `models/gpt.GPTConfig` is the first implementation,
 `models/kimi_linear.KimiLinearConfig` the second; the engine spells no
 family's fields.
+
+`ServingEngine` serves any decoder whose configuration answers
+`serving_family()` with a `ServingFamily`: the same init, a stem for
+each kind of step, the blocks given an attention function AND a state
+function, the head, and per layer what the layer keeps between steps
+(`LayerCache`: pages of keys and values, or arrays of constant size per
+slot). `models/gpt.GPTConfig` answers with what the engine did before
+the seam; `models/jamba.JambaConfig` is the first with state.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from distributed_model_parallel_tpu.models import layers as L
 
@@ -53,3 +61,62 @@ class LMFamily:
         default_factory=dict
     )
 
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerCache:
+    """What one layer keeps between two steps of a sequence: pages of
+    keys and values (`kv_heads` > 0: that many cached heads of
+    `head_dim`, however many query heads read them), or `state`, arrays
+    of constant size per slot, {name: (shape of one slot's, dtype or
+    None for the activations')}."""
+
+    kv_heads: int = 0
+    head_dim: int = 0
+    state: Dict[str, Tuple[Tuple[int, ...], Any]] = dataclasses.field(
+        default_factory=dict
+    )
+
+
+@dataclasses.dataclass(frozen=True)
+class ServingFamily:
+    name: str
+    vocab_size: int
+    max_position: int
+    # () -> the whole model as a Layer, params {"stem", "blocks": {"0",
+    # ...}, "head"}: init and checkpoint interop alone.
+    model: Callable[[], L.Layer]
+    # (attention_fn, state_fn) -> the decoder blocks on the (hidden,
+    # mask) pair. A layer that holds pages calls `attention_fn(q, k, v,
+    # mask)` once; a layer that holds state calls `state_fn(advance)`
+    # once, `advance(state) -> (output, new state)` on its own
+    # `LayerCache.state` arrays with a leading row axis; both in layer
+    # order. The mask says which positions are real (a chunk's tail is
+    # not) and a state-holding layer must not advance on the others.
+    blocks: Callable[[Any, Any], List[L.Layer]]
+    # (whole params, tokens (slots,), positions (slots,), dtype)
+    # -> (slots, 1, dim): every slot's next token at its own position
+    decode_stem: Callable[[Any, Any, Any, Any], Any]
+    # (whole params, ids (1, T), start, dtype) -> (1, T, dim)
+    chunk_stem: Callable[[Any, Any, Any, Any], Any]
+    # (whole params, ids (B, T), offset, dtype) -> (B, T, dim)
+    prefill_stem: Callable[[Any, Any, Any, Any], Any]
+    # (whole params, tokens (slots, T), positions (slots,), dtype)
+    # -> (slots, T, dim)
+    verify_stem: Callable[[Any, Any, Any, Any], Any]
+    # (whole params, hidden (B, T, dim)) -> float32 logits (B, T, vocab)
+    head: Callable[[Any, Any], Any]
+    # (whole params, hidden (1, T, dim), row) -> float32 logits (vocab,)
+    # of that one row
+    head_row: Callable[[Any, Any, Any], Any]
+    # one per layer, in layer order
+    layers: Tuple[LayerCache, ...]
+    # Weights at rest: `model().init` gives float32; the engine casts
+    # the tree to this under the same jit. None keeps float32.
+    param_dtype: Any = None
+    # Widths the tp decode rings chunk over 'model', {label: n}.
+    ring_widths: Dict[str, int] = dataclasses.field(default_factory=dict)
+    # What of the engine this family cannot run yet, {option: the
+    # mechanism that is missing}; the engine refuses each at
+    # construction under the option's name.
+    missing: Dict[str, str] = dataclasses.field(default_factory=dict)

@@ -118,6 +118,16 @@ METRIC_NAMES: Dict[str, str] = {
         "iteration (page-granular allocation scales with live tokens, "
         "not slots*max_len — serving/kv_cache.py)"
     ),
+    "serve_state_pool_bytes": (
+        "bytes of the state pool beside the page pool: what the layers "
+        "that keep arrays of constant size per slot (a recurrent state, "
+        "a convolution's last inputs) hold for all slots; 0 for a "
+        "family without such layers. In `Scheduler.paged_stats` as "
+        "`state_pool_bytes`, beside `prefill_positions_valid`, "
+        "`prefill_positions_computed` (chunks are padded to "
+        "prefill_chunk) and `state_resets` (chunks that began a prompt "
+        "and so started their slot's state from zeros)"
+    ),
     "serve_prefix_hits_total": (
         "requests whose prompt reused >= 1 cached prefix page "
         "(prompt caching; counter)"

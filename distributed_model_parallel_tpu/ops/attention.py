@@ -60,3 +60,50 @@ def dot_product_attention(
     weights = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", weights, v.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+def grouped_query_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    mask: Optional[jax.Array] = None,
+    *,
+    scale: Optional[float] = None,
+    causal: bool = False,
+) -> jax.Array:
+    """`dot_product_attention` where k and v carry FEWER heads than q:
+    q (B, Tq, H, Dh) over k, v (B, Tkv, Hkv, Dh) with H = G * Hkv, query
+    head h reading cached head h // G. The cached heads are never
+    repeated: the G query heads of a group become G more query rows of
+    their one cached head, and the result is laid back out by head.
+    With H == Hkv this IS `dot_product_attention`. A 4-d mask is
+    (B|1, 1, Tq, Tkv): one per query position, shared by the heads."""
+    b, tq, h, dh = q.shape
+    hkv = k.shape[2]
+    if h == hkv:
+        return dot_product_attention(
+            q, k, v, mask, scale=scale, causal=causal
+        )
+    g = h // hkv
+    if g * hkv != h:
+        raise ValueError(f"{h} query heads over {hkv} cached heads")
+    rows = q.reshape(b, tq, hkv, g, dh).swapaxes(2, 3).reshape(
+        b, tq * g, hkv, dh
+    )
+    if mask is not None and mask.ndim == 4:
+        mask = jnp.repeat(mask, g, axis=2)
+    if causal:
+        tri = (
+            jnp.repeat(jnp.arange(tq), g)[:, None]
+            >= jnp.arange(k.shape[1])[None, :]
+        )[None, None]
+        if mask is None:
+            mask = tri
+        else:
+            mask = tri & (
+                mask[:, None, None, :] if mask.ndim == 2 else mask
+            )
+    out = dot_product_attention(rows, k, v, mask, scale=scale)
+    return out.reshape(b, tq, g, hkv, dh).swapaxes(2, 3).reshape(
+        b, tq, h, dh
+    )

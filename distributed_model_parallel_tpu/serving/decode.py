@@ -52,8 +52,14 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from distributed_model_parallel_tpu.models.gpt import (  # noqa: F401
+    chunk_stem,
+    decode_stem,
+    prefill_stem,
+    verify_stem,
+)
 from distributed_model_parallel_tpu.ops.attention import (
-    dot_product_attention,
+    grouped_query_attention,
 )
 from distributed_model_parallel_tpu.ops.collective_matmul import (
     ag_matmul,
@@ -63,74 +69,6 @@ from distributed_model_parallel_tpu.ops.collective_matmul import (
 )
 from distributed_model_parallel_tpu.ops.quant_matmul import quant_dot
 from distributed_model_parallel_tpu.runtime.compat import shard_map
-
-
-# --------------------------------------------------------------- stems
-
-
-def decode_stem(stem_params, tokens, positions, dtype):
-    """One-token stem: word embedding of each slot's incoming token plus
-    ITS OWN position row — the dense `gpt.stem_apply` broadcasts one
-    shared position slice over the batch, which cannot express a ragged
-    (mixed-position) decode batch, so the gather is per-slot here.
-    tokens/positions (slots,) -> h (slots, 1, dim)."""
-    h = jnp.take(stem_params["word"], tokens, axis=0)[:, None, :]
-    pos = jnp.take(stem_params["position"], positions, axis=0)[:, None, :]
-    h = h + pos
-    if dtype is not None:
-        h = h.astype(dtype)
-    return h
-
-
-def chunk_stem(stem_params, ids, start, dtype):
-    """Chunked-prefill stem: (1, T) ids embedded at global positions
-    start + [0, T) with PER-TOKEN position gathers (clipped — padding
-    rows past the chunk's valid length may index beyond the table;
-    their outputs are discarded). `prefill_stem`'s dynamic_slice would
-    CLAMP the whole slice when start + T overruns the table, silently
-    shifting every position row — the per-token gather cannot."""
-    t = ids.shape[1]
-    pos_ids = jnp.clip(
-        start + jnp.arange(t), 0, stem_params["position"].shape[0] - 1
-    )
-    h = jnp.take(stem_params["word"], ids, axis=0) \
-        + jnp.take(stem_params["position"], pos_ids, axis=0)[None]
-    if dtype is not None:
-        h = h.astype(dtype)
-    return h
-
-
-def verify_stem(stem_params, tokens, positions, dtype):
-    """Speculative verify stem: each slot's (T,) token span embedded at
-    ITS OWN positions `positions[s] + [0, T)` — the batched cousin of
-    `chunk_stem` (same clipped per-token position gathers; padding rows
-    past the table are discarded by the verify masks) crossed with
-    `decode_stem`'s per-slot raggedness. tokens (slots, T),
-    positions (slots,) -> h (slots, T, dim)."""
-    t = tokens.shape[1]
-    pos_ids = jnp.clip(
-        positions[:, None] + jnp.arange(t)[None, :],
-        0, stem_params["position"].shape[0] - 1,
-    )
-    h = jnp.take(stem_params["word"], tokens, axis=0) \
-        + jnp.take(stem_params["position"], pos_ids, axis=0)
-    if dtype is not None:
-        h = h.astype(dtype)
-    return h
-
-
-def prefill_stem(stem_params, ids, offset, dtype):
-    """Prompt stem over (B, T) ids starting at global position `offset`
-    (0 for the dense layouts; the shard's global offset under 'seq'
-    sharding, mirroring the SP training engines)."""
-    t = ids.shape[1]
-    pos = lax.dynamic_slice_in_dim(
-        stem_params["position"], offset, t, axis=0
-    )
-    h = jnp.take(stem_params["word"], ids, axis=0) + pos[None]
-    if dtype is not None:
-        h = h.astype(dtype)
-    return h
 
 
 # ----------------------------------------------------- cache utilities
@@ -180,7 +118,7 @@ class CacheAttention:
         valid = (
             jnp.arange(kc.shape[1])[None, :] <= self.positions[:, None]
         )
-        return dot_product_attention(q, kc, vc, mask=valid)
+        return grouped_query_attention(q, kc, vc, mask=valid)
 
 
 def _sp_online_softmax_attend(q, kc, vc, valid, axis):
@@ -286,17 +224,24 @@ class PrefillRecorder:
 # `serve-decode-ring`) is untouched by paging.
 
 
-def _gather_pages(pool_layer, block_table):
+def _gather_pages(pool_layer, block_table, heads: int):
     """(num_pages, page, H, Dh) x (slots, P) -> position-ordered view
-    (slots, P*page, H, Dh). Unallocated entries (-1) clamp-gather page
-    0; their positions sit beyond every slot's live length, so the
-    validity masks keep them invisible."""
+    (slots, P*page, H, Dh); a pool whose pages fold the heads into the
+    row, (num_pages, page, H*Dh), gives the same view. Unallocated
+    entries (-1) clamp-gather page 0; their positions sit beyond every
+    slot's live length, so the validity masks keep them invisible."""
     pages = jnp.take(
         pool_layer, jnp.clip(block_table, 0, pool_layer.shape[0] - 1),
         axis=0,
     )  # (slots, P, page, H, Dh)
-    s, p, page, h, dh = pages.shape
-    return pages.reshape(s, p * page, h, dh)
+    s, p, page = pages.shape[:3]
+    return pages.reshape(s, p * page, heads, -1)
+
+
+def _as_pages(view, pool_layer):
+    """A position-ordered view (rows, T, H, Dh) cut back into the
+    pool's own pages: (rows, T/page, *pool_layer.shape[1:])."""
+    return view.reshape(view.shape[0], -1, *pool_layer.shape[1:])
 
 
 def _scatter_written_page(pool_layer, view, block_table, positions,
@@ -308,12 +253,10 @@ def _scatter_written_page(pool_layer, view, block_table, positions,
     private), so the scatter has no duplicate indices."""
     s = view.shape[0]
     num_pages = pool_layer.shape[0]
-    pages = view.reshape(
-        s, -1, page_size, view.shape[-2], view.shape[-1]
-    )
+    pages = _as_pages(view, pool_layer)
     wp = positions // page_size  # (slots,) slot-local page index
     written = jnp.take_along_axis(
-        pages, wp[:, None, None, None, None], axis=1
+        pages, jnp.expand_dims(wp, tuple(range(1, pages.ndim))), axis=1
     )[:, 0]  # (slots, page, H, Dh)
     dst = jnp.take_along_axis(block_table, wp[:, None], axis=1)[:, 0]
     dst = jnp.where(active & (dst >= 0), dst, num_pages)  # OOB -> drop
@@ -341,8 +284,9 @@ class PagedCacheAttention:
     def __call__(self, q, k_new, v_new, mask):
         i = self.layer
         self.layer += 1
-        kview = _gather_pages(self.k[i], self.bt)
-        vview = _gather_pages(self.v[i], self.bt)
+        heads = k_new.shape[2]
+        kview = _gather_pages(self.k[i], self.bt, heads)
+        vview = _gather_pages(self.v[i], self.bt, heads)
         kc = write_position(kview, k_new, self.positions, self.active)
         vc = write_position(vview, v_new, self.positions, self.active)
         self.k = self.k.at[i].set(_scatter_written_page(
@@ -356,7 +300,7 @@ class PagedCacheAttention:
         valid = (
             jnp.arange(kc.shape[1])[None, :] <= self.positions[:, None]
         )
-        return dot_product_attention(q, kc, vc, mask=valid)
+        return grouped_query_attention(q, kc, vc, mask=valid)
 
 
 class PagedSeqShardedCacheAttention:
@@ -391,8 +335,8 @@ class PagedSeqShardedCacheAttention:
         self.layer += 1
         psub = self.k.shape[2]  # page/S positions per shard
         idx = lax.axis_index(self.axis)
-        kview = _gather_pages(self.k[i], self.bt)
-        vview = _gather_pages(self.v[i], self.bt)
+        kview = _gather_pages(self.k[i], self.bt, k_new.shape[2])
+        vview = _gather_pages(self.v[i], self.bt, k_new.shape[2])
         # Write the new token if THIS shard owns its within-page
         # offset; the local flat index of global position p is
         # (p // page) * psub + (p % page) % psub.
@@ -463,9 +407,7 @@ class PagedChunkAttention:
         prefill_chunk=3 / page_size=4). A trailing index past the real
         span rewrites a just-gathered page with its own bytes."""
         num_pages = pool_layer.shape[0]
-        pages = view.reshape(
-            -1, self.page, view.shape[-2], view.shape[-1]
-        )
+        pages = view.reshape(-1, *pool_layer.shape[1:])
         idx = self.start // self.page + jnp.arange(
             (chunk - 1) // self.page + 2
         )
@@ -479,12 +421,12 @@ class PagedChunkAttention:
     def __call__(self, q, k_new, v_new, mask):
         i = self.layer
         self.layer += 1
-        chunk = k_new.shape[1]
+        chunk, heads = k_new.shape[1], k_new.shape[2]
         kview = self._write_chunk(
-            _gather_pages(self.k[i], self.bt[None])[0][None], k_new
+            _gather_pages(self.k[i], self.bt[None], heads)[0][None], k_new
         )
         vview = self._write_chunk(
-            _gather_pages(self.v[i], self.bt[None])[0][None], v_new
+            _gather_pages(self.v[i], self.bt[None], heads)[0][None], v_new
         )
         self.k = self.k.at[i].set(
             self._scatter_touched(self.k[i], kview, chunk)
@@ -499,7 +441,7 @@ class PagedChunkAttention:
         valid = (
             jnp.arange(kview.shape[1])[None, :] <= qpos[:, None]
         )  # (Tq, view)
-        return dot_product_attention(
+        return grouped_query_attention(
             q, kview, vview, mask=valid[None, None]
         )
 
@@ -559,10 +501,7 @@ class PagedVerifyAttention:
         keeps write pages private), so the flattened scatter has no
         duplicate indices."""
         num_pages = pool_layer.shape[0]
-        s = view.shape[0]
-        pages = view.reshape(
-            s, -1, self.page, view.shape[-2], view.shape[-1]
-        )
+        pages = _as_pages(view, pool_layer)
         n_touch = (t - 1) // self.page + 2
         idx = (
             self.positions[:, None] // self.page
@@ -570,23 +509,27 @@ class PagedVerifyAttention:
         )  # (slots, n_touch) slot-local page indices
         safe = jnp.clip(idx, 0, pages.shape[1] - 1)
         touched = jnp.take_along_axis(
-            pages, safe[:, :, None, None, None], axis=1
+            pages, jnp.expand_dims(safe, tuple(range(2, pages.ndim))),
+            axis=1,
         )  # (slots, n_touch, page, H, Dh)
         dst = jnp.take_along_axis(self.bt, safe, axis=1)
         ok = (idx < pages.shape[1]) & (dst >= 0) \
             & self.active[:, None]
         dst = jnp.where(ok, dst, num_pages)  # OOB -> drop
         return pool_layer.at[dst.reshape(-1)].set(
-            touched.reshape(-1, self.page, *view.shape[-2:]),
-            mode="drop",
+            touched.reshape(-1, *pool_layer.shape[1:]), mode="drop",
         )
 
     def __call__(self, q, k_new, v_new, mask):
         i = self.layer
         self.layer += 1
-        t = k_new.shape[1]
-        kview = self._write_span(_gather_pages(self.k[i], self.bt), k_new)
-        vview = self._write_span(_gather_pages(self.v[i], self.bt), v_new)
+        t, heads = k_new.shape[1], k_new.shape[2]
+        kview = self._write_span(
+            _gather_pages(self.k[i], self.bt, heads), k_new
+        )
+        vview = self._write_span(
+            _gather_pages(self.v[i], self.bt, heads), v_new
+        )
         self.k = self.k.at[i].set(self._scatter_span(self.k[i], kview, t))
         self.v = self.v.at[i].set(self._scatter_span(self.v[i], vview, t))
         # Causal across the prefix boundary, per slot: query token j of
@@ -600,9 +543,82 @@ class PagedVerifyAttention:
             jnp.arange(kview.shape[1])[None, None, :]
             <= qpos[:, :, None]
         )  # (slots, Tq, view)
-        return dot_product_attention(
+        return grouped_query_attention(
             q, kview, vview, mask=valid[:, None]
         )
+
+
+# ------------------------------------------------------ state functions
+#
+# The state-pool twins of the attention recorders: a layer that keeps
+# arrays of constant size per sequence (`models/lm_family.LayerCache.
+# state`) calls `state_fn(advance)` once per step, `advance(state) ->
+# (output, new state)` on arrays with a leading row axis. Construct one
+# fresh per trace with the incoming `cache["state"]` tree; the calls
+# come in layer order, so each consumes the next state-holding layer.
+# After the blocks run, `.state` is the updated tree.
+
+
+class _StateRecorder:
+    def __init__(self, state: dict):
+        self.state = dict(state)
+        self._layers = iter(sorted(state, key=int))
+
+    def __call__(self, advance):
+        layer = next(self._layers)
+        out, new = advance(self.read(self.state[layer]))
+        self.state[layer] = self.write(self.state[layer], new)
+        return out
+
+
+class SlotStateDecode(_StateRecorder):
+    """state_fn for one traced decode step: every slot's state advances
+    by its one token; a slot that is not `active` keeps the state it
+    had (an empty slot's row is garbage nobody reads, a slot still
+    ingesting its prompt must not be disturbed)."""
+
+    def __init__(self, state: dict, active):
+        super().__init__(state)
+        self.active = active  # (slots,) bool
+
+    def read(self, arrays):
+        return arrays
+
+    def write(self, arrays, new):
+        keep = lambda n, o: jnp.where(
+            jnp.expand_dims(self.active, tuple(range(1, o.ndim))),
+            n.astype(o.dtype), o,
+        )
+        return {name: keep(new[name], old) for name, old in arrays.items()}
+
+
+class SlotStateChunk(_StateRecorder):
+    """state_fn for ONE chunked-prefill step of ONE slot: the chunk
+    starts from the state the chunk before it left in the slot's row,
+    or from zeros when it is the prompt's first (`start == 0`): that is
+    the whole reset of a recycled slot, no program and no host copy of
+    its own. What the layer hands back (it must not have advanced over
+    the chunk's padded tail) replaces the row."""
+
+    def __init__(self, state: dict, slot, start):
+        super().__init__(state)
+        self.slot = slot    # int32 row of the state pool
+        self.start = start  # int32 global position of chunk token 0
+
+    def read(self, arrays):
+        row = lambda a: lax.dynamic_slice_in_dim(a, self.slot, 1, axis=0)
+        return {
+            name: jnp.where(self.start == 0, jnp.zeros_like(row(a)), row(a))
+            for name, a in arrays.items()
+        }
+
+    def write(self, arrays, new):
+        return {
+            name: lax.dynamic_update_slice_in_dim(
+                old, new[name].astype(old.dtype), self.slot, axis=0
+            )
+            for name, old in arrays.items()
+        }
 
 
 # ---------------------------------------- decode-time collective matmul
@@ -734,6 +750,8 @@ __all__ = [
     "PagedVerifyAttention",
     "PrefillRecorder",
     "SeqShardedCacheAttention",
+    "SlotStateChunk",
+    "SlotStateDecode",
     "chunk_stem",
     "decode_ring_permutes",
     "decode_stem",

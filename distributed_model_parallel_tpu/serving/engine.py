@@ -9,7 +9,18 @@ continuous batching — Orca, PAPERS.md). The host loop
 requests into free slots (prefill), one decode step for the active
 set, evict finished sequences and recycle their slots.
 
-Parameters are the dense `models/gpt.gpt_lm` pytree — the SAME tree the
+The engine reaches a model through ONE seam, the `ServingFamily` its
+configuration answers `serving_family()` with (`models/lm_family.py`):
+the parameter init, a stem for each kind of step, the blocks given an
+attention function and a state function, the head, and per layer what
+the layer keeps between steps — pages of keys and values, or arrays of
+constant size per slot (the state pool beside the page pool,
+`serving/kv_cache.py`). It spells no family's fields. What a family
+cannot run yet it names (`ServingFamily.missing`), and the engine
+refuses each such option at construction under the option's name.
+
+For `models/gpt.GPTConfig` the parameters are the dense
+`models/gpt.gpt_lm` pytree — the SAME tree the
 TP and SP-LM training engines train (`TrainState.params` serves
 directly), placed per layout:
 
@@ -46,12 +57,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_model_parallel_tpu.models import layers as L
-from distributed_model_parallel_tpu.models.gpt import (
-    GPTConfig,
-    decoder_blocks,
-    gpt_lm,
-    head_apply,
-)
 from distributed_model_parallel_tpu.observability.metrics import (
     get_metrics,
 )
@@ -76,20 +81,20 @@ from distributed_model_parallel_tpu.serving.decode import (
     PagedVerifyAttention,
     PrefillRecorder,
     SeqShardedCacheAttention,
-    chunk_stem,
-    decode_stem,
-    prefill_stem,
-    verify_stem,
+    SlotStateChunk,
+    SlotStateDecode,
 )
 from distributed_model_parallel_tpu.serving.kv_cache import (
     KVCacheSpec,
     PagedCacheHost,
     PagedKVCacheSpec,
+    StatePoolSpec,
     cache_pspecs,
     cache_shardings,
     copy_page,
     init_cache,
     init_paged_cache,
+    init_state_pool,
     paged_pspecs,
     paged_shardings,
 )
@@ -103,15 +108,25 @@ from distributed_model_parallel_tpu.serving.scheduler import (
 )
 
 
+@jax.jit
+def greedy_pick(logits):
+    """Greedy sampling on the device: each row's argmax (the first of
+    equal maxima, as NumPy's), so that the paged loop fetches one id a
+    row and not the row of float32 logits (32 x 65,536 of them are
+    8.4 MB a step, and the device idles while they cross)."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 @dataclasses.dataclass
 class ServingEngine:
-    """Autoregressive serving over `models/gpt` configs (module doc)."""
+    """Autoregressive serving of any configuration that answers
+    `serving_family()` (module doc)."""
 
-    cfg: GPTConfig
+    cfg: Any
     mesh: Optional[Mesh] = None
     layout: str = "replicated"  # replicated | tp | sp
     num_slots: int = 4
-    max_len: Optional[int] = None  # cache positions; <= cfg.max_position
+    max_len: Optional[int] = None  # cache positions; <= max_position
     prefill_len: Optional[int] = None  # padded prompt length; <= max_len
     # Latency-hiding decode rings over 'model' (tp layout only):
     # `serving/decode.DecodeCollectiveMatmul`. Default off, same math.
@@ -153,23 +168,34 @@ class ServingEngine:
     speculative_k: int = 0
 
     def __post_init__(self):
-        cfg = self.cfg
-        self.max_len = self.max_len or cfg.max_position
+        fam = self.family = self.cfg.serving_family()
+        self.max_len = self.max_len or fam.max_position
         self.prefill_len = self.prefill_len or self.max_len
-        if self.max_len > cfg.max_position:
+        if self.max_len > fam.max_position:
             raise ValueError(
                 f"max_len {self.max_len} exceeds the position table "
-                f"(cfg.max_position={cfg.max_position})"
+                f"(cfg.max_position={fam.max_position})"
             )
         if not 1 <= self.prefill_len <= self.max_len:
             raise ValueError(
                 f"prefill_len {self.prefill_len} must be in "
                 f"[1, max_len={self.max_len}]"
             )
-        if cfg.dim % cfg.num_heads:
-            raise ValueError(
-                f"dim {cfg.dim} not divisible by heads {cfg.num_heads}"
-            )
+        # What the family cannot run yet, refused under the option's
+        # name with the mechanism that is missing.
+        asked = {
+            "prefix_cache": self.prefix_cache,
+            "speculative_k": bool(self.speculative_k),
+            f"layout={self.layout}": True,
+            "page_size=None": self.page_size is None,
+            "prefill_chunk=None": self.prefill_chunk is None,
+        }
+        for option, why in fam.missing.items():
+            if asked.get(option):
+                raise ValueError(
+                    f"{option} is not built for the {fam.name} family: "
+                    f"{why}"
+                )
         # Normalize the knob once: the string triple {"f32","bf16",
         # "int8"} is the engine/CLI surface; dtype objects map onto it.
         self.compute_mode = normalize_compute_dtype(self.compute_dtype)
@@ -186,10 +212,30 @@ class ServingEngine:
                 "decode has no quantized policy path"
             )
         cache_dtype = self._act_dtype or jnp.float32
+        # Per layer, what it keeps between steps: pages (the layers
+        # that hold them share one pool, so one shape) or state.
+        paged_kinds = {
+            (lc.kv_heads, lc.head_dim) for lc in fam.layers if lc.kv_heads
+        }
+        if len(paged_kinds) != 1:
+            raise ValueError(
+                f"the {fam.name} family's layers cache {sorted(paged_kinds)} "
+                "(heads, width): one page pool holds one shape"
+            )
+        (kv_heads, head_dim), = paged_kinds
+        paged_layers = sum(1 for lc in fam.layers if lc.kv_heads)
+        stateful = tuple(
+            (i, tuple((name, tuple(shape), dtype or cache_dtype)
+                      for name, (shape, dtype) in lc.state.items()))
+            for i, lc in enumerate(fam.layers) if lc.state
+        )
+        self.state_spec = (
+            StatePoolSpec(self.num_slots, stateful) if stateful else None
+        )
         self.spec = KVCacheSpec(
-            num_layers=cfg.num_layers, num_slots=self.num_slots,
-            max_len=self.max_len, num_heads=cfg.num_heads,
-            head_dim=cfg.dim // cfg.num_heads, dtype=cache_dtype,
+            num_layers=paged_layers, num_slots=self.num_slots,
+            max_len=self.max_len, num_heads=kv_heads,
+            head_dim=head_dim, dtype=cache_dtype,
         )
         self.spec.validate(self.layout, self.mesh)
         self.paged_spec = None
@@ -210,15 +256,17 @@ class ServingEngine:
         else:
             pages_per_slot = -(-self.max_len // self.page_size)
             self.paged_spec = PagedKVCacheSpec(
-                num_layers=cfg.num_layers, num_slots=self.num_slots,
+                num_layers=paged_layers, num_slots=self.num_slots,
                 max_len=self.max_len, page_size=self.page_size,
                 num_pages=(
                     self.num_pages
                     if self.num_pages is not None
                     else self.num_slots * pages_per_slot
                 ),
-                num_heads=cfg.num_heads,
-                head_dim=cfg.dim // cfg.num_heads, dtype=cache_dtype,
+                num_heads=kv_heads, head_dim=head_dim,
+                dtype=cache_dtype,
+                # a unit head axis would be padded to a tile of rows
+                fold_heads=kv_heads == 1 and self.layout == "replicated",
             )
             self.paged_spec.validate(self.layout, self.mesh)
             if self.prefill_chunk is not None:
@@ -305,9 +353,7 @@ class ServingEngine:
                     )
                 for n, label in (
                     (self.num_slots, "num_slots"),
-                    (3 * cfg.dim, "qkv width (3*dim)"),
-                    (cfg.dim, "dim"),
-                    (cfg.ffn_dim, "ffn_dim"),
+                    *((n, label) for label, n in fam.ring_widths.items()),
                 ):
                     if n % s:
                         raise ValueError(
@@ -339,9 +385,9 @@ class ServingEngine:
                 )
         # Dense-parameter twin: init + checkpoint interop with the
         # training engines (identical pytree).
-        self._full = gpt_lm(cfg)
+        self._full = fam.model()
         self._blocks_state = {
-            str(i): {} for i in range(cfg.num_layers)
+            str(i): {} for i in range(len(fam.layers))
         }
         self._build_shardings()
         self._build_steps()
@@ -375,11 +421,16 @@ class ServingEngine:
             paged_shardings(mesh, self.layout)
             if self.paged_spec is not None else None
         )
+        if self._paged_sh is not None and self.state_spec is not None:
+            self._paged_sh["state"] = jax.tree_util.tree_map(
+                lambda _: self._repl,
+                jax.eval_shape(partial(init_state_pool, self.state_spec)),
+            )
 
     # ----------------------------------------------------------- steps
 
     def _build_steps(self):
-        cfg = self.cfg
+        fam = self.family
         cdt = self._act_dtype
         num_slots = self.num_slots
         max_len = self.max_len
@@ -388,8 +439,9 @@ class ServingEngine:
         mm = self._decode_mm
         ctx = L.Context(train=False, dtype=cdt)
 
-        def run_blocks(params, x, attention_fn, block_ctx):
-            blocks = L.sequential(*decoder_blocks(cfg, attention_fn))
+        def run_blocks(params, x, attention_fn, block_ctx,
+                       state_fn=None):
+            blocks = L.sequential(*fam.blocks(attention_fn, state_fn))
             (h, _), _ = blocks.apply(
                 params["blocks"], blocks_state, x, block_ctx
             )
@@ -401,16 +453,13 @@ class ServingEngine:
             rec = CacheAttention(
                 cache["k"], cache["v"], positions, active
             )
-            h = decode_stem(
-                params["stem"], tokens,
-                jnp.clip(positions, 0, cfg.max_position - 1), cdt,
-            )
+            h = fam.decode_stem(params, tokens, positions, cdt)
             mask = jnp.ones((num_slots, 1), jnp.bool_)
             h = run_blocks(
                 params, (h, mask), rec,
                 dataclasses.replace(ctx, matmul=mm),
             )
-            logits = head_apply(params["head"], h)[:, 0, :]
+            logits = fam.head(params, h)[:, 0, :]
             new_lengths = jnp.where(active, positions + 1, positions)
             new_cache = {
                 "k": rec.k, "v": rec.v, "lengths": new_lengths,
@@ -422,13 +471,10 @@ class ServingEngine:
             rec = SeqShardedCacheAttention(
                 cache["k"], cache["v"], positions, active, axis="seq"
             )
-            h = decode_stem(
-                params["stem"], tokens,
-                jnp.clip(positions, 0, cfg.max_position - 1), cdt,
-            )
+            h = fam.decode_stem(params, tokens, positions, cdt)
             mask = jnp.ones((num_slots, 1), jnp.bool_)
             h = run_blocks(params, (h, mask), rec, ctx)
-            logits = head_apply(params["head"], h)[:, 0, :]
+            logits = fam.head(params, h)[:, 0, :]
             new_lengths = jnp.where(active, positions + 1, positions)
             new_cache = {
                 "k": rec.k, "v": rec.v, "lengths": new_lengths,
@@ -438,12 +484,12 @@ class ServingEngine:
         # --- prefill: one padded prompt into one slot ----------------
         def prefill_step(params, cache, ids, length, slot):
             mask = jnp.arange(p_len)[None, :] < length  # (1, P)
-            h = prefill_stem(params["stem"], ids, 0, cdt)
+            h = fam.prefill_stem(params, ids, 0, cdt)
             rec = PrefillRecorder(
                 partial(dot_product_attention, causal=True)
             )
             h = run_blocks(params, (h, mask), rec, ctx)
-            logits = head_apply(params["head"], h)  # (1, P, V) f32
+            logits = fam.head(params, h)  # (1, P, V) f32
             next_logits = lax.dynamic_index_in_dim(
                 logits[0], length - 1, axis=0, keepdims=False
             )
@@ -476,12 +522,12 @@ class ServingEngine:
             idx = lax.axis_index("seq")
             offset = idx * tl
             gmask = (offset + jnp.arange(tl))[None, :] < length
-            h = prefill_stem(params["stem"], ids, offset, cdt)
+            h = fam.prefill_stem(params, ids, offset, cdt)
             rec = PrefillRecorder(
                 partial(ring_attention, axis_name="seq", causal=True)
             )
             h = run_blocks(params, (h, gmask), rec, ctx)
-            logits = head_apply(params["head"], h)  # (1, tl, V)
+            logits = fam.head(params, h)  # (1, tl, V)
             # The next-token logits live on the shard owning global
             # position length-1; psum broadcasts that one row.
             owner = (length - 1) // tl
@@ -491,7 +537,7 @@ class ServingEngine:
                 lax.dynamic_index_in_dim(
                     logits[0], li, axis=0, keepdims=False
                 ),
-                jnp.zeros((cfg.vocab_size,), jnp.float32),
+                jnp.zeros((fam.vocab_size,), jnp.float32),
             )
             next_logits = lax.psum(row, "seq")
             # Each cache shard owns positions [idx*chunk, (idx+1)*chunk);
@@ -531,22 +577,33 @@ class ServingEngine:
         paged = self.paged_spec
         page = paged.page_size if paged else 0
 
+        has_state = self.state_spec is not None
+
+        def new_cache(rec, states):
+            """The cache tree after a step: the recorders' pages, and
+            the state pool where the family keeps one."""
+            out = {"k": rec.k, "v": rec.v}
+            if states is not None:
+                out["state"] = states.state
+            return out
+
         def paged_decode_step(params, cache, bt, positions, tokens,
                               active):
             rec = PagedCacheAttention(
                 cache["k"], cache["v"], bt, positions, active, page
             )
-            h = decode_stem(
-                params["stem"], tokens,
-                jnp.clip(positions, 0, cfg.max_position - 1), cdt,
+            states = (
+                SlotStateDecode(cache["state"], active) if has_state
+                else None
             )
+            h = fam.decode_stem(params, tokens, positions, cdt)
             mask = jnp.ones((num_slots, 1), jnp.bool_)
             h = run_blocks(
                 params, (h, mask), rec,
-                dataclasses.replace(ctx, matmul=mm),
+                dataclasses.replace(ctx, matmul=mm), states,
             )
-            logits = head_apply(params["head"], h)[:, 0, :]
-            return {"k": rec.k, "v": rec.v}, logits
+            logits = fam.head(params, h)[:, 0, :]
+            return new_cache(rec, states), logits
 
         def sp_paged_decode_step(params, cache, bt, positions, tokens,
                                  active):
@@ -554,13 +611,10 @@ class ServingEngine:
                 cache["k"], cache["v"], bt, positions, active, page,
                 axis="seq",
             )
-            h = decode_stem(
-                params["stem"], tokens,
-                jnp.clip(positions, 0, cfg.max_position - 1), cdt,
-            )
+            h = fam.decode_stem(params, tokens, positions, cdt)
             mask = jnp.ones((num_slots, 1), jnp.bool_)
             h = run_blocks(params, (h, mask), rec, ctx)
-            logits = head_apply(params["head"], h)[:, 0, :]
+            logits = fam.head(params, h)[:, 0, :]
             return {"k": rec.k, "v": rec.v}, logits
 
         def _scatter_slot_pages(buf, stack, bt_row):
@@ -569,19 +623,19 @@ class ServingEngine:
             n_pages = paged.pages_per_slot
             pad = ((0, 0), (0, n_pages * page - p_len), (0, 0), (0, 0))
             pages = jnp.pad(stack, pad).reshape(
-                stack.shape[0], n_pages, page, *stack.shape[2:]
+                stack.shape[0], n_pages, *buf.shape[2:]
             ).astype(buf.dtype)
             dst = jnp.where(bt_row >= 0, bt_row, paged.num_pages)
             return buf.at[:, dst].set(pages, mode="drop")
 
         def paged_prefill_step(params, cache, bt_row, ids, length):
             mask = jnp.arange(p_len)[None, :] < length
-            h = prefill_stem(params["stem"], ids, 0, cdt)
+            h = fam.prefill_stem(params, ids, 0, cdt)
             rec = PrefillRecorder(
                 partial(dot_product_attention, causal=True)
             )
             h = run_blocks(params, (h, mask), rec, ctx)
-            logits = head_apply(params["head"], h)
+            logits = fam.head(params, h)
             next_logits = lax.dynamic_index_in_dim(
                 logits[0], length - 1, axis=0, keepdims=False
             )
@@ -599,12 +653,12 @@ class ServingEngine:
             idx = lax.axis_index("seq")
             offset = idx * tl
             gmask = (offset + jnp.arange(tl))[None, :] < length
-            h = prefill_stem(params["stem"], ids, offset, cdt)
+            h = fam.prefill_stem(params, ids, offset, cdt)
             rec = PrefillRecorder(
                 partial(ring_attention, axis_name="seq", causal=True)
             )
             h = run_blocks(params, (h, gmask), rec, ctx)
-            logits = head_apply(params["head"], h)
+            logits = fam.head(params, h)
             owner = (length - 1) // tl
             li = jnp.clip(length - 1 - offset, 0, tl - 1)
             row = jnp.where(
@@ -612,7 +666,7 @@ class ServingEngine:
                 lax.dynamic_index_in_dim(
                     logits[0], li, axis=0, keepdims=False
                 ),
-                jnp.zeros((cfg.vocab_size,), jnp.float32),
+                jnp.zeros((fam.vocab_size,), jnp.float32),
             )
             next_logits = lax.psum(row, "seq")
             n_pages = paged.pages_per_slot
@@ -642,18 +696,22 @@ class ServingEngine:
         chunk = self.prefill_chunk or 0
 
         def chunk_prefill_step(params, cache, bt_row, ids, start,
-                               n_valid):
+                               n_valid, slot=None):
+            # `slot`: the sequence's row of the state pool, handed in
+            # by the host loop iff the family keeps one
+            states = (
+                SlotStateChunk(cache["state"], slot, start)
+                if has_state else None
+            )
             rec = PagedChunkAttention(
                 cache["k"], cache["v"], bt_row, start, page
             )
-            h = chunk_stem(params["stem"], ids, start, cdt)
+            h = fam.chunk_stem(params, ids, start, cdt)
             mask = jnp.arange(chunk)[None, :] < n_valid
-            h = run_blocks(params, (h, mask), rec, ctx)
-            logits = head_apply(params["head"], h)
-            next_logits = lax.dynamic_index_in_dim(
-                logits[0], n_valid - 1, axis=0, keepdims=False
-            )
-            return {"k": rec.k, "v": rec.v}, next_logits
+            h = run_blocks(params, (h, mask), rec, ctx, states)
+            # the head on the one row the chunk needs
+            next_logits = fam.head_row(params, h, n_valid - 1)
+            return new_cache(rec, states), next_logits
 
         # --- speculative verify: all slots' k+1-token spans, one step -
         # The chunk-shaped twin of paged_decode_step: same recorder
@@ -668,15 +726,13 @@ class ServingEngine:
             rec = PagedVerifyAttention(
                 cache["k"], cache["v"], bt, positions, active, page
             )
-            h = verify_stem(
-                params["stem"], tokens_chunk, positions, cdt
-            )
+            h = fam.verify_stem(params, tokens_chunk, positions, cdt)
             mask = jnp.ones((num_slots, spec_t), jnp.bool_)
             h = run_blocks(
                 params, (h, mask), rec,
                 dataclasses.replace(ctx, matmul=mm),
             )
-            logits = head_apply(params["head"], h)  # (slots, k+1, V)
+            logits = fam.head(params, h)  # (slots, k+1, V)
             return {"k": rec.k, "v": rec.v}, logits
 
         verify_fn = paged_verify_step if self.speculative_k else None
@@ -751,7 +807,8 @@ class ServingEngine:
         * `decode_step(params, cache, bt, positions, tokens, active)`
         * `prefill(params, cache, bt_row, ids, length)` — monolithic
         * `chunk_prefill(params, cache, bt_row, ids, start, n_valid)`
-          (only when `prefill_chunk` is set)
+          (only when `prefill_chunk` is set; with a state pool a
+          seventh argument follows, `slot`: the sequence's row of it)
         * `verify_step(params, cache, bt, positions, tokens_chunk,
           active)` — speculative k+1-position scoring (only when
           `speculative_k` is set); logits (slots, k+1, vocab)
@@ -818,6 +875,7 @@ class ServingEngine:
                     chunk_fn,
                     in_shardings=(
                         self._param_sh, self._paged_sh, r, r, r, r,
+                        *((r,) if self.state_spec is not None else ()),
                     ),
                     out_shardings=(self._paged_sh, r),
                     donate_argnums=donate,
@@ -851,11 +909,23 @@ class ServingEngine:
     # ------------------------------------------------------------ state
 
     def init_params(self, rng: jax.Array):
-        """Fresh dense-twin parameters (`gpt_lm(cfg)` pytree — a trained
-        TrainState.params from the TP / SP-LM engines drops in via
-        `place_params`)."""
-        params, _ = self._full.init(rng)
-        return self.place_params(params)
+        """Fresh parameters of the family's whole model (for GPT the
+        `gpt_lm(cfg)` pytree — a trained TrainState.params from the TP /
+        SP-LM engines drops in via `place_params`), in the dtype the
+        family's weights rest in and in this layout's placement: one
+        jitted call, so no float32 copy of a whole tree that rests in
+        less ever exists."""
+        at_rest = self.family.param_dtype
+
+        def init(rng):
+            params, _ = self._full.init(rng)
+            if at_rest is None:
+                return params
+            return jax.tree_util.tree_map(
+                lambda x: x.astype(at_rest), params
+            )
+
+        return jax.jit(init, out_shardings=self._param_sh)(rng)
 
     def place_params(self, params):
         """Place an existing dense-layout param pytree (a checkpoint or
@@ -867,6 +937,8 @@ class ServingEngine:
     def init_cache(self) -> dict:
         if self.paged_spec is not None:
             cache = init_paged_cache(self.paged_spec)
+            if self.state_spec is not None:
+                cache["state"] = init_state_pool(self.state_spec)
             if self._paged_sh is None:
                 return cache
             return jax.device_put(cache, self._paged_sh)
@@ -914,12 +986,14 @@ class ServingEngine:
     @property
     def _slot_stripe_bytes(self) -> int:
         """Contiguous-equivalent bytes one live slot would pin (the
-        scheduler's SlotAllocator accounting seam)."""
+        scheduler's SlotAllocator accounting seam): a `max_len` stripe
+        of every paged layer's K and V, and the slot's row of the state
+        pool."""
         s = self.spec
         return (
             2 * s.num_layers * s.max_len * s.num_heads * s.head_dim
             * jnp.dtype(s.dtype).itemsize
-        )
+        ) + (self.state_spec.slot_bytes if self.state_spec else 0)
 
     def run(self, params, requests: Sequence[Request],
             sampling: Optional[SamplingConfig] = None, *,
@@ -1083,7 +1157,32 @@ class ServingEngine:
             "slot_steps_ingesting", "slot_steps_page_blocked",
             "slot_steps_drain_out", "slot_steps_free_other",
             "admit_page_blocked_iters",
+            # chunked prefill: prompt positions ingested, positions the
+            # chunk program ran over (every chunk is padded to
+            # prefill_chunk), and chunks that began a prompt, each of
+            # which starts its slot's row of the state pool from zeros
+            "prefill_positions_valid", "prefill_positions_computed",
+            "state_resets",
         ), 0)
+        state_pool_bytes = (
+            self.state_spec.pool_bytes if self.state_spec else 0
+        )
+        if mx.enabled:
+            mx.gauge("serve_state_pool_bytes", state_pool_bytes)
+        # the chunk step of a family with a state pool also takes the
+        # slot, its row of the pool; a page-only family's has no such
+        # argument
+        state_row = (
+            (lambda slot: (np.int32(slot),)) if self.state_spec
+            else (lambda slot: ())
+        )
+        # What the host fetches of a step's logits, and how it reads a
+        # row's token there: greedy picks on the device and fetches the
+        # ids, a sampler draws from the fetched rows.
+        if sampler is None:
+            to_fetch, pick = greedy_pick, (lambda row, slot: int(row))
+        else:
+            to_fetch, pick = (lambda logits: logits), sampler.pick
 
         def evict(slot):
             sched.finish(slot)
@@ -1156,7 +1255,11 @@ class ServingEngine:
                         active[seq.slot] = True
                         if seq.done(self.max_len):
                             evict(seq.slot)
-                    elif covered >= prompt.size - 1:
+                    elif (covered >= prompt.size - 1
+                          and self.state_spec is None):
+                        # (A state pool has no cached state to resume
+                        # from, so there every prompt ingests: its first
+                        # chunk is what resets the slot's state.)
                         # Full prefix hit: every needed position is
                         # cached — SKIP prefill entirely and decode the
                         # last prompt token at its own position. Its
@@ -1174,6 +1277,10 @@ class ServingEngine:
                 prompt, start, acc = ingest[slot]
                 seq = sched.active[slot]
                 n = min(self.prefill_chunk, int(prompt.size) - start)
+                tally["prefill_positions_valid"] += n
+                tally["prefill_positions_computed"] += self.prefill_chunk
+                if state_pool_bytes and start == 0:
+                    tally["state_resets"] += 1
                 host.ensure_pages(slot, start + n)
                 ids = np.zeros((1, self.prefill_chunk), np.int32)
                 ids[0, :n] = prompt[start:start + n]
@@ -1183,10 +1290,13 @@ class ServingEngine:
                     slot=slot, start=start,
                 ):
                     with tracer.span("dispatch"):
+                        # (host values as they are: the step's call
+                        # uploads them with the launch, where an array
+                        # made of each first is a transfer of its own)
                         cache, nl = self.chunk_prefill(
                             params, cache, host.device_row(slot),
-                            jnp.asarray(ids), jnp.int32(start),
-                            jnp.int32(n),
+                            ids, np.int32(start), np.int32(n),
+                            *state_row(slot),
                         )
                     done_ingest = start + n >= prompt.size
                     if done_ingest:
@@ -1197,9 +1307,9 @@ class ServingEngine:
                             with tracer.span("device_wait"):
                                 jax.block_until_ready(nl)
                         with tracer.span("logits_fetch"):
-                            row = np.asarray(nl)
+                            row = np.asarray(to_fetch(nl))
                         with tracer.span("sample"):
-                            tok = self._pick(sampler, row, slot)
+                            tok = pick(row, slot)
                 dt = tracer.now() - t0
                 useful += 1
                 if done_ingest:
@@ -1231,14 +1341,13 @@ class ServingEngine:
                     with tracer.span("dispatch"):
                         cache, logits = self.decode_step(
                             params, cache, host.device_table(),
-                            jnp.asarray(positions), jnp.asarray(tokens),
-                            jnp.asarray(active),
+                            positions, tokens, active,
                         )
                     if tracer.enabled:
                         with tracer.span("device_wait"):
                             jax.block_until_ready(logits)
                     with tracer.span("logits_fetch"):
-                        logits_np = np.asarray(logits)
+                        rows = np.asarray(to_fetch(logits))
                 dt = tracer.now() - t0
                 sched.record_decode_step(n_active)
                 # Where this step's other slot-steps went: every slot
@@ -1261,7 +1370,7 @@ class ServingEngine:
                     for slot, seq in list(sched.active.items()):
                         if slot in ingest or not active[slot]:
                             continue
-                        tok = self._pick(sampler, logits_np[slot], slot)
+                        tok = pick(rows[slot], slot)
                         first = not seq.generated
                         if first:
                             # A full prefix hit's first token arrives
@@ -1301,7 +1410,9 @@ class ServingEngine:
             "pages_in_use_peak": host.pages_in_use_peak,
             "kv_cache_bytes_peak": (
                 host.pages_in_use_peak * self.paged_spec.page_bytes
+                + state_pool_bytes
             ),
+            "state_pool_bytes": state_pool_bytes,
             "contiguous_bytes": (
                 self.num_slots * self._slot_stripe_bytes
             ),

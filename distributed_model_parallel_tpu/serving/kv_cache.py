@@ -22,6 +22,14 @@ Two granularities share this module:
   between slots; a write into a shared page copies it first
   (copy-on-write, engine-side).
 
+* **State pool** (`StatePoolSpec`): beside the pages, for layers that
+  keep no keys and values but arrays of CONSTANT size per sequence (a
+  state-space layer's recurrent state and its convolution's last
+  inputs): `{"state": {layer: {name: (slots, ...)}}}` in the same
+  cache tree the steps donate, one row per slot, no pages, no host
+  bookkeeping. A slot that is recycled is not cleared: the step that
+  ingests a prompt's first chunk starts from zeros instead.
+
 A SLOT remains the unit of admission (Orca's iteration-level
 scheduling): each active request owns one slot for its lifetime and
 eviction is a host-side free-list operation (`SlotAllocator`) — the
@@ -53,6 +61,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import heapq
 from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -210,6 +219,21 @@ class PagedKVCacheSpec:
     num_heads: int
     head_dim: int
     dtype: Any = jnp.float32
+    # A page's rows are (page_size, num_heads * head_dim) instead of
+    # (page_size, num_heads, head_dim). The TPU tiles an array's two
+    # minor axes, so a pool of ONE cached head of 128 laid out
+    # (..., 1, 128) would pad its unit axis to a whole tile of rows, 16
+    # times its bytes in bfloat16. The paged attention classes reshape
+    # a gathered view to heads either way. Not with the tp layout,
+    # which shards the head axis.
+    fold_heads: bool = False
+
+    @property
+    def page_shape(self) -> Tuple[int, ...]:
+        """One page of one layer's keys (or values)."""
+        if self.fold_heads:
+            return (self.page_size, self.num_heads * self.head_dim)
+        return (self.page_size, self.num_heads, self.head_dim)
 
     @property
     def pages_per_slot(self) -> int:
@@ -250,10 +274,12 @@ class PagedKVCacheSpec:
             raise ValueError(f"layout {layout!r} needs a mesh")
         if layout == "tp":
             s = mesh.shape["model"]
-            if self.num_heads % s:
+            if self.fold_heads or self.num_heads % s:
                 raise ValueError(
                     f"tp cache shards heads over 'model': num_heads "
                     f"{self.num_heads} not divisible by {s} shards"
+                    + (" (fold_heads leaves no head axis)"
+                       if self.fold_heads else "")
                 )
         if layout == "sp":
             s = mesh.shape["seq"]
@@ -293,13 +319,45 @@ def init_paged_cache(spec: PagedKVCacheSpec) -> dict:
     contiguous cache, `lengths` is NOT device state — the host loop
     owns every slot's position (it owns the block table anyway), so
     positions ride in as a step argument."""
-    kv_shape = (
-        spec.num_layers, spec.num_pages, spec.page_size,
-        spec.num_heads, spec.head_dim,
-    )
+    kv_shape = (spec.num_layers, spec.num_pages, *spec.page_shape)
     return {
         "k": jnp.zeros(kv_shape, spec.dtype),
         "v": jnp.zeros(kv_shape, spec.dtype),
+    }
+
+
+@dataclasses.dataclass(frozen=True)
+class StatePoolSpec:
+    """Static shape of the STATE POOL (module docstring): for each
+    layer that keeps state, by its index in the model, the arrays of
+    one slot as (name, shape, dtype)."""
+
+    num_slots: int
+    layers: Tuple[Tuple[int, Tuple[Tuple[str, Tuple[int, ...], Any], ...]],
+                  ...]
+
+    @property
+    def slot_bytes(self) -> int:
+        """Bytes one slot's state pins across all layers."""
+        return sum(
+            int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+            for _, arrays in self.layers for _, shape, dtype in arrays
+        )
+
+    @property
+    def pool_bytes(self) -> int:
+        return self.num_slots * self.slot_bytes
+
+
+def init_state_pool(spec: StatePoolSpec) -> dict:
+    """Zero-filled {layer: {name: (slots, *shape)}}, the `"state"`
+    entry of the cache tree."""
+    return {
+        str(layer): {
+            name: jnp.zeros((spec.num_slots, *shape), dtype)
+            for name, shape, dtype in arrays
+        }
+        for layer, arrays in spec.layers
     }
 
 
@@ -317,6 +375,8 @@ class PagePool:
             raise ValueError(f"num_pages must be >= 1, got {num_pages}")
         self.num_pages = num_pages
         self.page_bytes = int(page_bytes)
+        # a heap: the lowest free page in log time, on the path of
+        # every chunk's launch
         self._free: List[int] = list(range(num_pages))
         self._refs: Dict[int, int] = {}
 
@@ -342,8 +402,7 @@ class PagePool:
                 "are live — size the pool larger (--kv-pages) or admit "
                 "fewer concurrent sequences"
             )
-        page = min(self._free)
-        self._free.remove(page)
+        page = heapq.heappop(self._free)
         self._refs[page] = 1
         return page
 
@@ -361,7 +420,7 @@ class PagePool:
             self._refs[page] = n - 1
             return False
         del self._refs[page]
-        self._free.append(page)
+        heapq.heappush(self._free, page)
         return True
 
 
@@ -513,8 +572,9 @@ def copy_page(cache: dict, src, dst) -> dict:
     engine jits this once with the cache donated, so a COW costs one
     tiny in-place scatter, not a pool copy."""
     return {
-        name: buf.at[:, dst].set(buf[:, src])
-        for name, buf in cache.items()
+        **cache,
+        **{name: cache[name].at[:, dst].set(cache[name][:, src])
+           for name in ("k", "v")},
     }
 
 
@@ -568,9 +628,13 @@ class PagedCacheHost:
 
     def device_row(self, slot: int):
         """One slot's block-table row — the per-slot steps (prefill,
-        chunk ingest) take only their own row, sliced from the cached
-        device mirror."""
-        return self.device_table()[slot]
+        chunk ingest) take only their own row: a copy of the host
+        table's, which the step's call uploads with its launch. (Not
+        sliced from the device mirror: a step that ingests has just
+        allocated pages, which invalidates the mirror, so a slice
+        costs a whole-table upload and a gather program on the device
+        ahead of the step.)"""
+        return self.block_tables[slot].copy()
 
     def _note_peak(self) -> None:
         self.pages_in_use_peak = max(
@@ -588,9 +652,10 @@ class PagedCacheHost:
         into a fresh page)."""
         total = 0
         for slot, commit in self._commit.items():
+            row = self.block_tables[slot]
             private = sum(
-                1 for pid in self.block_tables[slot]
-                if pid >= 0 and self.pool.refcount(int(pid)) == 1
+                1 for pid in row[row >= 0].tolist()
+                if self.pool.refcount(pid) == 1
             )
             total += max(0, commit - private)
         return total
@@ -713,11 +778,13 @@ __all__ = [
     "PagedKVCacheSpec",
     "PrefixCache",
     "SlotAllocator",
+    "StatePoolSpec",
     "copy_page",
     "cache_pspecs",
     "cache_shardings",
     "init_cache",
     "init_paged_cache",
+    "init_state_pool",
     "paged_pspecs",
     "paged_shardings",
 ]

@@ -2,10 +2,10 @@
 default) or temperature / top-k / top-p sampling with a per-slot PRNG
 lane.
 
-Sampling runs on the HOST over the logits row the decode step already
-fetched (the engine reads every step's logits to feed the next token
-back in, so there is no extra device round-trip), which keeps it
-layout-independent — replicated, TP and SP serve the same math.
+Sampling runs on the HOST over the logits rows fetched after a step,
+which keeps it layout-independent — replicated, TP and SP serve the
+same math. Greedy needs no row: the paged loop takes each row's argmax
+on the device (`serving/engine.greedy_pick`) and fetches the ids.
 
 Determinism contract:
 
