@@ -548,6 +548,13 @@ def main(argv=None) -> dict:
             raise
         # a family's refusals, by the option's name
         raise SystemExit(f"--model-config {args.model_config}: {e}") from e
+    if engine.chunk_state_program and jax.process_index() == 0:
+        print(
+            f"==> {engine.family.name}: a chunk of {engine.prefill_chunk} "
+            f"positions runs the state layers' recurrence as the "
+            f"{engine.chunk_state_program}",
+            flush=True,
+        )
     draft_engine = draft_params = None
     if args.speculative_k:
         draft_cfg, draft_ckpt = _draft_config(args, cfg)

@@ -22,7 +22,10 @@ state-space layers carry order). Layer i (0-based) attends iff
   matrix is multiplied by is rounded to the activations' dtype, the
   recurrence's inputs are not: the compiler dropped that rounding in
   the chunk program and kept it elsewhere, PERF.md section 6);
-  `y + D * c`; output `(y * silu(z)) W_out`.
+  `y + D * c`; output `(y * silu(z)) W_out`. `selective_scan` picks
+  its program per call: on a TPU a chunk's stretch runs as one kernel
+  with the state resident on the chip, one position (the decode step)
+  and every other backend as the compiled loop.
   Between two steps of a sequence a layer keeps the convolution's last
   K - 1 inputs and the recurrence's state: it reaches them through the
   `state_fn` its blocks are built with (`models/lm_family.py`), so the
@@ -56,6 +59,7 @@ from distributed_model_parallel_tpu.ops.attention import (
 )
 from distributed_model_parallel_tpu.ops.ssm_scan import (
     conv_carry,
+    scan_kind,
     selective_scan,
 )
 
@@ -146,6 +150,9 @@ class JambaConfig:
                 for i in range(self.num_hidden_layers)
             ),
             param_dtype=jnp.dtype(self.param_dtype),
+            chunk_state_program=lambda chunk: scan_kind(
+                chunk, self.d_inner, self.mamba_d_state
+            ),
             missing={
                 "prefix_cache": (
                     state + ": a shared prefix page carries keys and "

@@ -114,6 +114,11 @@ class ServingFamily:
     # Weights at rest: `model().init` gives float32; the engine casts
     # the tree to this under the same jit. None keeps float32.
     param_dtype: Any = None
+    # (positions of one chunk) -> the program the layers that keep a
+    # state compile their recurrence to over such a stretch, by name
+    # ("kernel" | "loop"): what the model's own selector answers for
+    # the widths of this family. None for a family that keeps none.
+    chunk_state_program: Optional[Callable[[int], str]] = None
     # Widths the tp decode rings chunk over 'model', {label: n}.
     ring_widths: Dict[str, int] = dataclasses.field(default_factory=dict)
     # What of the engine this family cannot run yet, {option: the

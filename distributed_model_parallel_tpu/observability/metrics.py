@@ -128,6 +128,15 @@ METRIC_NAMES: Dict[str, str] = {
         "prefill_chunk) and `state_resets` (chunks that began a prompt "
         "and so started their slot's state from zeros)"
     ),
+    "serve_state_scan_kernel": (
+        "1 when the chunk program runs the recurrence of the layers that "
+        "keep a state as ONE kernel with the state resident on the chip "
+        "(ops/ssm_scan.py: on a TPU, at widths that tile), 0 when as the "
+        "compiled loop or for a family without such layers (gauge; "
+        "`ServingEngine.chunk_state_program`). `Scheduler.paged_stats` "
+        "counts the chunks dispatched to the kernel program as "
+        "`state_kernel_chunks`"
+    ),
     "serve_prefix_hits_total": (
         "requests whose prompt reused >= 1 cached prefix page "
         "(prompt caching; counter)"

@@ -232,6 +232,14 @@ class ServingEngine:
         self.state_spec = (
             StatePoolSpec(self.num_slots, stateful) if stateful else None
         )
+        # Which program the chunk step's state layers run their
+        # recurrence in, as the family's own selector answers for
+        # prefill_chunk positions (None: no state, or no chunk step).
+        self.chunk_state_program = (
+            fam.chunk_state_program(self.prefill_chunk)
+            if stateful and fam.chunk_state_program and self.prefill_chunk
+            else None
+        )
         self.spec = KVCacheSpec(
             num_layers=paged_layers, num_slots=self.num_slots,
             max_len=self.max_len, num_heads=kv_heads,
@@ -1163,12 +1171,17 @@ class ServingEngine:
             # which starts its slot's row of the state pool from zeros
             "prefill_positions_valid", "prefill_positions_computed",
             "state_resets",
+            # chunks whose program ran the state layers' recurrence as
+            # the kernel (0 for a page-only family and off a TPU)
+            "state_kernel_chunks",
         ), 0)
+        kernel_chunk = int(self.chunk_state_program == "kernel")
         state_pool_bytes = (
             self.state_spec.pool_bytes if self.state_spec else 0
         )
         if mx.enabled:
             mx.gauge("serve_state_pool_bytes", state_pool_bytes)
+            mx.gauge("serve_state_scan_kernel", float(kernel_chunk))
         # the chunk step of a family with a state pool also takes the
         # slot, its row of the pool; a page-only family's has no such
         # argument
@@ -1279,6 +1292,7 @@ class ServingEngine:
                 n = min(self.prefill_chunk, int(prompt.size) - start)
                 tally["prefill_positions_valid"] += n
                 tally["prefill_positions_computed"] += self.prefill_chunk
+                tally["state_kernel_chunks"] += kernel_chunk
                 if state_pool_bytes and start == 0:
                     tally["state_resets"] += 1
                 host.ensure_pages(slot, start + n)

@@ -17,7 +17,12 @@ itself in float32. With `--faults`, on the first seed, the same check
 with each fault of `tests/benchmark/test_bench_jamba.FAULTS` planted in
 the program (a bfloat16 pool; bfloat16 step sizes, factors and state
 inside the one-position step with the pool left float32; a skipped
-reset; an unmasked tail): each has to come out not `ok`. One JSON line
+reset; an unmasked tail): each has to come out not `ok`. The
+`bfloat16_step` plant patches `selective_step`, which on the chip only
+the decode step runs since the chunk program's recurrence is the
+`ssm_scan` kernel: here that plant also pins `ops/ssm_scan.py`'s
+selector to the loop, so that the fault is in BOTH timed
+programs and the check is shown to catch it in each. One JSON line
 a reading; `--toy` runs the rehearsal's widths on the CPU to try the
 script, its numbers mean nothing. ~1.5 min a check on the chip.
 """
@@ -56,6 +61,8 @@ def main() -> int:
     import jax
     import jax.numpy as jnp
     import pytest
+
+    from distributed_model_parallel_tpu.ops import ssm_scan
 
     spec = importlib.util.spec_from_file_location(
         "planted", os.path.join(ROOT, "tests/benchmark/test_bench_jamba.py"))
@@ -115,6 +122,10 @@ def main() -> int:
         if args.faults and seed == args.first_seed:
             for name, plant in planted.FAULTS.items():
                 with pytest.MonkeyPatch.context() as patch:
+                    if name == "bfloat16_step":
+                        # planted in the loop's body, which the kernel
+                        # does not call: the chunk program on the loop
+                        patch.setattr(ssm_scan, "_on_tpu", lambda: False)
                     plant(patch)
                     report(seed, name, **check(seed, params))
         del params

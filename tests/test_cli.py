@@ -656,6 +656,54 @@ def test_serve_cli_replicated(tmp_path):
     assert exported["counters"]["serve_tokens_total"] == 18
 
 
+def test_serve_cli_says_once_which_program_a_chunks_recurrence_runs_as(
+        tmp_path, capsys):
+    """`--model-config` with a family that keeps a state: one start-up
+    line names the program the chunk step's state layers run their
+    recurrence as at --prefill-chunk (off a TPU: the loop), from the
+    engine's own field; the gauge says the same and `paged` counts the
+    chunks dispatched to the kernel program. A GPT run prints no such
+    line."""
+    import json
+
+    from distributed_model_parallel_tpu.cli import serve
+    from distributed_model_parallel_tpu.observability import metrics
+
+    config = tmp_path / "config.json"
+    config.write_text(json.dumps({
+        "model_type": "jamba", "vocab_size": 97, "hidden_size": 32,
+        "num_hidden_layers": 3, "num_attention_heads": 4,
+        "num_key_value_heads": 1, "intermediate_size": 64,
+        "attn_layer_period": 3, "attn_layer_offset": 1,
+        "mamba_d_state": 4, "mamba_d_conv": 4, "mamba_dt_rank": 6,
+        "mamba_expand": 2, "rms_norm_eps": 1e-6,
+        "max_position_embeddings": 64,
+    }))
+    drain = [
+        "--num-slots", "2", "--max-len", "32", "--prefill-len", "32",
+        "--page-size", "4", "--prefill-chunk", "8", "--num-requests", "3",
+        "--prompt-len-min", "2", "--prompt-len-max", "12",
+        "--max-new-tokens", "3",
+    ]
+    mpath = tmp_path / "metrics.json"
+    try:
+        result = serve.main(["--model-config", str(config), *drain,
+                             "--metrics-out", str(mpath)])
+    finally:
+        metrics.set_metrics(None)
+    out = capsys.readouterr().out
+    line = ("==> jamba: a chunk of 8 positions runs the state layers' "
+            "recurrence as the loop")
+    assert out.count(line) == 1 and out.count("recurrence as the") == 1
+    assert result["serving"]["paged"]["state_kernel_chunks"] == 0
+    with open(mpath) as f:
+        assert json.load(f)["gauges"]["serve_state_scan_kernel"] == 0.0
+    assert "serve_state_scan_kernel" in metrics.METRIC_NAMES
+    serve.main(["--dim", "16", "--layers", "2", "--heads", "4",
+                "--ffn-dim", "32", "--vocab-size", "61", *drain])
+    assert "recurrence as the" not in capsys.readouterr().out
+
+
 @pytest.mark.slow
 def test_serve_cli_tp_collective_matmul():
     """--layout tp --collective-matmul drives the full serving entry

@@ -111,6 +111,9 @@ def test_chunked_prefill_and_decode_equal_the_full_forward(engine, params):
         -(-n // CHUNK) for n in LENGTHS)
     assert stats["state_resets"] == len(LENGTHS)
     assert stats["state_pool_bytes"] == engine.state_spec.pool_bytes > 0
+    # off a TPU the recurrence is the loop, in every chunk
+    assert engine.chunk_state_program == "loop"
+    assert stats["state_kernel_chunks"] == 0
     assert stats["cow_copies"] == 0
 
 
@@ -281,6 +284,78 @@ def _row(engine, slot, n_tokens):
     return host.device_row(slot)
 
 
+def drained(cfg, lengths, chunk):
+    """(engine, scheduler, the state pool the drain left) of a toy
+    drain over 2 slots."""
+    eng = ServingEngine(cfg, None, num_slots=2, max_len=256, page_size=16,
+                        prefill_chunk=chunk)
+    params = eng.init_params(jax.random.PRNGKey(3))
+    left = {}
+    chunk_prefill, decode_step = eng.chunk_prefill, eng.decode_step
+
+    def keep(step):
+        def spy(*args):
+            cache, out = step(*args)
+            left["state"] = cache["state"]
+            return cache, out
+        return spy
+
+    eng.chunk_prefill, eng.decode_step = keep(chunk_prefill), keep(decode_step)
+    try:
+        sched = eng.run(params, requests_of(lengths, new_tokens=4, seed=5))
+    finally:
+        eng.chunk_prefill, eng.decode_step = chunk_prefill, decode_step
+    return eng, sched, jax.tree_util.tree_map(np.asarray, left["state"])
+
+
+def test_the_chunk_program_with_the_kernel_drains_as_the_loop_does(
+        monkeypatch):
+    """The selector answering "kernel" (as on a TPU; the kernel itself
+    through the interpreter): the same greedy tokens and the same state
+    pool as the loop, a recycled slot and padded tails among the
+    requests; `state_kernel_chunks` counts the chunks dispatched."""
+    from distributed_model_parallel_tpu.ops import ssm_scan
+
+    # widths the kernel tiles: 128 channels, a state of 8, chunks of 64
+    cfg = dataclasses.replace(
+        CFG, hidden_size=64, num_hidden_layers=3, attn_layer_period=3,
+        mamba_d_state=8)
+    chunk, lengths = 64, [70, 3, 64, 130, 20]
+    loop_eng, loop, loop_state = drained(cfg, lengths, chunk)
+    assert loop_eng.chunk_state_program == "loop"
+    assert loop.paged_stats["state_kernel_chunks"] == 0
+
+    monkeypatch.setattr(ssm_scan, "_on_tpu", lambda: True)
+    eng, sched, state = drained(cfg, lengths, chunk)
+    assert eng.chunk_state_program == "kernel"
+    chunks = sum(-(-n // chunk) for n in lengths)
+    stats = sched.paged_stats
+    assert stats["state_kernel_chunks"] == chunks
+    assert stats["prefill_positions_computed"] == chunks * chunk
+    assert stats["state_resets"] == len(lengths)  # 5 prompts over 2 slots
+    tokens = lambda s: {f.rid: list(f.tokens) for f in s.finished}
+    assert tokens(sched) == tokens(loop) and len(tokens(sched)) == len(lengths)
+    for got, want in zip(jax.tree_util.tree_leaves(state),
+                         jax.tree_util.tree_leaves(loop_state)):
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    # the chunk program holds one kernel a state layer, the decode step
+    # (one position) none
+    params = jax.eval_shape(eng.init_params, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(eng.init_cache)
+    slots = np.zeros((2,), np.int32)
+    chunk_graph = str(jax.make_jaxpr(eng.chunk_prefill)(
+        params, cache, eng.new_host().device_row(0),
+        np.zeros((1, chunk), np.int32), np.int32(0), np.int32(chunk),
+        np.int32(0)))
+    decode_graph = str(jax.make_jaxpr(eng.decode_step)(
+        params, cache, eng.new_host().device_table(), slots, slots,
+        slots.astype(bool)))
+    # (the kernel is traced once, `name=ssm_scan`, and called a layer)
+    assert chunk_graph.count("name=ssm_scan") == 1
+    assert chunk_graph.count("jaxpr=_scan_call") == 2
+    assert "pallas_call" not in decode_graph
+
+
 def test_the_gpt_engine_answers_the_same_seam_with_what_it_did():
     cfg = GPTConfig(vocab_size=61, dim=32, num_layers=2, num_heads=4,
                     ffn_dim=64, max_position=64, dropout_rate=0.0,
@@ -301,6 +376,8 @@ def test_the_gpt_engine_answers_the_same_seam_with_what_it_did():
         rid=0, prompt=np.arange(1, 12, dtype=np.int32), max_new_tokens=4)])
     stats = sched.paged_stats
     assert stats["state_pool_bytes"] == 0 and stats["state_resets"] == 0
+    assert eng.chunk_state_program is None
+    assert stats["state_kernel_chunks"] == 0
     assert (stats["prefill_positions_valid"],
             stats["prefill_positions_computed"]) == (11, 16)
     # the programs keep the names the benchmark's readers look for

@@ -1,5 +1,7 @@
 """The selective scan, its one-token step and the carried convolution
-(`ops/ssm_scan.py`) against the recurrence written token by token."""
+(`ops/ssm_scan.py`) against the recurrence written token by token; the
+scan as one kernel (through the Pallas interpreter, at toy sizes)
+against the scan as a loop, and the selector between the two."""
 
 import jax
 import jax.numpy as jnp
@@ -9,7 +11,10 @@ import pytest
 from distributed_model_parallel_tpu.ops import ssm_scan
 from distributed_model_parallel_tpu.ops.ssm_scan import (
     conv_carry,
+    scan_kind,
     selective_scan,
+    selective_scan_kernel,
+    selective_scan_loop,
     selective_step,
 )
 
@@ -124,6 +129,166 @@ def test_no_underflow_over_a_long_stretch():
     # the fixed point of h = f h + 0.3 with f = exp(0.3 a)
     f = np.exp(0.3 * np.asarray(a))
     np.testing.assert_allclose(h[0], 0.3 / (1 - f), rtol=1e-5)
+
+
+# ------------------------------------------------------- the kernel
+# (T, D, N) the kernel tiles, with the blocks (positions, channels) it
+# is given here: several stretches and several channel blocks a row.
+KERNEL_SHAPES = [
+    pytest.param((64, 256, 8), (16, 128), id="4x2-blocks"),
+    pytest.param((32, 384, 16), (8, 128), id="4x3-blocks-N16"),
+    pytest.param((128, 128, 8), (128, 128), id="one-block"),
+    pytest.param((48, 512, 8), (48, 256), id="one-stretch-2-tiles-a-block"),
+]
+
+
+def kernel_inputs(t, d, n, rows=B, seed=11):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    return {
+        "x": jax.random.normal(ks[0], (rows, t, d)),
+        "delta": jax.nn.softplus(jax.random.normal(ks[1], (rows, t, d)) - 1.0),
+        "a": -jnp.exp(jax.random.normal(ks[2], (n, d))),
+        "b": jax.random.normal(ks[3], (rows, t, n)),
+        "c": jax.random.normal(ks[4], (rows, t, n)),
+        "h0": jax.random.normal(ks[5], (rows, n, d)),
+    }
+
+
+def both(i, monkeypatch, blocks, h0=None, valid=None):
+    """((y, h) of the loop, (y, h) of the kernel) on the same inputs."""
+    monkeypatch.setattr(ssm_scan, "BLOCK_T", blocks[0])
+    monkeypatch.setattr(ssm_scan, "BLOCK_D", blocks[1])
+    args = (i["x"], i["delta"], i["a"], i["b"], i["c"],
+            i["h0"] if h0 is None else h0, valid)
+    return selective_scan_loop(*args), selective_scan_kernel(*args)
+
+
+def assert_equal_float32(got, want):
+    # the same arithmetic per element; y sums its N terms in another order
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        scale = float(jnp.max(jnp.abs(w.astype(jnp.float32))))
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), np.asarray(w, np.float32),
+            rtol=1e-6, atol=1e-6 * scale)
+
+
+@pytest.mark.parametrize("shape, blocks", KERNEL_SHAPES)
+def test_kernel_equals_the_loop_from_a_state_that_is_not_zero(
+        shape, blocks, monkeypatch):
+    want, got = both(kernel_inputs(*shape), monkeypatch, blocks)
+    assert got[0].dtype == got[1].dtype == jnp.float32
+    assert_equal_float32(got, want)
+
+
+@pytest.mark.parametrize("cuts", [(32,), (8, 24, 56)])
+def test_kernel_chunk_by_chunk_with_carried_state_equals_one_pass(
+        cuts, monkeypatch):
+    t = 64
+    i = kernel_inputs(t, 256, 8)
+    (want_y, want_h), _ = both(i, monkeypatch, (8, 128))
+    h, ys, at = i["h0"], [], 0
+    for cut in (*cuts, t):
+        part = {k: (v[:, at:cut] if k in "x delta b c".split() else v)
+                for k, v in i.items()}
+        y, h = selective_scan_kernel(
+            part["x"], part["delta"], part["a"], part["b"], part["c"], h)
+        ys.append(y)
+        at = cut
+    assert_equal_float32((jnp.concatenate(ys, 1), h), (want_y, want_h))
+
+
+@pytest.mark.parametrize("n_valid", [0, 1, 13, 40])
+def test_kernel_masked_tail_leaves_the_state_as_it_was(n_valid, monkeypatch):
+    t = 40
+    i = kernel_inputs(t, 128, 8)
+    valid = jnp.arange(t)[None] < jnp.asarray([[n_valid], [t]])
+    want, got = both(i, monkeypatch, (8, 128), valid=valid)
+    assert_equal_float32(got, want)
+    if n_valid == 0:
+        # exactly: a factor of 1 and an input of 0 change no bit
+        np.testing.assert_array_equal(
+            np.asarray(got[1][0]), np.asarray(i["h0"][0]))
+
+
+def test_kernel_keeps_the_state_in_the_dtype_it_is_held_in(monkeypatch):
+    i = kernel_inputs(32, 128, 16)
+    want, got = both(i, monkeypatch, (16, 128),
+                     h0=i["h0"].astype(jnp.bfloat16))
+    assert got[1].dtype == jnp.bfloat16 and got[0].dtype == jnp.float32
+    # rounded at every position, as the loop rounds it: the same bits
+    np.testing.assert_array_equal(
+        np.asarray(got[1], np.float32), np.asarray(want[1], np.float32))
+
+
+def test_kernel_does_not_underflow_over_a_long_stretch(monkeypatch):
+    t, d, n = 2048, 128, 8
+    monkeypatch.setattr(ssm_scan, "BLOCK_T", 256)
+    x = jnp.ones((1, t, d))
+    delta = jnp.full((1, t, d), 0.3)
+    a = -jnp.arange(1.0, n + 1)[:, None] * jnp.ones((n, d)) * 4.0
+    bc = jnp.ones((1, t, n))
+    y, h = selective_scan_kernel(x, delta, a, bc, bc, jnp.zeros((1, n, d)))
+    assert np.isfinite(np.asarray(y)).all() and np.isfinite(np.asarray(h)).all()
+    f = np.exp(0.3 * np.asarray(a))
+    np.testing.assert_allclose(h[0], 0.3 / (1 - f), rtol=1e-5)
+
+
+def test_kernel_refuses_widths_that_do_not_tile():
+    i = kernel_inputs(37, 24, 4)
+    with pytest.raises(ValueError, match="do not tile"):
+        selective_scan_kernel(i["x"], i["delta"], i["a"], i["b"], i["c"],
+                              i["h0"])
+
+
+# ----------------------------------------------------- the selector
+def traced(fn, i):
+    # (a fresh function each time: a trace is cached by function and
+    # shapes, and the selector's answer is not part of that key)
+    return str(jax.make_jaxpr(lambda *args: fn(*args))(
+        i["x"], i["delta"], i["a"], i["b"], i["c"], i["h0"]))
+
+
+def test_off_a_tpu_the_scan_is_the_loop_bit_for_bit(inputs):
+    big = kernel_inputs(512, 256, 16, rows=1)
+    for i in (inputs, big):
+        t, d = i["x"].shape[1:]
+        assert scan_kind(t, d, i["a"].shape[0]) == "loop"
+        graph = traced(selective_scan, i)
+        assert "pallas_call" not in graph
+        assert graph == traced(selective_scan_loop, i)
+    got, want = scan(inputs), selective_scan_loop(
+        *(inputs[k] for k in ("x", "delta", "a", "b", "c", "h0")))
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("t, d, n, want", [
+    pytest.param(512, 5120, 16, "kernel", id="the-cells-chunk"),
+    pytest.param(64, 128, 8, "kernel", id="the-shortest-that-pays"),
+    pytest.param(1, 5120, 16, "loop", id="one-position-the-decode-step"),
+    pytest.param(56, 5120, 16, "loop", id="a-short-stretch"),
+    pytest.param(250, 5120, 16, "loop", id="positions-not-whole-sublanes"),
+    pytest.param(512, 5119, 16, "loop", id="a-prime-width"),
+    pytest.param(512, 5120, 4, "loop", id="a-state-of-half-a-sublane-tile"),
+])
+def test_on_a_tpu_the_selector_picks_from_the_stretch_and_the_widths(
+        t, d, n, want, monkeypatch):
+    monkeypatch.setattr(ssm_scan, "_on_tpu", lambda: True)
+    assert scan_kind(t, d, n) == want
+    if t * d <= 512 * 256:  # trace the small ones: one kernel or none
+        graph = traced(selective_scan, kernel_inputs(t, d, n, rows=1))
+        assert graph.count("pallas_call") == (want == "kernel")
+
+
+def test_on_a_tpu_a_chunk_traces_one_kernel_named_ssm_scan(monkeypatch):
+    monkeypatch.setattr(ssm_scan, "_on_tpu", lambda: True)
+    graph = traced(selective_scan, kernel_inputs(512, 256, 16, rows=1))
+    assert graph.count("pallas_call") == 1 and "ssm_scan" in graph
+    # and no loop of `selective_step` beside it
+    assert f"unroll={ssm_scan.UNROLL}" not in graph
+    assert f"unroll={ssm_scan.UNROLL}" in traced(
+        selective_scan_loop, kernel_inputs(512, 256, 16, rows=1))
 
 
 def conv_whole(u, w, bias):
