@@ -228,9 +228,12 @@ def build_parser() -> argparse.ArgumentParser:
 
 # `model_type` of a --model-config file -> its configuration's builder.
 def _model_families() -> dict:
-    from distributed_model_parallel_tpu.models import jamba
+    from distributed_model_parallel_tpu.models import glm_moe, jamba
 
-    return {jamba.MODEL_TYPE: jamba.config_from_dict}
+    return {
+        jamba.MODEL_TYPE: jamba.config_from_dict,
+        glm_moe.MODEL_TYPE: glm_moe.config_from_dict,
+    }
 
 
 # What --model-config replaces or cannot be combined with, by flag.
@@ -348,9 +351,10 @@ def _checkpoint_guard(directory: str, name: str, cfg) -> None:
         raise SystemExit(
             f"--checkpoint {directory}: the checkpoint records "
             f"lm_family.model_type={family.get('model_type')!r} "
-            "(cli.lm --model-config); the serving engine builds GPT "
-            "decoder blocks over a paged K/V cache and has neither the "
-            "recurrent nor the latent cache that family needs"
+            "(cli.lm --model-config); --checkpoint restores a GPT "
+            "parameter tree, and no loader maps that family's trained "
+            "tree onto a serving family's (cli.serve --model-config "
+            "serves one from --seed)"
         )
     recorded = meta.get("gpt_config")
     if not recorded:
@@ -358,9 +362,11 @@ def _checkpoint_guard(directory: str, name: str, cfg) -> None:
     if int(recorded.get("num_experts", 0)) > 0:
         raise SystemExit(
             f"--checkpoint {directory}: the checkpoint is a "
-            f"Mixture-of-Experts LM (num_experts="
-            f"{recorded['num_experts']}); the serving engine builds "
-            "dense decoder blocks and cannot serve it"
+            f"Mixture-of-Experts GPT (num_experts="
+            f"{recorded['num_experts']}); --checkpoint restores a GPT "
+            "parameter tree into dense GPT decoder blocks, which have "
+            "no expert layer (an expert layer serves through "
+            "--model-config, from --seed)"
         )
     for field, flag in _GPT_CONFIG_FLAGS.items():
         if field not in recorded:
@@ -418,9 +424,11 @@ def _draft_config(args, target_cfg) -> "tuple[GPTConfig, str | None]":
     if int(recorded.get("num_experts", 0)) > 0:
         raise SystemExit(
             f"--speculative-draft {args.speculative_draft}: the draft "
-            f"is a Mixture-of-Experts LM (num_experts="
-            f"{recorded['num_experts']}); the serving engine builds "
-            "dense decoder blocks and cannot serve it"
+            f"is a Mixture-of-Experts GPT (num_experts="
+            f"{recorded['num_experts']}); the draft's loader restores a "
+            "GPT parameter tree into dense GPT decoder blocks, which "
+            "have no expert layer (an expert layer serves through "
+            "--model-config, from --seed)"
         )
     if int(recorded["vocab_size"]) != target_cfg.vocab_size:
         raise SystemExit(
@@ -548,6 +556,17 @@ def main(argv=None) -> dict:
             raise
         # a family's refusals, by the option's name
         raise SystemExit(f"--model-config {args.model_config}: {e}") from e
+    if engine.latent_dim and jax.process_index() == 0:
+        spec = engine.paged_spec
+        print(
+            f"==> {engine.family.name}: the cache holds one latent row "
+            f"of {spec.latent_dim} values a token a layer (stored as "
+            f"{spec.latent_width}) and nothing per head: "
+            f"{spec.num_pages} pages of {spec.page_size} over "
+            f"{spec.num_layers} layers, "
+            f"{spec.num_pages * spec.page_bytes / 1e9:.3f} GB stored",
+            flush=True,
+        )
     if engine.chunk_state_program and jax.process_index() == 0:
         print(
             f"==> {engine.family.name}: a chunk of {engine.prefill_chunk} "

@@ -11,9 +11,12 @@ family's fields.
 `serving_family()` with a `ServingFamily`: the same init, a stem for
 each kind of step, the blocks given an attention function AND a state
 function, the head, and per layer what the layer keeps between steps
-(`LayerCache`: pages of keys and values, or arrays of constant size per
-slot). `models/gpt.GPTConfig` answers with what the engine did before
-the seam; `models/jamba.JambaConfig` is the first with state.
+(`LayerCache`: pages of keys and values, pages of ONE latent row a
+token, or arrays of constant size per slot). `models/gpt.GPTConfig`
+answers with what the engine did before the seam;
+`models/jamba.JambaConfig` is the first with state,
+`models/glm_moe.GlmMoeConfig` the first with latent pages (and with
+expert layers, whose counters the engine sums).
 """
 
 from __future__ import annotations
@@ -67,12 +70,16 @@ class LMFamily:
 class LayerCache:
     """What one layer keeps between two steps of a sequence: pages of
     keys and values (`kv_heads` > 0: that many cached heads of
-    `head_dim`, however many query heads read them), or `state`, arrays
-    of constant size per slot, {name: (shape of one slot's, dtype or
-    None for the activations')}."""
+    `head_dim`, however many query heads read them), pages of latent
+    rows (`latent_dim` > 0: ONE vector of that many values a token, no
+    heads and no separate values: a latent mixer's compressed row and
+    its shared rotary key), or `state`, arrays of constant size per
+    slot, {name: (shape of one slot's, dtype or None for the
+    activations')}."""
 
     kv_heads: int = 0
     head_dim: int = 0
+    latent_dim: int = 0
     state: Dict[str, Tuple[Tuple[int, ...], Any]] = dataclasses.field(
         default_factory=dict
     )
@@ -93,6 +100,12 @@ class ServingFamily:
     # `LayerCache.state` arrays with a leading row axis; both in layer
     # order. The mask says which positions are real (a chunk's tail is
     # not) and a state-holding layer must not advance on the others.
+    # A layer that holds LATENT pages calls `attention_fn(q_nope,
+    # q_rope, c, k_rope, w_kvb, mask, dims)` once instead
+    # (`ops/latent_attention.latent_causal_attention`'s signature:
+    # rotary parts unrotated, since the function alone knows each
+    # token's position, caches the row `[c, rotated k_rope]` and
+    # attends over the rows kept so far).
     blocks: Callable[[Any, Any], List[L.Layer]]
     # (whole params, tokens (slots,), positions (slots,), dtype)
     # -> (slots, 1, dim): every slot's next token at its own position
@@ -119,6 +132,26 @@ class ServingFamily:
     # ("kernel" | "loop"): what the model's own selector answers for
     # the widths of this family. None for a family that keeps none.
     chunk_state_program: Optional[Callable[[int], str]] = None
+    # The decode step's mask says which SLOTS are live (a family whose
+    # layers route rows and count them); False hands every slot's row
+    # as real, as the step did before such a family.
+    masks_inactive: bool = False
+    # (blocks' post-forward state, "decode" | "chunk") -> {counter
+    # name: scalar} the engine accumulates on the device beside the
+    # cache and reports in `paged_stats`; None for a family without.
+    counters: Optional[Callable[[Dict[str, Any], str], Dict[str, Any]]] = None
+    # {counter name: "sum" | "max" | "last"}: how a counter combines
+    # over steps. "last" keeps the step's own value, an int32 array of
+    # one entry a row of the step (`counter_rows[name]` its shape after
+    # the rows): the decode step's under `<name>_decode`, one a slot,
+    # the chunk step's under `<name>_chunk`, one a position; in the
+    # cache tree a step hands back, not in `paged_stats`.
+    counter_reductions: Dict[str, str] = dataclasses.field(
+        default_factory=dict
+    )
+    counter_rows: Dict[str, Tuple[int, ...]] = dataclasses.field(
+        default_factory=dict
+    )
     # Widths the tp decode rings chunk over 'model', {label: n}.
     ring_widths: Dict[str, int] = dataclasses.field(default_factory=dict)
     # What of the engine this family cannot run yet, {option: the

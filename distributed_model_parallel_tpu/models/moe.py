@@ -270,6 +270,17 @@ COUNTERS = {
     "moe_expert_rows_max": "max",  # the fullest held expert's rows
     "moe_picks_dropped": "sum",    # held picks outside their expert's rows
 }
+# What a SERVED expert layer counts instead (a step whose mask says
+# which rows are real; `ServingFamily.counter_reductions`).
+SERVING_COUNTERS = {
+    "moe_picks": "sum",            # real rows' picks routed to an expert
+    "moe_experts_hit": "sum",      # held experts with at least one row
+    "moe_expert_rows_max": "max",  # the fullest held expert's rows
+    "moe_rows_masked": "sum",      # picks of rows that are not real
+    # the experts each row of the LAST step chose, (rows, k): what the
+    # step itself routed by, for whoever holds it to a reference
+    "moe_chosen": "last",
+}
 
 
 def gated_mlp(w, x):
@@ -312,7 +323,13 @@ def held_experts_feed_forward(
     over all of them, computes its own experts' part of the result and
     drops no pick. A pick of an absent expert adds nothing (its weight
     still counts in the renormalisation: the chips that hold it add
-    that part); with every expert held this is the whole layer.
+    that part); with every expert held this is the whole layer. Where
+    the (hidden, mask) pair carries a mask, the rows it calls not real
+    (a served chunk's padded tail, an inactive slot) are sorted behind
+    every held expert's rows like absent picks: they are neither
+    multiplied nor counted, and the state that comes back holds
+    `SERVING_COUNTERS` instead of `COUNTERS`. The training engines
+    pass no mask, and their step is what it was.
 
     No capacity (`held_experts_part`). Params: `router.w`
     (D, num_experts), `experts.w_in` (held, D, 2F) gate then up,
@@ -360,15 +377,28 @@ def held_experts_feed_forward(
             jax.lax.stop_gradient(state["router_bias"]), top_k,
             routed_scale,
         )
+        real = None if mask is None else mask.reshape(b * t)
         routed, sizes, n_held, n_placed = held_experts_part(
-            params["experts"], flat, ids, weights, first
+            params["experts"], flat, ids, weights, first, real
         )
         out = routed + gated_mlp(params["shared"], flat)
-        counters = {
-            "moe_picks_held": n_held,
-            "moe_expert_rows_max": jnp.max(sizes).astype(jnp.float32),
-            "moe_picks_dropped": n_held - n_placed,
-        }
+        rows_max = jnp.max(sizes).astype(jnp.float32)
+        if real is None:
+            counters = {
+                "moe_picks_held": n_held,
+                "moe_expert_rows_max": rows_max,
+                "moe_picks_dropped": n_held - n_placed,
+            }
+        else:
+            counters = {
+                "moe_picks": n_held,
+                "moe_experts_hit": jnp.sum(sizes > 0).astype(jnp.float32),
+                "moe_expert_rows_max": rows_max,
+                "moe_rows_masked": top_k * jnp.sum(~real).astype(
+                    jnp.float32
+                ),
+                "moe_chosen": ids.astype(jnp.int32),
+            }
         return (out.reshape(b, t, d), mask), {
             "router_bias": state["router_bias"], **counters
         }
@@ -376,7 +406,7 @@ def held_experts_feed_forward(
     return L.Layer(init, apply)
 
 
-def held_experts_part(w, flat, ids, weights, first):
+def held_experts_part(w, flat, ids, weights, first, real=None):
     """The part of the routed result that the experts `first ..
     first + held - 1` give, `held = w["w_in"].shape[0]`: flat (N, D),
     ids and weights (N, k) from `route` -> (part (N, D), rows of each
@@ -384,7 +414,9 @@ def held_experts_part(w, flat, ids, weights, first):
     them whose row in the sorted buffer lies among its expert's rows:
     where the sort put it, not what the mask says, so a buffer that ran
     out or a sort that misplaced a pick would show). `first` may be
-    traced (the test that adds up all the shares maps over it).
+    traced (the test that adds up all the shares maps over it). `real`
+    (N,) bool, where given, says which rows exist: the picks of the
+    others count as absent.
 
     Picks are sorted by expert, absent ones behind every held expert's
     rows, into a buffer of N * k rows — the worst case, since a smaller
@@ -398,6 +430,8 @@ def held_experts_part(w, flat, ids, weights, first):
 
     held, top_k = w["w_in"].shape[0], ids.shape[-1]
     is_held = (ids >= first) & (ids < first + held)
+    if real is not None:
+        is_held = is_held & real[:, None]
     group = jnp.where(is_held, ids - first, held).reshape(-1)
     order = jnp.argsort(group, stable=True)
     inverse = jnp.zeros_like(order).at[order].set(

@@ -118,6 +118,19 @@ METRIC_NAMES: Dict[str, str] = {
         "iteration (page-granular allocation scales with live tokens, "
         "not slots*max_len — serving/kv_cache.py)"
     ),
+    "serve_latent_pool_bytes": (
+        "bytes of the latent page pool as the device stores it: "
+        "num_pages x page_size x the row's width in whole lane tiles "
+        "(576 values are stored as 640) x layers, for a family whose "
+        "layers cache ONE latent row a token (serving/kv_cache.py); 0 "
+        "for a family that caches K and V. In "
+        "`Scheduler.paged_stats` as `latent_pool_bytes`, beside the "
+        "expert layers' counters the steps accumulate on the device: "
+        "`moe_picks` (real rows' picks routed), `moe_experts_hit` (sum "
+        "over decode steps and expert layers of the experts with at "
+        "least one row), `moe_expert_rows_max`, `moe_rows_masked` "
+        "(picks of padded or inactive rows kept out)"
+    ),
     "serve_state_pool_bytes": (
         "bytes of the state pool beside the page pool: what the layers "
         "that keep arrays of constant size per slot (a recurrent state, "

@@ -67,6 +67,11 @@ from distributed_model_parallel_tpu.ops.collective_matmul import (
     matmul_rs,
     matmul_rs_quant,
 )
+from distributed_model_parallel_tpu.ops.latent_attention import (
+    latent_attention_blocks,
+    paged_decode_attention,
+    rope,
+)
 from distributed_model_parallel_tpu.ops.quant_matmul import quant_dot
 from distributed_model_parallel_tpu.runtime.compat import shard_map
 
@@ -548,6 +553,148 @@ class PagedVerifyAttention:
         )
 
 
+# ----------------------------------------------- latent attention fns
+#
+# The recorders of a family whose layers keep LATENT pages
+# (`models/lm_family.LayerCache.latent_dim`): one pool a layer,
+# {layer: (num_pages, page, width)}, a token's row `[c, rotated rope
+# key]` and zeros up to whole lane tiles (`serving/kv_cache.py`). A
+# latent mixer calls `attention_fn(q_nope, q_rope, c, k_rope,
+# w_kvb, mask, dims)` with its rotary parts UNROTATED: the recorder
+# rotates them in float32 at the positions it holds, writes the new
+# rows into the pool FIRST and attends over the pool, so a key is
+# rotated once, rounded once, and read the same by the step that wrote
+# it and by every later one. Nothing per head is ever cached. The calls
+# come in layer order; after the blocks run, `.pools` is the updated
+# tree.
+
+
+class _LatentRecorder:
+    def __init__(self, pools: dict, page_size: int):
+        self.pools = dict(pools)
+        self._layers = iter(sorted(pools, key=int))
+        self.page = page_size
+
+    def _rows(self, c, k_rope, pos, dims, pool):
+        """(B, T, rank), (B, T, rope) unrotated, pos (B, T) -> the
+        rows to cache (B, T, the pool's width) in the pool's dtype:
+        `[c, rotated k_rope, zeros]`."""
+        b, t, _ = c.shape
+        return jnp.concatenate([
+            c, rope(k_rope, pos, dims.theta).astype(c.dtype),
+            jnp.zeros((b, t, pool.shape[-1] - dims.row), c.dtype),
+        ], -1).astype(pool.dtype)
+
+
+class PagedLatentDecode(_LatentRecorder):
+    """attention function for one traced PAGED decode step of a latent
+    family: every active slot's new row lands at its own position (one
+    row scattered a slot; an inactive slot's drops out of bounds), then
+    each slot's query attends ABSORBED over the rows its block table
+    reaches, up to its position (`ops/latent_attention.
+    paged_decode_attention`: on a TPU a kernel that walks each slot's
+    pages up to its own length)."""
+
+    def __init__(self, pools, block_table, positions, active,
+                 page_size: int):
+        super().__init__(pools, page_size)
+        self.bt = block_table  # (slots, pages_per_slot) int32
+        self.positions = positions  # (slots,) write/attend position
+        self.active = active  # (slots,) bool
+
+    def __call__(self, q_nope, q_rope, c, k_rope, w_kvb, mask, dims):
+        layer = next(self._layers)
+        pool = self.pools[layer]
+        num_pages = pool.shape[0]
+        pos = self.positions[:, None]
+        rows = self._rows(c, k_rope, pos, dims, pool)[:, 0]
+        dst = jnp.take_along_axis(
+            self.bt, self.positions[:, None] // self.page, axis=1
+        )[:, 0]
+        dst = jnp.where(self.active & (dst >= 0), dst, num_pages)
+        pool = pool.at[dst, self.positions % self.page].set(
+            rows, mode="drop"
+        )
+        self.pools[layer] = pool
+        return paged_decode_attention(
+            q_nope, rope(q_rope, pos, dims.theta).astype(q_nope.dtype),
+            pool, self.bt, self.positions, self.active, w_kvb, dims,
+        )
+
+
+class PagedLatentChunk(_LatentRecorder):
+    """attention function for ONE chunked-prefill step of ONE slot of a
+    latent family: the chunk's first `n_valid` rows land in the slot's
+    pages at [start, start + n_valid) (the padded tail is not written:
+    the pages it would touch are rewritten with what they held), then
+    the chunk's queries attend causally over the slot's rows stretch by
+    stretch, as many stretches as reach start + chunk
+    (`ops/latent_attention.latent_attention_blocks`: the work follows
+    the slot's live length). A stretch is a whole number of pages, at
+    most `KEY_BLOCK` rows, and divides the slot's window."""
+
+    KEY_BLOCK = 1024
+
+    def __init__(self, pools, bt_row, start, n_valid, page_size: int):
+        super().__init__(pools, page_size)
+        self.bt = bt_row  # (pages_per_slot,) int32
+        self.start = start  # int32 global position of chunk token 0
+        self.n_valid = n_valid  # int32 real tokens of the chunk
+        per_slot = bt_row.shape[0]
+        self.per = max(
+            n for n in range(1, per_slot + 1)
+            if per_slot % n == 0
+            and n * page_size <= max(self.KEY_BLOCK, page_size)
+        )
+
+    def _write(self, pool, rows):
+        """pool <- rows (chunk, row) at [start, start + n_valid): the
+        (chunk - 1) // page + 2 pages the chunk can touch are gathered,
+        merged and scattered back whole (unallocated entries and pages
+        past the table drop)."""
+        chunk, page = rows.shape[0], self.page
+        num_pages, per_slot = pool.shape[0], self.bt.shape[0]
+        idx = self.start // page + jnp.arange((chunk - 1) // page + 2)
+        dst = jnp.take(self.bt, jnp.clip(idx, 0, per_slot - 1), axis=0)
+        held = jnp.take(pool, dst, axis=0, mode="clip")
+        t = idx[:, None] * page + jnp.arange(page)[None, :] - self.start
+        new = jnp.take(rows, jnp.clip(t, 0, chunk - 1), axis=0)
+        inside = (t >= 0) & (t < self.n_valid)
+        dst = jnp.where((idx < per_slot) & (dst >= 0), dst, num_pages)
+        return pool.at[dst].set(
+            jnp.where(inside[..., None], new, held), mode="drop"
+        )
+
+    def __call__(self, q_nope, q_rope, c, k_rope, w_kvb, mask, dims):
+        layer = next(self._layers)
+        pool = self.pools[layer]
+        chunk = c.shape[1]
+        pos = (self.start + jnp.arange(chunk))[None]
+        pool = self._write(
+            pool, self._rows(c, k_rope, pos, dims, pool)[0]
+        )
+        self.pools[layer] = pool
+        per, block = self.per, self.per * self.page
+        # The slot's window gathered ONCE, outside the loop (one slot's
+        # rows are a few MB): a loop that reads the pool itself makes
+        # the compiler copy the whole pool into and out of it.
+        view = jnp.take(pool, self.bt, axis=0, mode="clip").reshape(
+            1, -1, pool.shape[-1]
+        )
+
+        def fetch(j):
+            return lax.dynamic_slice_in_dim(view, j * block, block, axis=1)
+
+        n_blocks = jnp.minimum(
+            (self.start + chunk + block - 1) // block,
+            self.bt.shape[0] // per,
+        )
+        return latent_attention_blocks(
+            q_nope, rope(q_rope, pos, dims.theta).astype(q_nope.dtype),
+            fetch, n_blocks, block, pool.shape[-1], pos[0], w_kvb, dims,
+        )
+
+
 # ------------------------------------------------------ state functions
 #
 # The state-pool twins of the attention recorders: a layer that keeps
@@ -746,6 +893,8 @@ __all__ = [
     "DecodeCollectiveMatmul",
     "PagedCacheAttention",
     "PagedChunkAttention",
+    "PagedLatentChunk",
+    "PagedLatentDecode",
     "PagedSeqShardedCacheAttention",
     "PagedVerifyAttention",
     "PrefillRecorder",

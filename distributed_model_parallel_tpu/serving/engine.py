@@ -13,9 +13,12 @@ The engine reaches a model through ONE seam, the `ServingFamily` its
 configuration answers `serving_family()` with (`models/lm_family.py`):
 the parameter init, a stem for each kind of step, the blocks given an
 attention function and a state function, the head, and per layer what
-the layer keeps between steps — pages of keys and values, or arrays of
-constant size per slot (the state pool beside the page pool,
-`serving/kv_cache.py`). It spells no family's fields. What a family
+the layer keeps between steps — pages of keys and values, pages of one
+latent row a token (the latent pool), or arrays of constant size per
+slot (the state pool beside the page pool, `serving/kv_cache.py`) —
+and, where its layers count something a step at a time (an expert
+layer's picks), the counters the engine accumulates on the device
+beside the cache. It spells no family's fields. What a family
 cannot run yet it names (`ServingFamily.missing`), and the engine
 refuses each such option at construction under the option's name.
 
@@ -77,6 +80,8 @@ from distributed_model_parallel_tpu.serving.decode import (
     DecodeCollectiveMatmul,
     PagedCacheAttention,
     PagedChunkAttention,
+    PagedLatentChunk,
+    PagedLatentDecode,
     PagedSeqShardedCacheAttention,
     PagedVerifyAttention,
     PrefillRecorder,
@@ -215,15 +220,19 @@ class ServingEngine:
         # Per layer, what it keeps between steps: pages (the layers
         # that hold them share one pool, so one shape) or state.
         paged_kinds = {
-            (lc.kv_heads, lc.head_dim) for lc in fam.layers if lc.kv_heads
+            (lc.kv_heads, lc.head_dim, lc.latent_dim)
+            for lc in fam.layers if lc.kv_heads or lc.latent_dim
         }
         if len(paged_kinds) != 1:
             raise ValueError(
                 f"the {fam.name} family's layers cache {sorted(paged_kinds)} "
-                "(heads, width): one page pool holds one shape"
+                "(heads, width, latent row): one page pool holds one shape"
             )
-        (kv_heads, head_dim), = paged_kinds
-        paged_layers = sum(1 for lc in fam.layers if lc.kv_heads)
+        (kv_heads, head_dim, latent_dim), = paged_kinds
+        paged_layers = sum(
+            1 for lc in fam.layers if lc.kv_heads or lc.latent_dim
+        )
+        self.latent_dim = latent_dim
         stateful = tuple(
             (i, tuple((name, tuple(shape), dtype or cache_dtype)
                       for name, (shape, dtype) in lc.state.items()))
@@ -275,6 +284,7 @@ class ServingEngine:
                 dtype=cache_dtype,
                 # a unit head axis would be padded to a tile of rows
                 fold_heads=kv_heads == 1 and self.layout == "replicated",
+                latent_dim=latent_dim,
             )
             self.paged_spec.validate(self.layout, self.mesh)
             if self.prefill_chunk is not None:
@@ -425,15 +435,41 @@ class ServingEngine:
         else:
             self._param_sh = self._repl
         self._cache_sh = cache_shardings(mesh, self.layout)
-        self._paged_sh = (
-            paged_shardings(mesh, self.layout)
-            if self.paged_spec is not None else None
-        )
-        if self._paged_sh is not None and self.state_spec is not None:
-            self._paged_sh["state"] = jax.tree_util.tree_map(
-                lambda _: self._repl,
-                jax.eval_shape(partial(init_state_pool, self.state_spec)),
-            )
+        self._paged_sh = None
+        if self.paged_spec is not None:
+            # K and V as the layout shards them; what rides in the
+            # cache tree beside (or instead of) them replicated: the
+            # latent pool, the state pool, the counters
+            kv = paged_shardings(mesh, self.layout)
+            self._paged_sh = {
+                name: kv.get(name) or jax.tree_util.tree_map(
+                    lambda _: self._repl, entry
+                )
+                for name, entry in jax.eval_shape(self._paged_cache).items()
+            }
+
+    def _paged_cache(self) -> dict:
+        """The paged cache tree, zeroed: the pages, the state pool of a
+        family that keeps one, the counters of one that counts."""
+        out = init_paged_cache(self.paged_spec)
+        if self.state_spec is not None:
+            out["state"] = init_state_pool(self.state_spec)
+        if self.family.counters is not None:
+            # (whole numbers: a float32 sum stops being exact at 2**24
+            # picks, minutes into a drain)
+            counters = out["counters"] = {}
+            for name, how in self.family.counter_reductions.items():
+                if how != "last":
+                    counters[name] = jnp.zeros((), jnp.int32)
+                    continue
+                # a step's own array, one entry a row of the step
+                after = self.family.counter_rows[name]
+                for kind, rows in (("decode", self.num_slots),
+                                   ("chunk", self.prefill_chunk or 0)):
+                    counters[f"{name}_{kind}"] = jnp.zeros(
+                        (rows, *after), jnp.int32
+                    )
+        return out
 
     # ----------------------------------------------------------- steps
 
@@ -447,13 +483,17 @@ class ServingEngine:
         mm = self._decode_mm
         ctx = L.Context(train=False, dtype=cdt)
 
-        def run_blocks(params, x, attention_fn, block_ctx,
-                       state_fn=None):
+        def apply_blocks(params, x, attention_fn, block_ctx,
+                         state_fn=None):
+            """-> (hidden, the blocks' post-forward state)."""
             blocks = L.sequential(*fam.blocks(attention_fn, state_fn))
-            (h, _), _ = blocks.apply(
+            (h, _), after = blocks.apply(
                 params["blocks"], blocks_state, x, block_ctx
             )
-            return h
+            return h, after
+
+        def run_blocks(*args):
+            return apply_blocks(*args)[0]
 
         # --- decode: one token for every slot, mixed positions -------
         def decode_step(params, cache, tokens, active):
@@ -586,32 +626,67 @@ class ServingEngine:
         page = paged.page_size if paged else 0
 
         has_state = self.state_spec is not None
+        latent = bool(self.latent_dim)
 
-        def new_cache(rec, states):
-            """The cache tree after a step: the recorders' pages, and
-            the state pool where the family keeps one."""
-            out = {"k": rec.k, "v": rec.v}
+        def new_cache(rec, states, counters=None):
+            """The cache tree after a step: the recorders' pages, the
+            state pool where the family keeps one, its counters where
+            it counts."""
+            out = (
+                {"latent": rec.pools} if latent
+                else {"k": rec.k, "v": rec.v}
+            )
             if states is not None:
                 out["state"] = states.state
+            if counters is not None:
+                out["counters"] = counters
             return out
+
+        def counted_blocks(params, cache, kind, *args):
+            """`run_blocks`, and the cache's counters with this step's
+            (`kind`: "decode" | "chunk") added as the family's
+            reductions say; None for a family that counts nothing."""
+            h, after = apply_blocks(params, *args)
+            if fam.counters is None:
+                return h, None
+            how = {"sum": jnp.add, "max": jnp.maximum}
+            step = fam.counters(after, kind)
+            counters = dict(cache["counters"])
+            for name, reduction in fam.counter_reductions.items():
+                if reduction == "last":  # this kind of step's own
+                    name, told = f"{name}_{kind}", step[name]
+                    counters[name] = told.astype(counters[name].dtype)
+                else:
+                    counters[name] = how[reduction](
+                        counters[name],
+                        step[name].astype(counters[name].dtype),
+                    )
+            return h, counters
 
         def paged_decode_step(params, cache, bt, positions, tokens,
                               active):
-            rec = PagedCacheAttention(
-                cache["k"], cache["v"], bt, positions, active, page
+            rec = (
+                PagedLatentDecode(
+                    cache["latent"], bt, positions, active, page
+                ) if latent else PagedCacheAttention(
+                    cache["k"], cache["v"], bt, positions, active, page
+                )
             )
             states = (
                 SlotStateDecode(cache["state"], active) if has_state
                 else None
             )
             h = fam.decode_stem(params, tokens, positions, cdt)
-            mask = jnp.ones((num_slots, 1), jnp.bool_)
-            h = run_blocks(
-                params, (h, mask), rec,
+            mask = (
+                active[:, None] if fam.masks_inactive
+                else jnp.ones((num_slots, 1), jnp.bool_)
+            )
+            h, counters = counted_blocks(
+                params, cache, "decode", (h, mask), rec,
                 dataclasses.replace(ctx, matmul=mm), states,
             )
             logits = fam.head(params, h)[:, 0, :]
-            return new_cache(rec, states), logits
+            return new_cache(rec, states, counters), logits
 
         def sp_paged_decode_step(params, cache, bt, positions, tokens,
                                  active):
@@ -711,15 +786,21 @@ class ServingEngine:
                 SlotStateChunk(cache["state"], slot, start)
                 if has_state else None
             )
-            rec = PagedChunkAttention(
-                cache["k"], cache["v"], bt_row, start, page
+            rec = (
+                PagedLatentChunk(
+                    cache["latent"], bt_row, start, n_valid, page
+                ) if latent else PagedChunkAttention(
+                    cache["k"], cache["v"], bt_row, start, page
+                )
             )
             h = fam.chunk_stem(params, ids, start, cdt)
             mask = jnp.arange(chunk)[None, :] < n_valid
-            h = run_blocks(params, (h, mask), rec, ctx, states)
+            h, counters = counted_blocks(
+                params, cache, "chunk", (h, mask), rec, ctx, states
+            )
             # the head on the one row the chunk needs
             next_logits = fam.head_row(params, h, n_valid - 1)
-            return new_cache(rec, states), next_logits
+            return new_cache(rec, states, counters), next_logits
 
         # --- speculative verify: all slots' k+1-token spans, one step -
         # The chunk-shaped twin of paged_decode_step: same recorder
@@ -944,9 +1025,7 @@ class ServingEngine:
 
     def init_cache(self) -> dict:
         if self.paged_spec is not None:
-            cache = init_paged_cache(self.paged_spec)
-            if self.state_spec is not None:
-                cache["state"] = init_state_pool(self.state_spec)
+            cache = self._paged_cache()
             if self._paged_sh is None:
                 return cache
             return jax.device_put(cache, self._paged_sh)
@@ -998,8 +1077,14 @@ class ServingEngine:
         of every paged layer's K and V, and the slot's row of the state
         pool."""
         s = self.spec
+        # (a latent pool keeps one row a token, stored in whole lane
+        # tiles, not K and V per head)
+        per_token = (
+            self.paged_spec.latent_width if self.latent_dim
+            else 2 * s.num_heads * s.head_dim
+        )
         return (
-            2 * s.num_layers * s.max_len * s.num_heads * s.head_dim
+            s.num_layers * s.max_len * per_token
             * jnp.dtype(s.dtype).itemsize
         ) + (self.state_spec.slot_bytes if self.state_spec else 0)
 
@@ -1179,7 +1264,12 @@ class ServingEngine:
         state_pool_bytes = (
             self.state_spec.pool_bytes if self.state_spec else 0
         )
+        latent_pool_bytes = (
+            self.paged_spec.num_pages * self.paged_spec.page_bytes
+            if self.latent_dim else 0
+        )
         if mx.enabled:
+            mx.gauge("serve_latent_pool_bytes", latent_pool_bytes)
             mx.gauge("serve_state_pool_bytes", state_pool_bytes)
             mx.gauge("serve_state_scan_kernel", float(kernel_chunk))
         # the chunk step of a family with a state pool also takes the
@@ -1427,11 +1517,19 @@ class ServingEngine:
                 + state_pool_bytes
             ),
             "state_pool_bytes": state_pool_bytes,
+            "latent_pool_bytes": latent_pool_bytes,
             "contiguous_bytes": (
                 self.num_slots * self._slot_stripe_bytes
             ),
             "cow_copies": host.cow_copies,
             **tally,
+            # what the family's layers counted, step by step on the
+            # device (an expert layer's picks): fetched once, here
+            **{
+                name: int(value) for name, value in
+                jax.device_get(cache.get("counters", {})).items()
+                if not value.shape  # (a step's own arrays stay there)
+            },
         }
         if host.prefix is not None:
             total_prompt = sum(

@@ -22,6 +22,21 @@ Two granularities share this module:
   between slots; a write into a shared page copies it first
   (copy-on-write, engine-side).
 
+* **Latent pool** (`PagedKVCacheSpec.latent_dim` > 0): the same pages,
+  block tables, refcounts and prefix cache over a pool whose row is ONE
+  vector a token (a latent-attention mixer's compressed row and its
+  shared rotary key, already rotated): `{"latent": {layer: (num_pages,
+  page_size, latent_width)}}`, one array a layer, no heads, no separate
+  values. The row is the array's minor axis and is STORED padded with
+  zeros to whole lane tiles (`latent_width`: 576 values are 4.5 tiles
+  of 128 and are stored as 640): the TPU keeps an array whose minor
+  axis is no multiple of 128 with another axis on the lanes, and every
+  step then re-lays the whole pool out on its way in and out (two
+  copies of 0.3 GB a layer a step, compiled for a v5e). `page_bytes`
+  and `latent_pool_bytes` count what is STORED, a ninth more than the
+  values. One array a layer, not a stacked one, so that a step scatters
+  its rows into a layer's pool in place and never copies the stack.
+
 * **State pool** (`StatePoolSpec`): beside the pages, for layers that
   keep no keys and values but arrays of CONSTANT size per sequence (a
   state-space layer's recurrent state and its convolution's last
@@ -71,6 +86,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 LAYOUTS = ("replicated", "tp", "sp")
+LANES = 128  # the TPU's minor tile
 
 
 @dataclasses.dataclass(frozen=True)
@@ -227,10 +243,23 @@ class PagedKVCacheSpec:
     # a gathered view to heads either way. Not with the tp layout,
     # which shards the head axis.
     fold_heads: bool = False
+    # > 0: a LATENT pool (module docstring): a page's rows are
+    # (page_size, latent_width), `latent_dim` values and zeros up to
+    # whole lane tiles, one pool a layer and no value pool; `num_heads`
+    # and `head_dim` are not read.
+    latent_dim: int = 0
+
+    @property
+    def latent_width(self) -> int:
+        """Values a latent row is stored as: `latent_dim` rounded up to
+        the TPU's 128 lanes."""
+        return -(-self.latent_dim // LANES) * LANES
 
     @property
     def page_shape(self) -> Tuple[int, ...]:
-        """One page of one layer's keys (or values)."""
+        """One page of one layer's keys (or values, or latent rows)."""
+        if self.latent_dim:
+            return (self.page_size, self.latent_width)
         if self.fold_heads:
             return (self.page_size, self.num_heads * self.head_dim)
         return (self.page_size, self.num_heads, self.head_dim)
@@ -242,7 +271,14 @@ class PagedKVCacheSpec:
 
     @property
     def page_bytes(self) -> int:
-        """K AND V bytes one pool page pins across all layers."""
+        """K AND V bytes (or the latent rows', AS STORED: a row takes
+        `latent_width` values, a ninth more than its 576 at the
+        published widths) one pool page pins across all layers."""
+        if self.latent_dim:
+            return (
+                self.num_layers * self.page_size * self.latent_width
+                * jnp.dtype(self.dtype).itemsize
+            )
         return (
             2 * self.num_layers * self.page_size * self.num_heads
             * self.head_dim * jnp.dtype(self.dtype).itemsize
@@ -270,6 +306,11 @@ class PagedKVCacheSpec:
             )
         if layout == "replicated":
             return
+        if self.latent_dim:
+            raise ValueError(
+                f"a latent pool has no head axis to shard: layout "
+                f"{layout!r} is not built for it"
+            )
         if mesh is None:
             raise ValueError(f"layout {layout!r} needs a mesh")
         if layout == "tp":
@@ -319,6 +360,12 @@ def init_paged_cache(spec: PagedKVCacheSpec) -> dict:
     contiguous cache, `lengths` is NOT device state — the host loop
     owns every slot's position (it owns the block table anyway), so
     positions ride in as a step argument."""
+    if spec.latent_dim:
+        return {"latent": {
+            str(i): jnp.zeros((spec.num_pages, *spec.page_shape),
+                              spec.dtype)
+            for i in range(spec.num_layers)
+        }}
     kv_shape = (spec.num_layers, spec.num_pages, *spec.page_shape)
     return {
         "k": jnp.zeros(kv_shape, spec.dtype),
@@ -568,14 +615,20 @@ class PrefixCache:
 
 def copy_page(cache: dict, src, dst) -> dict:
     """Device-side page copy (the copy-on-write kernel): duplicate pool
-    page `src` into `dst` across every layer of both K and V. The
-    engine jits this once with the cache donated, so a COW costs one
-    tiny in-place scatter, not a pool copy."""
-    return {
+    page `src` into `dst` across every layer of both K and V, or of
+    the latent pool. The engine jits this once with the cache donated,
+    so a COW costs one tiny in-place scatter, not a pool copy."""
+    out = {
         **cache,
         **{name: cache[name].at[:, dst].set(cache[name][:, src])
-           for name in ("k", "v")},
+           for name in ("k", "v") if name in cache},
     }
+    if "latent" in cache:
+        out["latent"] = {
+            layer: pool.at[dst].set(pool[src])
+            for layer, pool in cache["latent"].items()
+        }
+    return out
 
 
 class PagedCacheHost:

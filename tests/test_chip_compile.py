@@ -52,3 +52,33 @@ def test_ssm_scan_compiles_for_a_v5e_at_the_jamba_cells_chunk(
     ).compile().as_text()
     assert text.count("tpu_custom_call") == 1 and "ssm_scan" in text
     assert " while(" not in text
+
+
+def test_latent_decode_attention_compiles_for_a_v5e_at_the_glm_cells_widths(
+        one_chip, monkeypatch):
+    """32 slots of 20 heads over a pool of 6144 pages of 64 rows of
+    640 stored values, a block table of 256 pages a slot:
+    `glm47f_serve_longdoc`'s decode step runs this 7 times. Compiled,
+    not interpreted: one Mosaic call named `paged_attention`, and no
+    gathered view of every slot's whole window beside it."""
+    from distributed_model_parallel_tpu.ops import latent_attention as LA
+
+    monkeypatch.setattr(LA, "_on_tpu", lambda: True)
+    dims = LA.LatentDims(heads=20, rank=512, nope=192, rope=64, dv=256,
+                         theta=1e6, scale=256 ** -0.5)
+    assert LA.decode_kind(640, 64, 256) == "kernel"
+    assert LA.decode_kind(576, 64, 256) == "gather"
+    bf16 = jnp.bfloat16
+    arg = lambda shape, dtype=bf16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    # (the tests' default of `highest` products is not the program's,
+    # and Mosaic refuses the kernel's own product under it)
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(partial(LA.paged_decode_attention, dims=dims)).lower(
+            arg((32, 1, 20, 192)), arg((32, 1, 20, 64)),
+            arg((6144, 64, 640)), arg((32, 256), jnp.int32),
+            arg((32,), jnp.int32), arg((32,), jnp.bool_),
+            arg((512, 20 * 448)),
+        ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and "paged_attention" in text
+    assert "bf16[32,16384,640]" not in text and "[8192,64,640]" not in text
