@@ -25,18 +25,37 @@ for the two right-hand sides that do not depend on S_0 (by the
 inverse of the unit-triangular system, built from matrix products:
 `_unit_lower_inverse`), and the scan over chunks carries S only.
 
-Every exponent taken is a difference G_i - G_j with j <= i, or G_i
-itself: never positive, so nothing overflows however strong the decay
-(`exp(-G_j)`, which the factorised form of A needs, overflows float32
-after a handful of tokens at g = -20). The price is that A and B are
-reduced from a (C, C, dk) tensor on the vector unit and not a matrix
-product; a later kernel can split the chunk into sub-blocks whose
-off-diagonal pairs factorise safely. Decay, state and the triangular
-solve are float32, and the products with the state run at `highest`
-precision: on the TPU a float32 product at the default precision
-rounds its operands to bfloat16, which for a state that is carried
-over the whole sequence is the lower precision the configuration's
-tolerance is meant to catch.
+A and B are built from sub-blocks of `_SUB` rows. Inside a sub-block
+they are reduced on the vector unit from a (_SUB, _SUB, dk) tensor
+exp(G_i - G_j), j <= i, the differences taken from a cumulative sum
+that restarts at the sub-block (the more exact: under strong decay
+|G| passes 1,000 within a chunk). Every pair of sub-blocks below the
+diagonal is a matrix product through a reference token R that lies
+between them, last token of the column block <= R < first token of
+the row block:
+
+    G_i - G_j = (G_i - G_R) + (G_R - G_j)
+    A_ij = (k_i * exp(G_i - G_R)) . (k_j * exp(G_R - G_j))
+
+and the same with q_i for B. G only falls along the chunk and
+j <= R < i, so both brackets are <= 0: each factor is a row scaled
+into [0, 1]. The pairs are taken as `_unit_lower_inverse` takes its
+merges: neighbouring sub-blocks through the last token of the left
+one, then neighbouring pairs of them likewise, so R is always the
+token before the row block, both brackets are sums of g inside one
+block of the pair, and a chunk of 64 takes three products for A and
+three for B. No exponent taken anywhere is positive, so nothing
+overflows however strong the decay (`exp(-G_j)`, which the one-product
+form of A needs, overflows float32 after a handful of tokens at
+g = -20); a factor that underflows to 0 does so no sooner than
+exp(G_i - G_j) itself, which is no larger than either factor: it is
+the true value's own underflow.
+
+Decay, state and the triangular solve are float32, and every product
+runs at `highest` precision: on the TPU a float32 product at the
+default precision rounds its operands to bfloat16, which for a state
+that is carried over the whole sequence is the lower precision the
+configuration's tolerance is meant to catch.
 
 Differentiated by autodiff; the scan's body is rematerialised, so the
 backward pass stores one state per chunk and recomputes the rest.
@@ -49,6 +68,10 @@ import jax.numpy as jnp
 from jax import lax
 
 DEFAULT_CHUNK = 64
+# Rows of a sub-block of the chunk: A and B are reduced from a decay tensor
+# inside one and are matrix products between two. 16 on the v5e: 8 and 32
+# are no faster there (PERF.md, PR 37).
+_SUB = 16
 _HIGHEST = lax.Precision.HIGHEST
 
 
@@ -96,23 +119,90 @@ def _unit_lower_inverse(n):
     return inv[..., 0, :, :]
 
 
+def _chunk_products(q, k, g):
+    """A (strictly lower) and B (lower) of one chunk, both (..., C, C),
+    from float32 q, k and log decay g (..., C, dk): the module
+    docstring's sub-block form."""
+    c, dk = k.shape[-2:]
+    sub = min(_SUB, c)
+    # Sums of g that restart at every sub-block: `upto` from the block's
+    # start to t, with t (G_t - G_(start - 1)); `after` what follows t
+    # in its block (G_end - G_t).
+    gb = g.reshape(g.shape[:-2] + (c // sub, sub, dk))
+    later = jnp.pad(
+        gb[..., 1:, :], [(0, 0)] * (gb.ndim - 2) + [(0, 1), (0, 0)])
+    upto = jnp.cumsum(gb, axis=-2).reshape(g.shape)
+    after = lax.cumsum(later, axis=gb.ndim - 2, reverse=True).reshape(g.shape)
+    lower = jnp.tril(jnp.ones((sub, sub), jnp.bool_))    # j <= i
+
+    def place(block, row, col):
+        """`block` at (row, col) of a (C, C) of zeros."""
+        rows, cols = block.shape[-2:]
+        return jnp.pad(block, [(0, 0)] * (block.ndim - 2) + [
+            (row, c - row - rows), (col, c - col - cols)])
+
+    a = b = 0.0
+    for lo in range(0, c, sub):
+        rows = slice(lo, lo + sub)
+        gi = upto[..., rows, :]
+        diff = gi[..., :, None, :] - gi[..., None, :, :]   # G_i - G_j
+        decay = jnp.exp(jnp.where(lower[..., None], diff, -jnp.inf))
+        kd = k[..., None, rows, :] * decay                 # k_j e^(G_i-G_j)
+        a += place(
+            jnp.tril(jnp.sum(k[..., rows, None, :] * kd, axis=-1), -1),
+            lo, lo)
+        b += place(jnp.sum(q[..., rows, None, :] * kd, axis=-1), lo, lo)
+    # Under the diagonal: pairs of neighbouring blocks of n tokens, the
+    # right one's rows against the left one's columns through R, the
+    # left one's last token; then the sums restart every 2 n tokens.
+    n = sub
+    while n < c:
+        if n > sub:
+            upto, after = _restart_every(n, upto, after)
+        for lo in range(0, c, 2 * n):
+            left, right = slice(lo, lo + n), slice(lo + n, lo + 2 * n)
+            scale = jnp.exp(upto[..., right, :])           # e^(G_i - G_R)
+            cols = k[..., left, :] * jnp.exp(after[..., left, :])
+            under = lambda x: jnp.einsum(
+                "...ic,...jc->...ij", x[..., right, :] * scale, cols,
+                precision=_HIGHEST,
+            )
+            a += place(under(k), lo + n, lo)
+            b += place(under(q), lo + n, lo)
+        n *= 2
+    return a, b
+
+
+def _restart_every(n, upto, after):
+    """Sums (..., C, dk) that restart every n / 2 tokens -> every n: in
+    each pair of halves the right one's `upto` takes the left one's
+    total, and the left one's `after` the right one's."""
+    half = n // 2
+    uptos, afters = [], []
+    for lo in range(0, upto.shape[-2], n):
+        left, right = slice(lo, lo + half), slice(lo + half, lo + n)
+        uptos += [upto[..., left, :],
+                  upto[..., right, :] + upto[..., lo + half - 1:lo + half, :]]
+        afters += [after[..., left, :] + upto[..., lo + n - 1:lo + n, :],
+                   after[..., right, :]]
+    return (jnp.concatenate(uptos, axis=-2),
+            jnp.concatenate(afters, axis=-2))
+
+
 def _chunk_step(s0, chunk):
     """One chunk for every (batch, head): float32 state (B, H, dk, dv)
     -> (new state, outputs (B, H, C, dv))."""
     q, k, v, g, beta = (x.astype(jnp.float32) for x in chunk)
-    c, dv = q.shape[-2], v.shape[-1]
+    dv = v.shape[-1]
     gc = jnp.cumsum(g, axis=-2)                          # G_i, <= 0
-    diff = gc[..., :, None, :] - gc[..., None, :, :]     # G_i - G_j
-    lower = jnp.tril(jnp.ones((c, c), jnp.bool_))        # j <= i
-    decay = jnp.exp(jnp.where(lower[..., None], diff, -jnp.inf))
-    kd = k[..., None, :, :] * decay                      # k_j e^(G_i-G_j)
-    a = jnp.tril(jnp.sum(k[..., :, None, :] * kd, axis=-1), -1)
-    b = jnp.sum(q[..., :, None, :] * kd, axis=-1)
     eg = jnp.exp(gc)
-    rhs = beta[..., None] * jnp.concatenate([v, k * eg], axis=-1)
-    solved = jnp.matmul(
-        _unit_lower_inverse(beta[..., None] * a), rhs, precision=_HIGHEST
-    )
+    with jax.named_scope("kda_chunk"):
+        a, b = _chunk_products(q, k, g)
+        rhs = beta[..., None] * jnp.concatenate([v, k * eg], axis=-1)
+        solved = jnp.matmul(
+            _unit_lower_inverse(beta[..., None] * a), rhs,
+            precision=_HIGHEST,
+        )
     u = solved[..., :dv] - jnp.matmul(
         solved[..., dv:], s0, precision=_HIGHEST
     )

@@ -1,13 +1,20 @@
 """The chunked gated delta rule (`ops/delta_rule.py`) against the
 recurrence one token a step: lengths that are and are not a multiple
 of the chunk, no decay, mild decay, and a decay so strong that any
-`exp(-G)` would overflow float32."""
+`exp(-G)` would overflow float32. And the chunk's A and B, which come
+from sub-blocks and matrix products, against the (C, C, dk) reduction
+they replaced."""
+
+import math
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from distributed_model_parallel_tpu.ops.delta_rule import (
+    _SUB,
+    _chunk_products,
+    _chunk_step,
     gated_delta_rule,
     gated_delta_rule_stepwise,
 )
@@ -25,9 +32,15 @@ def inputs(t, decay, seed=0, b=2, h=3, dk=16, dv=8):
     return q, k, v, g, beta
 
 
-@pytest.mark.parametrize("decay", [0.0, 0.05, 20.0])
-@pytest.mark.parametrize("t,chunk", [(64, 64), (128, 64), (100, 64),
-                                     (37, 16), (5, 16)])
+# (192, 64, 80.0): every factor between two sub-blocks underflows to 0
+CASES = [
+    (t, chunk, decay)
+    for t, chunk in [(64, 64), (128, 64), (100, 64), (37, 16), (5, 16)]
+    for decay in [0.0, 0.05, 20.0]
+] + [(192, 64, 80.0)]
+
+
+@pytest.mark.parametrize("t,chunk,decay", CASES)
 def test_chunked_equals_token_by_token(t, chunk, decay):
     args = inputs(t, decay)
     chunked = gated_delta_rule(*args, chunk=chunk)
@@ -37,9 +50,10 @@ def test_chunked_equals_token_by_token(t, chunk, decay):
     assert float(jnp.abs(chunked - stepwise).max()) < 5e-6
 
 
-@pytest.mark.parametrize("decay", [0.0, 0.05, 20.0])
-def test_chunked_gradients_equal_token_by_token(decay):
-    args = inputs(100, decay, seed=1)
+@pytest.mark.parametrize("t,decay", [(100, 0.0), (100, 0.05), (100, 20.0),
+                                     (192, 80.0)])
+def test_chunked_gradients_equal_token_by_token(t, decay):
+    args = inputs(t, decay, seed=1)
     weight = jnp.cos(jnp.arange(8.0))
 
     def grads(fn):
@@ -54,6 +68,76 @@ def test_chunked_gradients_equal_token_by_token(decay):
         # decay the decay's gradient is ~1e-5 and the others ~1
         bound = 1e-5 * max(float(jnp.abs(b).max()), 1e-2)
         assert float(jnp.abs(a - b).max()) < bound, (name, decay)
+
+
+def whole_chunk_products(q, k, g):
+    """A and B as `_chunk_step` reduced them before the sub-blocks:
+    from exp(G_i - G_j) as one (C, C, dk) tensor."""
+    c = q.shape[-2]
+    gc = jnp.cumsum(g, axis=-2)
+    diff = gc[..., :, None, :] - gc[..., None, :, :]
+    lower = jnp.tril(jnp.ones((c, c), jnp.bool_))
+    decay = jnp.exp(jnp.where(lower[..., None], diff, -jnp.inf))
+    kd = k[..., None, :, :] * decay
+    a = jnp.tril(jnp.sum(k[..., :, None, :] * kd, axis=-1), -1)
+    return a, jnp.sum(q[..., :, None, :] * kd, axis=-1)
+
+
+def one_chunk(decay, c=64, dk=128, dv=24, h=2):
+    """(q, k, v, g, beta) of one chunk as the scan hands it over:
+    (1, h, C, ...)."""
+    return tuple(
+        jnp.moveaxis(x, 1, 2)
+        for x in inputs(c, decay, seed=4, b=1, h=h, dk=dk, dv=dv)
+    )
+
+
+@pytest.mark.parametrize("decay", [0.0, 0.05, 2.0, 20.0, "mixed"])
+def test_sub_block_products_equal_the_whole_chunk_reduction(decay):
+    """At the benchmark's (C, dk) = (64, 128); "mixed" is a head whose
+    even channels never decay and whose odd ones lose e^-40 a token."""
+    q, k, _, g, _ = one_chunk(1.0 if decay == "mixed" else decay)
+    if decay == "mixed":
+        g = jnp.broadcast_to(
+            jnp.where(jnp.arange(128) % 2 == 0, 0.0, -40.0), g.shape)
+    got, want = _chunk_products(q, k, g), whole_chunk_products(q, k, g)
+    for name, x, y in zip("AB", got, want):
+        assert x.shape == y.shape == (1, 2, 64, 64)
+        assert bool(jnp.isfinite(x).all()), name
+        assert float(jnp.abs(x - y).max()) < 5e-6, name
+    assert float(jnp.abs(jnp.triu(got[0])).max()) == 0.0
+    assert float(jnp.abs(jnp.triu(got[1], 1)).max()) == 0.0
+
+
+def equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from equations(sub)
+
+
+def test_no_chunk_wide_decay_tensor_and_products_below_the_diagonal():
+    """One chunk step at (C, dk, dv) = (64, 128, 24) for one (batch,
+    head): nothing it computes is larger than a quarter of C * C * dk,
+    and what matrix products over the channels give (other than those
+    with the state, which carry dv) covers every entry of A and of B
+    below the diagonal sub-blocks, however the pairs are arranged."""
+    c, dk, dv = 64, 128, 24
+    chunk = one_chunk(0.05, c, dk, dv, h=1)
+    jaxpr = jax.make_jaxpr(_chunk_step)(jnp.zeros((1, 1, dk, dv)), chunk)
+    largest, below = 0, 0
+    for eqn in equations(jaxpr.jaxpr):
+        for var in eqn.outvars:
+            largest = max(largest, math.prod(var.aval.shape))
+        if eqn.primitive.name != "dot_general":
+            continue
+        (lhs_c, _), _ = eqn.params["dimension_numbers"]
+        lhs, rhs = (v.aval.shape for v in eqn.invars)
+        if [lhs[i] for i in lhs_c] == [dk] and dv not in lhs + rhs:
+            below += math.prod(eqn.outvars[0].aval.shape)
+    assert _SUB * _SUB * dk <= largest <= c * c * dk // 4
+    # half of what lies off the c / _SUB diagonal sub-blocks, for A and B
+    assert below == c * c - c * _SUB
 
 
 def test_strong_decay_forgets_the_past():
