@@ -189,7 +189,10 @@ TRACE_EVENT_NAMES: Dict[str, str] = {
     # Inside one pass of the paged loop (serving/engine.py _run_paged).
     "engine_iter": (
         "serving: one pass of the paged loop (admission, one chunk per "
-        "ingesting slot, one decode step): a decoding user's token gap"
+        "ingesting slot, one decode step): a decoding user's token gap. "
+        "`index` numbers it: the scheduler's `passes[index]` is the same "
+        "interval, and the prefill / prefill_chunk / decode_step spans "
+        "inside carry `iter=index`"
     ),
     "admit": "serving: the admission loop (can_hold, reserve, prefix)",
     "cow": "serving: copy-on-write pass before a decode step",
@@ -205,7 +208,6 @@ TRACE_EVENT_NAMES: Dict[str, str] = {
     "sample": "serving: host sampling of the fetched logits + eviction",
     "queued": "serving request leg: submit -> admission",
     "decode": "serving request leg: first token -> eviction",
-    "batch_occupancy": "serving counter: active slots per decode step",
     "draft_round": (
         "serving: one speculative proposal round (k draft decode "
         "steps over the active set, serving/speculative.py)"

@@ -49,9 +49,12 @@ approximation.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import gc
+import time
 from functools import partial
-from typing import Any, Optional, Sequence
+from typing import Any, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -108,9 +111,35 @@ from distributed_model_parallel_tpu.serving.sampling import (
     SlotSampler,
 )
 from distributed_model_parallel_tpu.serving.scheduler import (
+    PassRow,
     Request,
     Scheduler,
 )
+
+
+@contextlib.contextmanager
+def _collector_pauses():
+    """The seconds of every garbage collection that runs inside the
+    block, as a list that fills while it runs: a collection holds the
+    interpreter, so it stalls the loop whichever thread set it off. The
+    hook costs nothing while the collector rests and is gone when the
+    block ends, however it ends. Pauses are durations, read on
+    `time.perf_counter` (the tracer's clock unless a test injected
+    another, which the collector must not tick)."""
+    pauses: List[float] = []
+    began = []
+
+    def hook(phase, info):
+        if phase == "start":
+            began.append(time.perf_counter())
+        elif began:  # (not a collection under way as the hook went in)
+            pauses.append(time.perf_counter() - began.pop())
+
+    gc.callbacks.append(hook)
+    try:
+        yield pauses
+    finally:
+        gc.callbacks.remove(hook)
 
 
 @jax.jit
@@ -1124,7 +1153,10 @@ class ServingEngine:
                 "speculative_k > 0 on the target engine as well"
             )
         if self.paged_spec is not None:
-            return self._run_paged(params, requests, sampler)
+            with _collector_pauses() as gc_pauses:
+                return self._run_paged(
+                    params, requests, sampler, gc_pauses
+                )
         return self._run_contiguous(params, requests, sampler)
 
     def _run_contiguous(self, params, requests: Sequence[Request],
@@ -1157,7 +1189,7 @@ class ServingEngine:
                         params, cache, ids, length, jnp.int32(seq.slot)
                     )
                     tok = self._pick(sampler, next_logits, seq.slot)
-                seq.t_first_token = tracer.now()
+                sched.emit(seq, tok, tracer.now())
                 # A monolithic prefill is one engine iteration in which
                 # exactly ONE slot did useful work — the admission
                 # stall the chunked path removes, made visible in the
@@ -1172,7 +1204,6 @@ class ServingEngine:
                     # so the counter totals to the report's
                     # generated_tokens exactly.
                     mx.inc("serve_tokens_total", 1)
-                seq.generated.append(tok)
                 tokens[seq.slot] = tok
                 active[seq.slot] = True
                 if seq.done(self.max_len):
@@ -1189,16 +1220,15 @@ class ServingEngine:
                     jnp.asarray(active),
                 )
                 logits_np = np.asarray(logits)
-            dt = tracer.now() - t0
+            t1 = tracer.now()
+            dt = t1 - t0
             sched.record_decode_step(n_active)
             sched.record_iteration(n_active)
-            tracer.counter("batch_occupancy", n_active)
             if mx.enabled:
                 mx.observe("serve_decode_step_s", dt)
             for slot, seq in list(sched.active.items()):
                 tok = self._pick(sampler, logits_np[slot], slot)
-                seq.generated.append(tok)
-                seq.token_times.append(dt)
+                sched.emit(seq, tok, t1, dt)
                 tokens[slot] = tok
                 if seq.done(self.max_len):
                     sched.finish(slot)
@@ -1208,14 +1238,18 @@ class ServingEngine:
     # ----------------------------------------------------- paged loop
 
     def _run_paged(self, params, requests: Sequence[Request],
-                   sampler: Optional[SlotSampler]) -> Scheduler:
+                   sampler: Optional[SlotSampler],
+                   gc_pauses: List[float]) -> Scheduler:
         """Continuous batching over the PAGE POOL: page-granular
         admission, optional chunked prefill (one `prefill_chunk`-token
         ingest per ingesting slot per engine iteration, SHARING the
         iteration with the in-flight decode step — a long prompt never
         stalls the batch), optional prefix caching (a cached prompt
         skips its prefill; its last partial page copies on the first
-        divergent write)."""
+        divergent write). Every pass leaves one `PassRow` and every
+        token its stamp with the scheduler, whether or not the tracer
+        is on; `paged_stats["timeline"]` is their reduction, with the
+        collector's pauses `run` gathered in `gc_pauses`."""
         tracer = get_tracer()
         mx = get_metrics()
         host = self.new_host()
@@ -1292,12 +1326,18 @@ class ServingEngine:
             active[slot] = False
             host.release(slot)
 
+        # Passes tile the loop: one begins at the reading the last
+        # ended with, so their walls add up to the loop's.
+        t_iter = tracer.now()
         while sched.has_work() or ingest:
             useful = 0
             # The pass as a decoding user feels it: `engine_iter` with
             # the queue's state at its start; every stretch inside has
-            # a child span (observability/metrics.py TRACE_EVENT_NAMES).
-            t_iter = tracer.now()
+            # a child span (observability/metrics.py TRACE_EVENT_NAMES)
+            # that carries the pass's number, and its seconds go into
+            # the pass's row.
+            n_pass = len(sched.passes)
+            chunks, prefill_s, decode_s = 0, 0.0, 0.0
             n_waiting, n_ingesting = len(sched.waiting), len(ingest)
             n_decoding = len(sched.active) - n_ingesting
             # ---- admission: free slots AND page headroom -----------
@@ -1337,22 +1377,21 @@ class ServingEngine:
                         t0 = tracer.now()
                         with tracer.span(
                             "prefill", rid=repr(seq.request.rid),
-                            slot=seq.slot,
+                            slot=seq.slot, iter=n_pass,
                         ):
                             cache, nl = self.prefill(
                                 params, cache,
                                 host.device_row(seq.slot), ids, length,
                             )
                             tok = self._pick(sampler, nl, seq.slot)
-                        seq.t_first_token = tracer.now()
+                        t1 = tracer.now()
+                        chunks += 1
+                        prefill_s += t1 - t0
+                        sched.emit(seq, tok, t1)
                         sched.record_iteration(1)
                         if mx.enabled:
-                            mx.observe(
-                                "serve_prefill_s",
-                                seq.t_first_token - t0,
-                            )
+                            mx.observe("serve_prefill_s", t1 - t0)
                             mx.inc("serve_tokens_total", 1)
-                        seq.generated.append(tok)
                         tokens[seq.slot] = tok
                         positions[seq.slot] = prompt.size
                         active[seq.slot] = True
@@ -1391,7 +1430,7 @@ class ServingEngine:
                 t0 = tracer.now()
                 with tracer.span(
                     "prefill_chunk", rid=repr(seq.request.rid),
-                    slot=slot, start=start,
+                    slot=slot, start=start, iter=n_pass,
                 ):
                     with tracer.span("dispatch"):
                         # (host values as they are: the step's call
@@ -1414,14 +1453,16 @@ class ServingEngine:
                             row = np.asarray(to_fetch(nl))
                         with tracer.span("sample"):
                             tok = pick(row, slot)
-                dt = tracer.now() - t0
+                t1 = tracer.now()
+                dt = t1 - t0
+                chunks += 1
+                prefill_s += dt
                 useful += 1
                 if done_ingest:
-                    seq.t_first_token = tracer.now()
+                    sched.emit(seq, tok, t1)
                     if mx.enabled:
                         mx.observe("serve_prefill_s", acc + dt)
                         mx.inc("serve_tokens_total", 1)
-                    seq.generated.append(tok)
                     tokens[slot] = tok
                     positions[slot] = prompt.size
                     active[slot] = True
@@ -1441,7 +1482,9 @@ class ServingEngine:
                             cache, int(slot), int(positions[slot])
                         )
                 t0 = tracer.now()
-                with tracer.span("decode_step", active=n_active):
+                with tracer.span(
+                    "decode_step", active=n_active, iter=n_pass
+                ):
                     with tracer.span("dispatch"):
                         cache, logits = self.decode_step(
                             params, cache, host.device_table(),
@@ -1452,7 +1495,8 @@ class ServingEngine:
                             jax.block_until_ready(logits)
                     with tracer.span("logits_fetch"):
                         rows = np.asarray(to_fetch(logits))
-                dt = tracer.now() - t0
+                t1 = tracer.now()
+                decode_s = dt = t1 - t0
                 sched.record_decode_step(n_active)
                 # Where this step's other slot-steps went: every slot
                 # is decoding (step_occupancy), ingesting, or free, and
@@ -1466,7 +1510,6 @@ class ServingEngine:
                 else:
                     # freed after this pass's admission had run
                     tally["slot_steps_free_other"] += free
-                tracer.counter("batch_occupancy", n_active)
                 if mx.enabled:
                     mx.observe("serve_decode_step_s", dt)
                 useful += n_active
@@ -1474,16 +1517,12 @@ class ServingEngine:
                     for slot, seq in list(sched.active.items()):
                         if slot in ingest or not active[slot]:
                             continue
+                        # One stamp for the step's tokens: when its
+                        # fetch returned. (A full prefix hit's FIRST
+                        # token arrives here too: its whole "prefill"
+                        # was the cache lookup.)
                         tok = pick(rows[slot], slot)
-                        first = not seq.generated
-                        if first:
-                            # A full prefix hit's first token arrives
-                            # from this decode step — its whole
-                            # "prefill" was the cache lookup.
-                            seq.t_first_token = tracer.now()
-                        else:
-                            seq.token_times.append(dt)
-                        seq.generated.append(tok)
+                        sched.emit(seq, tok, t1, dt)
                         tokens[slot] = tok
                         positions[slot] += 1
                         if seq.done(self.max_len):
@@ -1502,12 +1541,18 @@ class ServingEngine:
                     f"{self.paged_spec.page_size}) — size the pool "
                     "larger (num_pages / --kv-pages)"
                 )
+            t_end = tracer.now()
+            sched.passes.append(PassRow(
+                t_iter, t_end, chunks, n_active, n_waiting,
+                prefill_s, decode_s,
+            ))
             if tracer.enabled:
                 tracer.complete(
-                    "engine_iter", t_iter, tracer.now(),
+                    "engine_iter", t_iter, t_end, index=n_pass,
                     waiting=n_waiting, ingesting=n_ingesting,
                     active=n_decoding,
                 )
+            t_iter = t_end
         sched.paged_stats = {
             "page_size": self.paged_spec.page_size,
             "num_pages": self.paged_spec.num_pages,
@@ -1530,6 +1575,10 @@ class ServingEngine:
                 jax.device_get(cache.get("counters", {})).items()
                 if not value.shape  # (a step's own arrays stay there)
             },
+            # The one entry that holds times (Scheduler.timeline): its
+            # counts repeat from run to run like the rest, its seconds
+            # are this run's alone.
+            "timeline": sched.timeline(gc_pauses),
         }
         if host.prefix is not None:
             total_prompt = sum(

@@ -11,13 +11,21 @@ The scheduler is deliberately host-side and tiny: FIFO admission over
 a `SlotAllocator` free list, per-sequence bookkeeping (generated
 tokens, timing legs for the latency report). Policy experiments
 (priority, preemption) swap this class without touching the engine.
+
+Every token leaves a serving loop through `Scheduler.emit`, which
+stamps it with the time the host had it, on the tracer's clock
+(`Sequence.token_t`); the paged loop leaves one row a pass
+(`Scheduler.passes`). `Scheduler.timeline` reduces both, once, after the
+drain: the gaps between a request's tokens, admission to first token,
+queue wait, and how each pass divided between the device's stretches
+and the host. Recorded in every run, whether or not the tracer is on.
 """
 
 from __future__ import annotations
 
 import dataclasses
 from collections import deque
-from typing import Any, Deque, Dict, List, Optional
+from typing import Any, Deque, Dict, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -52,7 +60,14 @@ class Request:
 @dataclasses.dataclass
 class Sequence:
     """A live (admitted) request: its slot, generated tokens, and the
-    timing legs the latency report is built from."""
+    timing legs the latency report is built from. Two lists of times,
+    both filled by `Scheduler.emit` alone: `token_t[i]` is WHEN the
+    host had token i (a reading of the tracer's clock; tokens that
+    leave one step share it), `token_times` is HOW LONG the step that
+    produced a token took on the host (the `decode_step` stretch,
+    counted once for every slot it served; no entry for the first
+    token): not a time of day, and blind to whatever ran between two
+    of a request's steps."""
 
     request: Request
     slot: int
@@ -60,6 +75,7 @@ class Sequence:
     t_admit: float = 0.0
     t_first_token: float = 0.0
     token_times: List[float] = dataclasses.field(default_factory=list)
+    token_t: List[float] = dataclasses.field(default_factory=list)
     generated: List[int] = dataclasses.field(default_factory=list)
 
     @property
@@ -80,16 +96,47 @@ class Sequence:
 
 @dataclasses.dataclass
 class FinishedSequence:
+    """What an evicted request leaves behind. Seconds throughout;
+    `token_t` counts from the request's own submission, so
+    `token_t[0] == prefill_s` and `token_t[i] - token_t[i - 1]` is the
+    gap its user felt before token i. `decode_s` is the other list
+    (`Sequence.token_times`): the producing step's host duration, one
+    entry a token after the first, which `decode_p50_ms` summarises."""
+
     rid: Any
     prompt_len: int
     tokens: List[int]
     prefill_s: float  # submit -> first token (queueing + prefill)
-    decode_s: List[float]  # per-token decode latencies
-    total_s: float
+    decode_s: List[float]  # the producing step's duration, per token
+    total_s: float  # submit -> eviction
+    queued_s: float = 0.0  # submit -> admission
+    token_t: List[float] = dataclasses.field(default_factory=list)
+    t_submit: float = 0.0  # on the tracer's clock
+
+
+class PassRow(NamedTuple):
+    """One pass of the paged loop, as the loop itself saw it: readings
+    of the tracer's clock, counts it had at hand, and the seconds it
+    spent inside its `prefill_chunk` (or unchunked `prefill`) and
+    `decode_step` stretches. The rest of the pass is the host's."""
+
+    start: float
+    end: float
+    chunks: int    # prefill launches of the pass
+    decoding: int  # slots its decode step served (0: no step)
+    waiting: int   # the queue's length at its start
+    prefill_s: float
+    decode_s: float
 
 
 class Scheduler:
-    """FIFO continuous batching over `num_slots` cache slots."""
+    """FIFO continuous batching over `num_slots` cache slots, and the
+    one place a serving loop's tokens and passes are timed: `emit`
+    takes every token with the stamp its loop already read, `finish`
+    turns the stamps into a request's `token_t`, `passes` takes the
+    paged loop's row a pass, `timeline` reduces them. All of it on
+    `get_tracer().now()`, in every run (the tracer decides what is
+    EXPORTED as spans, not what is recorded here)."""
 
     def __init__(self, num_slots: int, max_len: int, *,
                  bytes_per_slot: int = 0):
@@ -127,6 +174,11 @@ class Scheduler:
         # section of latency_report.
         self.spec_accept_lens: List[int] = []
         self.spec_k: Optional[int] = None
+        # The paged loop's row a pass (pass n is `passes[n]` and,
+        # traced, the span `engine_iter index=n`), and the first
+        # submission's time: the origin `timeline` counts `at_s` from.
+        self.passes: List[PassRow] = []
+        self.t_origin: Optional[float] = None
 
     # ------------------------------------------------------- lifecycle
 
@@ -141,7 +193,10 @@ class Scheduler:
         # identical to time.perf_counter by default, and the only
         # domain the request-lifecycle spans emitted at finish() may
         # mix with — an injected test clock stays coherent end to end.
-        self.waiting.append((get_tracer().now(), request))
+        now = get_tracer().now()
+        if self.t_origin is None:
+            self.t_origin = now
+        self.waiting.append((now, request))
 
     def can_admit(self) -> bool:
         return bool(self.waiting) and self.slots.free_slots > 0
@@ -158,6 +213,21 @@ class Scheduler:
         self.active[slot] = seq
         return seq
 
+    def emit(self, seq: Sequence, token: int, now: float,
+             step_s: Optional[float] = None) -> None:
+        """The one place a token leaves a serving loop. `now` is the
+        reading of the tracer's clock the loop took when the step's
+        fetch returned (tokens of one step, or of one verify round,
+        share it: their user gets them together); `step_s` the
+        producing step's host duration, kept for every token but a
+        request's first (`token_times`)."""
+        if not seq.generated:
+            seq.t_first_token = now
+        elif step_s is not None:
+            seq.token_times.append(step_s)
+        seq.generated.append(int(token))
+        seq.token_t.append(now)
+
     def finish(self, slot: int) -> FinishedSequence:
         """Evict a finished sequence and recycle its slot."""
         seq = self.active.pop(slot)
@@ -170,6 +240,9 @@ class Scheduler:
             prefill_s=seq.t_first_token - seq.t_submit,
             decode_s=list(seq.token_times),
             total_s=now - seq.t_submit,
+            queued_s=seq.t_admit - seq.t_submit,
+            token_t=[t - seq.t_submit for t in seq.token_t],
+            t_submit=seq.t_submit,
         )
         self.finished.append(fin)
         # Request-lifecycle spans, emitted ONCE at eviction when every
@@ -197,7 +270,7 @@ class Scheduler:
         # quantiles summarize, live on the exposition surface.
         mx = get_metrics()
         if mx.enabled:
-            mx.observe("serve_queued_s", seq.t_admit - seq.t_submit)
+            mx.observe("serve_queued_s", fin.queued_s)
             mx.observe("serve_ttft_s", fin.prefill_s)
             for t in fin.decode_s:
                 mx.observe("serve_token_s", t)
@@ -230,8 +303,8 @@ class Scheduler:
     def record_accept_len(self, n_emitted: int) -> None:
         """One slot's emitted-token count for one verify round
         (accepted draft prefix + the correction/bonus token): the
-        acceptance-length histogram obsreport turns into realized
-        speedup."""
+        acceptance-length histogram behind the report's
+        `mean_accept_len`."""
         self.spec_accept_lens.append(int(n_emitted))
         mx = get_metrics()
         if mx.enabled:
@@ -249,10 +322,112 @@ class Scheduler:
 
     # --------------------------------------------------------- reports
 
+    def timeline(self, gc_pauses: Optional[List[float]] = None) -> dict:
+        """The drain from inside, reduced once from what `emit` and
+        `passes` kept (plain numbers; ms rounded to the
+        microsecond). The token half, for every loop: `itl_ms` over
+        EVERY gap between two tokens of one request, all requests
+        pooled, with when (`itl_max_at_s`, from the first submission)
+        and to whom (`itl_max_rid`) the longest happened;
+        `first_token_ms` from admission; `queued_ms`. The pass half,
+        where the loop recorded passes: their walls, the seconds inside
+        the `prefill_chunk` and `decode_step` stretches, the rest as
+        `host_s`, and two lists of five passes with what each held:
+        the longest, and the slowest for what they held (wall over the
+        pass's launches, its chunks and its decode step: a drain opens
+        with every slot ingesting at once, and those few passes are the
+        longest of any run, so a stall in a later, short pass shows in
+        the second list alone). `gc_pauses` are the collector's pauses
+        during the drain."""
+        fins = self.finished
+        origin = self.t_origin or 0.0
+        gaps, spans = [], 0.0
+        worst, worst_at, worst_rid = 0.0, None, None
+        for f in fins:
+            t = np.asarray(f.token_t, np.float64)
+            if t.size < 2:
+                continue
+            g = np.diff(t)
+            gaps.append(g)
+            spans += float(t[-1] - t[0])
+            i = int(g.argmax())
+            if g[i] > worst:
+                worst, worst_rid = float(g[i]), repr(f.rid)
+                worst_at = round(f.t_submit + float(t[i + 1]) - origin, 6)
+        pooled = np.sort(np.concatenate(gaps)).tolist() if gaps else []
+        out = {
+            "tokens": sum(len(f.token_t) for f in fins),
+            "gaps": len(pooled),
+            "itl_ms": {
+                **_pcts(pooled, (50, 90, 99)),
+                "max": _ms(pooled[-1]) if pooled else None,
+                "mean": _ms(sum(pooled) / len(pooled)) if pooled else None,
+            },
+            "itl_max_at_s": worst_at,
+            "itl_max_rid": worst_rid,
+            # Σ over requests of last stamp − first: = mean gap × gaps
+            "decode_span_s": round(spans, 6),
+            "first_token_ms": _pcts(
+                [f.prefill_s - f.queued_s for f in fins], (50, 90, 100)
+            ),
+            "queued_ms": _pcts([f.queued_s for f in fins], (50, 90, 100)),
+        }
+        if self.passes:
+            rows = self.passes
+            walls = [r.end - r.start for r in rows]
+            prefill = sum(r.prefill_s for r in rows)
+            decode = sum(r.decode_s for r in rows)
+            per_launch = [
+                w / max(r.chunks + (r.decoding > 0), 1)
+                for w, r in zip(walls, rows)
+            ]
+
+            def top(by):
+                order = sorted(
+                    range(len(rows)), key=by.__getitem__, reverse=True
+                )
+                return [
+                    {
+                        "index": n,
+                        "at_s": round(rows[n].start - origin, 6),
+                        "wall_ms": _ms(walls[n]),
+                        "per_launch_ms": _ms(per_launch[n]),
+                        "chunks": rows[n].chunks,
+                        "decoding": rows[n].decoding,
+                        "waiting": rows[n].waiting,
+                        "host_ms": _ms(
+                            walls[n] - rows[n].prefill_s - rows[n].decode_s
+                        ),
+                    }
+                    for n in order[:LONGEST_PASSES]
+                ]
+
+            out.update({
+                "passes": len(rows),
+                "pass_ms": _pcts(walls, (50, 90, 99, 100)),
+                "wall_s": round(sum(walls), 6),
+                "stretch_s": {
+                    "prefill_chunk": round(prefill, 6),
+                    "decode_step": round(decode, 6),
+                },
+                "host_s": round(sum(walls) - prefill - decode, 6),
+                "longest_passes": top(walls),
+                "slowest_passes": top(per_launch),
+            })
+        if gc_pauses is not None:
+            out["gc"] = {
+                "collections": len(gc_pauses),
+                "pause_s": round(sum(gc_pauses, 0.0), 6),
+                "pause_max_ms": _ms(max(gc_pauses, default=0.0)),
+            }
+        return out
+
     def latency_report(self) -> dict:
         """Aggregate tokens/sec and per-token p50/p99 over the finished
         set, split by leg (prefill = submit->first token, decode =
-        per-token step latency), plus batch-occupancy telemetry:
+        the producing step's duration once a slot, `token_times`: the
+        gap a user felt between two tokens is `timeline`'s `itl_ms`),
+        plus batch-occupancy telemetry:
         `mean_batch_occupancy` is active slots per decode step and
         `goodput` the useful fraction of slot-steps (each active slot
         yields exactly one token per step, so occupied/total slot-steps
@@ -298,8 +473,13 @@ class Scheduler:
             ),
             "goodput": goodput,
         }
+        # The timeline once, under one key whatever the loop: the paged
+        # loop reduced it after its last eviction (with its passes and
+        # the collector's pauses); the others have the token half.
+        paged = dict(self.paged_stats or {})
+        out["timeline"] = paged.pop("timeline", None) or self.timeline()
         if self.paged_stats is not None:
-            out["paged"] = dict(self.paged_stats)
+            out["paged"] = paged
         if self.prefix_stats is not None:
             out["prefix_cache"] = dict(self.prefix_stats)
         if self.spec_accept_lens:
@@ -321,6 +501,19 @@ class Scheduler:
         return out
 
 
+# How many passes each of `timeline`'s two lists keeps whole.
+LONGEST_PASSES = 5
+
+
+def _ms(seconds: float) -> float:
+    return round(seconds * 1e3, 3)
+
+
+def _pcts(xs, qs) -> dict:
+    """{"p50": ms, ...} of a seconds sample (`_pct`); 100 is "max"."""
+    return {("max" if q == 100 else f"p{q:g}"): _pct(xs, q) for q in qs}
+
+
 def _pct(xs, q: float):
     """Milliseconds quantile of a seconds sample list through the
     repo's ONE percentile rule (`observability/metrics.exact_quantile`
@@ -332,6 +525,7 @@ def _pct(xs, q: float):
 
 __all__ = [
     "FinishedSequence",
+    "PassRow",
     "Request",
     "Scheduler",
     "Sequence",

@@ -270,14 +270,13 @@ def run_speculative(target, params, requests: Sequence[Request],
                         dhost.device_row(seq.slot), ids, length,
                     )
                     tok = target._pick(sampler, nl, seq.slot)
-                seq.t_first_token = tracer.now()
+                sched.emit(seq, tok, tracer.now())
                 sched.record_iteration(1)
                 if mx.enabled:
                     mx.observe(
                         "serve_prefill_s", seq.t_first_token - t0
                     )
                     mx.inc("serve_tokens_total", 1)
-                seq.generated.append(tok)
                 tokens[seq.slot] = tok
                 positions[seq.slot] = prompt.size
                 draft_n[seq.slot] = prompt.size
@@ -311,8 +310,11 @@ def run_speculative(target, params, requests: Sequence[Request],
                         jnp.int32(n),
                     )
                     if t_next + n >= prompt.size:
+                        # The first token, stamped when the target had
+                        # it (the slot decodes once the draft's ingest
+                        # is done too, perhaps a pass later).
                         tok = target._pick(sampler, nl, slot)
-                        seq.generated.append(tok)
+                        sched.emit(seq, tok, tracer.now())
                         tokens[slot] = tok
                         positions[slot] = prompt.size
                         host.register_prefix(slot, prompt)
@@ -344,11 +346,9 @@ def run_speculative(target, params, requests: Sequence[Request],
                     # token at its own position.
                     positions[slot] = prompt.size - 1
                     tokens[slot] = int(prompt[-1])
-                else:
-                    seq.t_first_token = tracer.now()
-                    if mx.enabled:
-                        mx.observe("serve_prefill_s", acc + dt)
-                        mx.inc("serve_tokens_total", 1)
+                elif mx.enabled:
+                    mx.observe("serve_prefill_s", acc + dt)
+                    mx.inc("serve_tokens_total", 1)
                 # The draft holds [0, prompt.size) either way; with a
                 # prefix hit the first proposal step rewrites position
                 # prompt.size-1 with identical content.
@@ -382,9 +382,9 @@ def run_speculative(target, params, requests: Sequence[Request],
                         jnp.asarray(active),
                     )
                     logits_np = np.asarray(logits)
-                dt = tracer.now() - t0
+                t1 = tracer.now()
+                dt = t1 - t0
                 sched.record_decode_step(n_active)
-                tracer.counter("batch_occupancy", n_active)
                 if mx.enabled:
                     mx.observe("serve_decode_step_s", dt)
                 useful += n_active
@@ -392,11 +392,7 @@ def run_speculative(target, params, requests: Sequence[Request],
                     if slot in ingest or not active[slot]:
                         continue
                     tok = target._pick(sampler, logits_np[slot], slot)
-                    if not seq.generated:
-                        seq.t_first_token = tracer.now()
-                    else:
-                        seq.token_times.append(dt)
-                    seq.generated.append(tok)
+                    sched.emit(seq, tok, t1, dt)
                     tokens[slot] = tok
                     positions[slot] += 1
                     # The plain step leaves the draft further behind;
@@ -492,8 +488,8 @@ def run_speculative(target, params, requests: Sequence[Request],
                         jnp.asarray(tokens_chunk), jnp.asarray(active),
                     )
                     vlog = np.asarray(vlogits)
-                dt = tracer.now() - t0
-                tracer.counter("batch_occupancy", n_active)
+                t1 = tracer.now()
+                dt = t1 - t0
                 useful += n_active
                 # 4. Accept/rollback per slot, on the host.
                 total_emitted = 0
@@ -515,11 +511,8 @@ def run_speculative(target, params, requests: Sequence[Request],
                     finished = False
                     per_tok = dt / len(emitted)
                     for tok in emitted:
-                        if not seq.generated:
-                            seq.t_first_token = tracer.now()
-                        else:
-                            seq.token_times.append(per_tok)
-                        seq.generated.append(int(tok))
+                        # a round's tokens reach their user together
+                        sched.emit(seq, tok, t1, per_tok)
                         kept += 1
                         if seq.done(target.max_len):
                             finished = True

@@ -147,15 +147,17 @@ def test_step_children_lie_inside_a_step_and_steps_inside_an_iteration(
         assert all(p[0] == "prefill_chunk" for p in inside(s, steps))
 
 
-def test_iterations_do_not_overlap_and_carry_the_queue_at_their_start(
+def test_iterations_tile_the_loop_and_carry_the_queue_at_their_start(
         traced):
     sched, spans = traced
     iters = named(spans, "engine_iter")
-    assert all(a[2] < b[1] for a, b in zip(iters, iters[1:]))
-    assert all(set(i[3]) == {"waiting", "ingesting", "active"}
+    # a pass begins at the reading the last one ended with (ISSUE 38)
+    assert all(a[2] == b[1] for a, b in zip(iters, iters[1:]))
+    assert all(set(i[3]) == {"index", "waiting", "ingesting", "active"}
                for i in iters)
+    assert [i[3]["index"] for i in iters] == list(range(len(iters)))
     first, last = iters[0][3], iters[-1][3]
-    assert first == {"waiting": 5, "ingesting": 0, "active": 0}
+    assert first == {"index": 0, "waiting": 5, "ingesting": 0, "active": 0}
     assert last["waiting"] == 0 and last["active"] >= 1
     assert any(i[3]["ingesting"] for i in iters)  # the two-chunk prompt
     # a pass that ran a decode step holds exactly one
@@ -177,6 +179,20 @@ def test_children_cover_a_decode_step_but_for_the_clocks_own_ticks(traced):
         # start: one tick; last child end, parent end: one tick
         gaps = [b - a for a, b in zip(edges[::2], edges[1::2])]
         assert gaps == [1] * (len(kids) + 1), (step, kids)
+
+
+def without_times(paged):
+    return {k: v for k, v in paged.items() if k != "timeline"}
+
+
+def timeline_counts(sched):
+    """What of `paged_stats["timeline"]` is a count: the tokens, the
+    gaps, the passes, and every pass's row."""
+    t = sched.paged_stats["timeline"]
+    return (
+        t["tokens"], t["gaps"], t["passes"],
+        [(r.chunks, r.decoding, r.waiting) for r in sched.passes],
+    )
 
 
 def fresh_requests(seed, prompt_len, budgets):
@@ -232,8 +248,11 @@ def test_slot_step_identity_holds_exactly(two_slots, name):
     )
     assert all(paged[k] > 0 for k in positive), paged
     assert all(paged[k] == 0 for k in zero), paged
-    # the report carries the tally with the rest of the page accounting
-    assert sched.latency_report()["paged"] == paged
+    # the report carries the tally with the rest of the page accounting,
+    # and the one entry that holds times under a key of its own
+    report = sched.latency_report()
+    assert report["timeline"] == paged["timeline"]
+    assert report["paged"] == without_times(paged)
 
 
 def test_tracing_off_records_nothing_and_never_waits_twice(
@@ -257,4 +276,11 @@ def test_tracing_off_records_nothing_and_never_waits_twice(
     tokens = {f.rid: f.tokens for f in traced[0].finished}
     assert {f.rid: f.tokens for f in off.finished} == tokens
     assert {f.rid: f.tokens for f in on.finished} == tokens
-    assert off.paged_stats == on.paged_stats == traced[0].paged_stats
+    # every count repeats, traced or not; `timeline` alone holds times,
+    # which are a run's own, and of it the counts repeat too
+    assert without_times(off.paged_stats) == without_times(
+        on.paged_stats
+    ) == without_times(traced[0].paged_stats)
+    assert timeline_counts(off) == timeline_counts(on) == timeline_counts(
+        traced[0]
+    )
