@@ -93,6 +93,7 @@ class GPTConfig:
             blocks=partial(decoder_blocks, self),
             stem=stem,
             head=head_apply,
+            head_operands=head_operands,
             targets=partial(lm_targets, pad_token_id=self.pad_token_id),
             # Per-shard routing under 'seq' sharding breaks the dense
             # capacity semantics and the moe_aux leaves never reach the
@@ -264,10 +265,17 @@ def prefill_stem(stem_params, ids, offset, dtype):
     return h
 
 
+def head_operands(params, h):
+    """(rows, matrix) of the vocabulary product: nothing comes before
+    it (`models/lm_family.LMFamily.head_operands`)."""
+    return h, params["w"]
+
+
 def head_apply(params, h):
     """Untied vocabulary projection; logits in f32. Shared by the dense
     Layer and the sequence-parallel engine."""
-    return h.astype(jnp.float32) @ params["w"]
+    rows, matrix = head_operands(params, h)
+    return rows.astype(jnp.float32) @ matrix
 
 
 def _lm_stem(cfg: GPTConfig) -> L.Layer:

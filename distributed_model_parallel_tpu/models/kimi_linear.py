@@ -107,6 +107,7 @@ class KimiLinearConfig:
                 params, ids, ctx
             ),
             head=partial(head_apply, eps=self.rms_norm_eps),
+            head_operands=partial(head_operands, eps=self.rms_norm_eps),
             targets=_lm_targets,
             counters=step_counters,
             counter_reductions=dict(COUNTERS),
@@ -396,10 +397,17 @@ def stem_apply(params, ids, ctx):
     return h, None
 
 
+def head_operands(params, h, *, eps: float):
+    """(rows, matrix) of the vocabulary product: the final RMSNorm
+    comes before it (`models/lm_family.LMFamily.head_operands`)."""
+    return rms_norm(params["norm"], h, eps), params["w"]
+
+
 def head_apply(params, h, *, eps: float):
     """Final RMSNorm, then the untied vocabulary projection; float32
     logits, as `models/gpt.head_apply`."""
-    return rms_norm(params["norm"], h, eps).astype(jnp.float32) @ params["w"]
+    rows, matrix = head_operands(params, h, eps=eps)
+    return rows.astype(jnp.float32) @ matrix
 
 
 def _stem(cfg: KimiLinearConfig) -> L.Layer:

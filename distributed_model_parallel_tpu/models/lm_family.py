@@ -43,6 +43,12 @@ class LMFamily:
     stem: Callable[[Any, Any, L.Context, Any], Any]
     # (head params, hidden) -> float32 logits
     head: Callable[[Any, Any], Any]
+    # (head params, hidden) -> (rows, matrix): what the vocabulary
+    # product takes, `head` being `rows.astype(float32) @ matrix`: the
+    # hidden rows after whatever the head does first (a final norm,
+    # or nothing) and the (dim, vocab) matrix. A step that wants the
+    # loss and not the logits hands them to `ops/head_loss.head_loss`.
+    head_operands: Callable[[Any, Any], Tuple[Any, Any]]
     # host ids (B, T) -> next-token targets, -1 where nothing is scored
     targets: Callable[[Any], Any]
     # blocks' post-forward state -> {counter name: scalar} the engine

@@ -95,6 +95,7 @@ from distributed_model_parallel_tpu.models import layers as L
 from distributed_model_parallel_tpu.models.staging import (
     stack_block_params,
 )
+from distributed_model_parallel_tpu.ops.head_loss import head_loss
 from distributed_model_parallel_tpu.parallel.data_parallel import (
     TrainState,
     _metrics,
@@ -336,6 +337,7 @@ class ComposedPlanEngine:
             decoder_blocks,
             gpt_lm,
             head_apply as lm_head_apply,
+            head_operands as lm_head_operands,
             lm_targets,
             stem_apply as lm_stem_apply,
         )
@@ -916,7 +918,11 @@ class ComposedPlanEngine:
             mb = bl // M
             h_elems = mb * tl * D
             wire_elems = h_elems + mb * tl  # (h, mask) pair
-            buf_size = max(wire_elems, mb * tl * V)
+            # (the last stage's logits ride the same buffer; one stage
+            # has no wire and makes no logits)
+            buf_size = (
+                wire_elems if S == 1 else max(wire_elems, mb * tl * V)
+            )
             s_idx = lax.axis_index("stage")
             is_first = s_idx == 0
             is_last = s_idx == S - 1
@@ -1002,30 +1008,39 @@ class ComposedPlanEngine:
                 h, mask = scan_blocks(
                     my_blocks, blk_ids, (h, mask), ctx.child(1)
                 )
-                # Head on EVERY device; only the last stage's logits
-                # reach the loss/wire.
-                logits = lm_head_apply(mat["head"], h)
-                y_pad = jnp.where(
-                    is_last, pack_logits(logits), pack_pair(h, mask)
-                )
-                # Mask bubble ticks so garbage never reaches the wire
-                # or the loss.
-                y_pad = jnp.where(valid, y_pad, jnp.zeros_like(y_pad))
                 # Loss counts only on the last stage's valid ticks;
                 # stays LOCAL (no psum before grad).
                 w = (valid & is_last).astype(jnp.float32)
-                m_tick = _local_sums(
-                    logits.astype(jnp.float32), tg_mb
-                )
-                m_acc = {
-                    k: m_acc[k] + m_tick[k] * w for k in m_acc
-                }
-                if S > 1:
+                if S == 1:
+                    # One stage: nothing reads the tick's logits but
+                    # the loss, so the head's product and the loss are
+                    # one op that never holds them whole.
+                    m_tick = head_loss(
+                        *lm_head_operands(mat["head"], h), tg_mb
+                    )
+                else:
+                    # Head on EVERY device; only the last stage's
+                    # logits reach the loss/wire.
+                    logits = lm_head_apply(mat["head"], h)
+                    y_pad = jnp.where(
+                        is_last, pack_logits(logits), pack_pair(h, mask)
+                    )
+                    # Mask bubble ticks so garbage never reaches the
+                    # wire or the loss.
+                    y_pad = jnp.where(
+                        valid, y_pad, jnp.zeros_like(y_pad)
+                    )
+                    m_tick = _local_sums(
+                        logits.astype(jnp.float32), tg_mb
+                    )
                     with jax.named_scope(WIRE_SCOPE):
                         buf = lax.ppermute(
                             y_pad, "stage",
                             [(i, i + 1) for i in range(S - 1)],
                         )
+                m_acc = {
+                    k: m_acc[k] + m_tick[k] * w for k in m_acc
+                }
                 return (buf, m_acc), None
 
             buf0 = jnp.zeros((buf_size,), wire_dt)

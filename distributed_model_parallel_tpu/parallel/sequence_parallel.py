@@ -57,6 +57,7 @@ from distributed_model_parallel_tpu.ops.grad_reduction import (
     bucketed_psum,
     data_replica_index,
 )
+from distributed_model_parallel_tpu.ops.head_loss import head_loss
 from distributed_model_parallel_tpu.ops.wire_codec import require_dcn_axis
 from distributed_model_parallel_tpu.parallel.data_parallel import (
     TrainState,
@@ -445,11 +446,15 @@ class CausalLMSequenceParallelEngine:
         blocks = L.sequential(*block_list)
         cdt = self.compute_dtype
 
-        def forward(params, blocks_state, ids, ctx):
-            """Per-shard forward: local ids (Bl, Tl) -> (local logits,
-            the family's step counters). The SAME stem/head math as the
+        def forward(params, blocks_state, ids, targets, ctx):
+            """Per-shard forward: local ids and targets (Bl, Tl) ->
+            the step's metric SUMS over this shard's tokens (the shared
+            `_metrics` contract on the flattened token axis) with the
+            family's step counters. The SAME stem/head math as the
             dense model, the stem told this shard's index so that what
-            depends on position starts at the shard's global offset.
+            depends on position starts at the shard's global offset;
+            the head's product and the loss are one op
+            (`ops/head_loss.py`), which never holds the logits whole.
             `blocks_state` is the blocks' non-trained buffers
             (`TrainState.model_state["blocks"]`); the state the blocks
             hand back carries the counters and is not kept."""
@@ -460,11 +465,12 @@ class CausalLMSequenceParallelEngine:
                 params["blocks"], blocks_state, (h, mask), ctx.child(1)
             )
             counters = fam.counters(after) if fam.counters else {}
-            return fam.head(params["head"], h), counters
+            rows, matrix = fam.head_operands(params["head"], h)
+            return {**head_loss(rows, matrix, targets), **counters}
 
         def local_sums(logits, targets):
-            """Per-shard metric SUMS over this shard's tokens — the
-            shared `_metrics` contract on the flattened token axis."""
+            """The same sums from logits, for the stagewise backward,
+            whose last segment closes with the head."""
             b, tl, v = logits.shape
             flat_logits = logits.reshape(b * tl, v)
             flat_t = targets.reshape(b * tl)
@@ -573,10 +579,10 @@ class CausalLMSequenceParallelEngine:
                 )
             else:
                 def loss_fn(params):
-                    logits, counters = forward(
-                        params, ts.model_state["blocks"], ids, ctx
+                    m = forward(
+                        params, ts.model_state["blocks"], ids, targets,
+                        ctx,
                     )
-                    m = {**local_sums(logits, targets), **counters}
                     # LOCAL token-loss sum (pipeline discipline: no psum
                     # before grad).
                     return m["loss_sum"], m
@@ -614,11 +620,10 @@ class CausalLMSequenceParallelEngine:
             return new_ts, reduced(m)
 
         def shard_eval(ts: TrainState, ids, targets):
-            logits, counters = forward(
-                ts.params, ts.model_state["blocks"], ids,
+            return reduced(forward(
+                ts.params, ts.model_state["blocks"], ids, targets,
                 L.Context(train=False, dtype=cdt, matmul=mm),
-            )
-            return reduced({**local_sums(logits, targets), **counters})
+            ))
 
         donate = (0,) if self.donate else ()
         self.train_step = jax.jit(

@@ -16,8 +16,10 @@ chip's 16.9 GB, and a step 0.27 GiB too large read "Used 16.01G of
 `--take-gib` adds an argument of that size that is only passed
 through (it costs twice that, as argument and as result): how much room
 the step can give up and still compile says whether its peak is what
-it needs or what the scheduler took. A one-chip cell only; nothing
-runs, so this says nothing about results or times.
+it needs or what the scheduler took. A four-chip cell compiles over the
+described 2x2 host with its state laid out as the plan engine says
+(`gpt2xl_train_fsdp4`, ~100 s; the memory is a chip's). Nothing runs,
+so this says nothing about results or times.
 """
 
 from __future__ import annotations
@@ -46,7 +48,7 @@ def main(argv=None) -> int:
 
     topo = topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2")
-    described = [topo.devices[0]]
+    described = list(topo.devices)
     jax.devices = jax.local_devices = lambda *a, **k: described
     jax.default_backend = lambda: "tpu"
 
@@ -57,8 +59,7 @@ def main(argv=None) -> int:
     )
 
     cell = manifest.resolve_cell(manifest.load_manifest(), args.workload)
-    if cell.chips != 1:
-        raise SystemExit(f"{cell.name} is a {cell.chips}-chip cell")
+    del described[cell.chips:]
     builder = manifest.load_module("builder", cell.config["builder"])
 
     class EngineBuilt(Exception):
@@ -75,20 +76,29 @@ def main(argv=None) -> int:
             engine = built.args[0]
 
     whole = NamedSharding(engine.mesh, PartitionSpec())
-    shaped = lambda shape, dtype: jax.ShapeDtypeStruct(
-        shape, dtype, sharding=whole)
+    shaped = lambda shape, dtype, sharding=whole: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=sharding)
 
     def init(rng):
         params, model_state = engine._full.init(rng)
         return TrainState(params, model_state, engine.optimizer.init(params),
                           jnp.zeros((), jnp.int32))
 
+    state = jax.eval_shape(init, jax.ShapeDtypeStruct((2,), jnp.uint32))
+    if cell.chips == 1:
+        layout = jax.tree_util.tree_map(lambda _: whole, state)
+    else:
+        # a plan engine says how its state lies over the chips
+        layout = jax.tree_util.tree_map(
+            lambda spec: NamedSharding(engine.mesh, spec),
+            engine.state_partition_specs(),
+            is_leaf=lambda x: isinstance(x, PartitionSpec),
+        )
     state = jax.tree_util.tree_map(
-        lambda x: shaped(x.shape, x.dtype),
-        jax.eval_shape(init, jax.ShapeDtypeStruct((2,), jnp.uint32)),
-    )
+        lambda x, sharding: shaped(x.shape, x.dtype, sharding), state, layout)
     training = cell.config["training"]
-    ids = shaped((training["batch_size"], training["seq_len"]), jnp.int32)
+    ids = shaped((training["batch_size"], training["seq_len"]), jnp.int32,
+                 engine._batch)
     lr = shaped((), jnp.float32)
     t0 = time.time()
     if args.take_gib:

@@ -82,3 +82,55 @@ def test_latent_decode_attention_compiles_for_a_v5e_at_the_glm_cells_widths(
         ).compile().as_text()
     assert text.count("tpu_custom_call") == 1 and "paged_attention" in text
     assert "bf16[32,16384,640]" not in text and "[8192,64,640]" not in text
+
+
+@pytest.mark.parametrize(
+    "cell, rows, dim, vocab",
+    [("gpt2s_train", 16384, 768, 50257),
+     ("gpt2xl_train_fsdp4", 2048, 1600, 50257),
+     ("kimilin_train_8k", 16384, 2304, 20480)],
+)
+def test_head_loss_compiles_for_a_v5e_at_the_training_cells_shapes(
+        one_chip, monkeypatch, cell, rows, dim, vocab):
+    """A chip's rows of a step, the head's width and vocabulary of the
+    three training cells: the selector picks the kernels there, forward
+    and backward compile inside their fast-memory limit (1,600 is not
+    whole lanes, 50,257 not whole tiles), every Mosaic call names itself
+    `head_loss`, and the one float32 array of the whole plane is the
+    kept logits: nothing lays it out again or keeps a gradient its
+    size."""
+    from distributed_model_parallel_tpu.ops import head_loss as HL
+
+    monkeypatch.setattr(HL, "_on_tpu", lambda: True)
+    assert HL.head_loss_kind(rows) == "kernel"
+
+    def step(h, w, labels):
+        def loss(h, w):
+            m = HL.head_loss(h, w, labels)
+            return m["loss_sum"], m
+
+        return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(h, w)
+
+    arg = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(step).lower(
+            arg((rows, dim), jnp.bfloat16), arg((dim, vocab), jnp.float32),
+            arg((rows,), jnp.int32),
+        ).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    stretches = -(-rows // HL._stretch_rows(rows, dim))
+    assert len(calls) == 1 + stretches
+    assert all("head_loss" in ln for ln in calls)
+    # the plane is the forward call's result and the backward calls'
+    # operand, and nothing else's
+    tile_v = HL._tiles(dim)[1]
+    plane = f"f32[{rows},{-(-vocab // tile_v) * tile_v}]"
+    touching = [ln for ln in text.splitlines() if plane in ln]
+    assert touching and (
+        vocab % tile_v == 0 or f"f32[{rows},{vocab}]" not in text
+    )
+    assert all(
+        "tpu_custom_call" in ln or " get-tuple-element(" in ln
+        for ln in touching
+    ), touching

@@ -883,3 +883,186 @@ def test_plan_without_fsdp_keeps_its_one_fused_psum():
         len(dims) == 3 and dims[0] == layers
         for dims, _ in _stablehlo_operands(text, "all_reduce")
     )
+
+
+# ----------------------- the vocabulary head and its loss as one op
+# ISSUE 39: the LM engine's step and a one-stage plan's tick hand the
+# head's rows and matrix to `ops/head_loss.head_loss`, which walks the
+# rows x vocabulary plane in pieces. Numerics cannot see the whole
+# float32 logits come back (the sums are the same and the chip pays
+# passes over gigabytes), so the lowered step is pinned: no float32
+# array of a shard's whole (rows, vocabulary), however its leading axes
+# are cut.
+
+_HEAD_VOCAB = 61
+
+
+def _whole_plane_tensors(text, rows, vocab=_HEAD_VOCAB):
+    """Every float32 tensor type in `text` that holds `rows` x `vocab`
+    logits or more: (..., vocab) with the leading axes' product >=
+    rows."""
+    found = set()
+    for dims, el in _MLIR_TENSOR.findall(text):
+        dims = tuple(int(n) for n in dims.split("x") if n)
+        if (el == "f32" and len(dims) >= 2 and dims[-1] == vocab
+                and int(np.prod(dims[:-1])) >= rows):
+            found.add(dims)
+    return found
+
+
+def _old_head_loss(rows, matrix, labels):
+    """The step's sums as both engines made them until PR 39: the whole
+    float32 logits, `cross_entropy` and `_metrics` over them, autodiff
+    behind. Kept here as the reference the op is held to."""
+    from distributed_model_parallel_tpu.parallel.data_parallel import (
+        _metrics,
+    )
+    from distributed_model_parallel_tpu.training.metrics import (
+        cross_entropy,
+    )
+
+    logits = (rows.astype(jnp.float32) @ matrix).reshape(
+        -1, matrix.shape[1]
+    )
+    flat = labels.reshape(-1)
+    return _metrics(cross_entropy(logits, flat), logits, flat)
+
+
+def _head_loss_engine(build):
+    """(engine, sequences a step) at toy widths at which a shard holds
+    64 rows a step, more than one block of the op (the caller shrinks
+    the block) and no other array's leading size."""
+    from distributed_model_parallel_tpu.models.gpt import GPTConfig
+
+    cfg = GPTConfig(vocab_size=_HEAD_VOCAB, dim=32, num_layers=2,
+                    num_heads=4, ffn_dim=80, max_position=16,
+                    dropout_rate=0.0)
+    if build == "sp_lm":
+        from distributed_model_parallel_tpu.parallel.sequence_parallel import (
+            CausalLMSequenceParallelEngine,
+        )
+
+        eng = CausalLMSequenceParallelEngine(
+            cfg, SGD(), make_mesh(MeshSpec(data=2, seq=4)), donate=False
+        )
+        return eng, 32
+    from distributed_model_parallel_tpu.parallel.plan import (
+        build_plan_engine,
+    )
+
+    eng = build_plan_engine(
+        cfg, SGD(), "fsdp4", donate=False, force_composed=True,
+        min_shard_elems=16, devices=jax.devices()[:4],
+    )
+    return eng, 16
+
+
+_SHARD_ROWS = 64
+
+
+def _head_loss_batch(eng, sequences):
+    ids = np.random.RandomState(0).randint(
+        1, _HEAD_VOCAB, size=(sequences, 16)
+    ).astype(np.int32)
+    return eng.shard_batch(ids)
+
+
+def test_whole_plane_pattern_sees_the_old_formulation():
+    text = jax.jit(_old_head_loss).lower(
+        jnp.ones((4, 16, 32)), jnp.ones((32, _HEAD_VOCAB)),
+        jnp.ones((4, 16), jnp.int32),
+    ).as_text()
+    assert _whole_plane_tensors(text, 64)
+    # and a block of fewer rows is not the plane
+    assert not _whole_plane_tensors(
+        "tensor<16x61xf32> tensor<2x16x32xf32> tensor<64x61xi32>", 64
+    )
+
+
+@pytest.mark.parametrize("build", ["sp_lm", "fsdp_plan"])
+def test_lm_train_step_holds_no_whole_float32_logits(monkeypatch, build):
+    from distributed_model_parallel_tpu.ops import head_loss as HL
+
+    # blocks of 16 rows: four a shard
+    monkeypatch.setattr(HL, "BLOCK_ELEMENTS", 16 * _HEAD_VOCAB)
+    eng, sequences = _head_loss_engine(build)
+    ts = eng.init_state(jax.random.PRNGKey(0))
+    ids_s, tg_s = _head_loss_batch(eng, sequences)
+    for step, args in (
+        (eng.train_step, (ts, ids_s, tg_s, jnp.float32(0.1))),
+        (eng.eval_step, (ts, ids_s, tg_s)),
+    ):
+        text = step.lower(*args).as_text()
+        assert not _whole_plane_tensors(text, _SHARD_ROWS)
+        # the blocks are there, and the rank's pass in them
+        assert _whole_plane_tensors(text, 16) and "compare" in text
+
+
+@pytest.mark.parametrize("build", ["sp_lm", "fsdp_plan"])
+def test_lm_three_steps_equal_the_old_formulation(monkeypatch, build):
+    from distributed_model_parallel_tpu.ops import head_loss as HL
+    from distributed_model_parallel_tpu.parallel import (
+        plan, sequence_parallel,
+    )
+
+    monkeypatch.setattr(HL, "BLOCK_ELEMENTS", 16 * _HEAD_VOCAB)
+    module = sequence_parallel if build == "sp_lm" else plan
+
+    def run(patched):
+        with monkeypatch.context() as m:
+            if patched:
+                m.setattr(module, "head_loss", _old_head_loss)
+            eng, sequences = _head_loss_engine(build)
+            ts = eng.init_state(jax.random.PRNGKey(0))
+            ids_s, tg_s = _head_loss_batch(eng, sequences)
+            text = eng.train_step.lower(
+                ts, ids_s, tg_s, jnp.float32(0.1)
+            ).as_text()
+            assert bool(
+                _whole_plane_tensors(text, _SHARD_ROWS)
+            ) == patched
+            metrics = []
+            for _ in range(3):
+                ts, m_step = eng.train_step(
+                    ts, ids_s, tg_s, jnp.float32(0.1)
+                )
+                metrics.append(jax.device_get(m_step))
+            metrics.append(jax.device_get(eng.eval_step(ts, ids_s, tg_s)))
+            return jax.device_get(ts.params), metrics
+
+    params, metrics = run(False)
+    old_params, old_metrics = run(True)
+    for got, want in zip(metrics, old_metrics):
+        assert got["count"] == want["count"] > 0
+        assert got["correct1"] == want["correct1"]
+        assert got["correct5"] == want["correct5"]
+        np.testing.assert_allclose(
+            got["loss_sum"], want["loss_sum"], rtol=1e-5
+        )
+    assert metrics[2]["loss_sum"] < metrics[0]["loss_sum"]
+    for got, want in zip(
+        jax.tree_util.tree_leaves(params),
+        jax.tree_util.tree_leaves(old_params),
+    ):
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+
+def test_two_stage_plan_still_puts_its_logits_on_the_wire(monkeypatch):
+    """With more than one stage the tick's logits are the wire's
+    payload (the last stage packs them into the buffer every stage
+    permutes), so the tick keeps `head_apply` + `_local_sums`: decided
+    by the plan's stage count, nothing else."""
+    from distributed_model_parallel_tpu.ops import head_loss as HL
+
+    monkeypatch.setattr(HL, "BLOCK_ELEMENTS", 16 * _HEAD_VOCAB)
+    eng, found, text = _plan_step("pp2xdp2")
+    # a microbatch's logits, whole, and the wire sized to carry them
+    mb_rows = 4 * 16
+    assert _whole_plane_tensors(text, mb_rows)
+    wire = mb_rows * _PLAN_CFG["vocab_size"]
+    assert f"tensor<{wire}xf32>" in text
+    assert '"stablehlo.collective_permute"' in text
+    # and the one-stage plan of the same widths holds neither
+    _, _, text = _plan_step("dp4")
+    assert not _whole_plane_tensors(text, mb_rows)
+    assert f"tensor<{wire}xf32>" not in text
