@@ -24,6 +24,11 @@ so (I + Diag(b) A) U = Diag(b) (V - (K * exp G) S_0) is solved once
 for the two right-hand sides that do not depend on S_0 (by the
 inverse of the unit-triangular system, built from matrix products:
 `_unit_lower_inverse`), and the scan over chunks carries S only.
+A chunk's work splits in two: what does not read S_0 (G, the decay
+factors, A, B, the inverse and both solutions; `_chunk_terms`) and
+what does (`_state_step`): one product of the state with rows stacked
+from solved K and q * exp G, and one of U with rows stacked from B and
+the decayed keys, in place of four products.
 
 A and B are built from sub-blocks of `_SUB` rows. Inside a sub-block
 they are reduced on the vector unit from a (_SUB, _SUB, dk) tensor
@@ -109,7 +114,10 @@ def _unit_lower_inverse(n):
     inv = mm(mm(eye - d, eye + d2), eye + mm(d2, d2))
     while size < c:
         below = blocks(size, 1, 0)    # under the diagonal of each pair
-        upper, lower = inv[..., 0::2, :, :], inv[..., 1::2, :, :]
+        # pairs by a reshape: strided picks compile to gathers, which
+        # cost kimilin_train_8k its whole gain from the state step
+        pairs = inv.reshape(inv.shape[:-3] + (-1, 2) + inv.shape[-2:])
+        upper, lower = pairs[..., 0, :, :], pairs[..., 1, :, :]
         corner = -mm(mm(lower, below), upper)
         inv = jnp.concatenate([
             jnp.concatenate([upper, jnp.zeros_like(upper)], axis=-1),
@@ -189,9 +197,17 @@ def _restart_every(n, upto, after):
             jnp.concatenate(afters, axis=-2))
 
 
-def _chunk_step(s0, chunk):
-    """One chunk for every (batch, head): float32 state (B, H, dk, dv)
-    -> (new state, outputs (B, H, C, dv))."""
+def _chunk_terms(chunk):
+    """What a chunk (B, H, C, ...) needs from its inputs that does not
+    depend on the state it starts from:
+
+    - `reads` (..., 2C, dk): rows (solved K; q * exp G), which multiply
+      the state;
+    - `solved_v` (..., C, dv);
+    - `writes` (..., C + dk, C): rows (B; (k * exp(G_C - G))^T), which
+      multiply U;
+    - `decay` (..., dk, 1): exp(G_C) for the state's rows.
+    """
     q, k, v, g, beta = (x.astype(jnp.float32) for x in chunk)
     dv = v.shape[-1]
     gc = jnp.cumsum(g, axis=-2)                          # G_i, <= 0
@@ -203,18 +219,31 @@ def _chunk_step(s0, chunk):
             _unit_lower_inverse(beta[..., None] * a), rhs,
             precision=_HIGHEST,
         )
-    u = solved[..., :dv] - jnp.matmul(
-        solved[..., dv:], s0, precision=_HIGHEST
-    )
-    out = jnp.matmul(q * eg, s0, precision=_HIGHEST) + jnp.matmul(
-        b, u, precision=_HIGHEST
-    )
     g_end = gc[..., -1:, :]                              # G_C
-    new = jnp.swapaxes(jnp.exp(g_end), -1, -2) * s0 + jnp.matmul(
-        jnp.swapaxes(k * jnp.exp(g_end - gc), -1, -2), u,
-        precision=_HIGHEST,
-    )
-    return new, out
+    reads = jnp.concatenate([solved[..., dv:], q * eg], axis=-2)
+    writes = jnp.concatenate(
+        [b, jnp.swapaxes(k * jnp.exp(g_end - gc), -1, -2)], axis=-2)
+    decay = jnp.swapaxes(jnp.exp(g_end), -1, -2)
+    return reads, solved[..., :dv], writes, decay
+
+
+def _state_step(s0, terms):
+    """One chunk's work with the state (B, H, dk, dv), given its
+    `_chunk_terms`: u = solved V - solved K S_0, outputs (q e^G) S_0 +
+    B u, new state e^(G_C) S_0 + (k e^(G_C - G))^T u."""
+    reads, solved_v, writes, decay = terms
+    c = solved_v.shape[-2]
+    read = jnp.matmul(reads, s0, precision=_HIGHEST)
+    u = solved_v - read[..., :c, :]
+    written = jnp.matmul(writes, u, precision=_HIGHEST)
+    out = read[..., c:, :] + written[..., :c, :]
+    return decay * s0 + written[..., c:, :], out
+
+
+def _chunk_step(s0, chunk):
+    """One chunk for every (batch, head): float32 state (B, H, dk, dv)
+    -> (new state, outputs (B, H, C, dv))."""
+    return _state_step(s0, _chunk_terms(chunk))
 
 
 def gated_delta_rule(q, k, v, g, beta, *, chunk: int = DEFAULT_CHUNK):

@@ -1,9 +1,10 @@
 """The chunked gated delta rule (`ops/delta_rule.py`) against the
 recurrence one token a step: lengths that are and are not a multiple
 of the chunk, no decay, mild decay, and a decay so strong that any
-`exp(-G)` would overflow float32. And the chunk's A and B, which come
-from sub-blocks and matrix products, against the (C, C, dk) reduction
-they replaced."""
+`exp(-G)` would overflow float32. The chunk step's work with the state,
+two products over stacked rows, against the four products it replaced.
+And the chunk's A and B, which come from sub-blocks and matrix
+products, against the (C, C, dk) reduction they replaced."""
 
 import math
 
@@ -15,6 +16,7 @@ from distributed_model_parallel_tpu.ops.delta_rule import (
     _SUB,
     _chunk_products,
     _chunk_step,
+    _unit_lower_inverse,
     gated_delta_rule,
     gated_delta_rule_stepwise,
 )
@@ -138,6 +140,99 @@ def test_no_chunk_wide_decay_tensor_and_products_below_the_diagonal():
     assert _SUB * _SUB * dk <= largest <= c * c * dk // 4
     # half of what lies off the c / _SUB diagonal sub-blocks, for A and B
     assert below == c * c - c * _SUB
+
+
+def per_chunk_scan(q, k, v, g, beta, *, chunk):
+    """`gated_delta_rule` as it was before its work with the state was
+    stacked: four products with S_0 and U a chunk."""
+    bsz, t, h, dk = q.shape
+    dv = v.shape[-1]
+    pad = -t % chunk
+    n = (t + pad) // chunk
+
+    def step(s0, x):
+        q, k, v, g, beta = (y.astype(jnp.float32) for y in x)
+        gc = jnp.cumsum(g, axis=-2)
+        eg = jnp.exp(gc)
+        a, b = _chunk_products(q, k, g)
+        rhs = beta[..., None] * jnp.concatenate([v, k * eg], axis=-1)
+        solved = jnp.matmul(_unit_lower_inverse(beta[..., None] * a), rhs,
+                            precision="highest")
+        mm = lambda x, y: jnp.matmul(x, y, precision="highest")
+        u = solved[..., :dv] - mm(solved[..., dv:], s0)
+        out = mm(q * eg, s0) + mm(b, u)
+        g_end = gc[..., -1:, :]
+        new = jnp.swapaxes(jnp.exp(g_end), -1, -2) * s0 + mm(
+            jnp.swapaxes(k * jnp.exp(g_end - gc), -1, -2), u)
+        return new, out
+
+    def chunks(x):
+        x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+        x = x.reshape((bsz, n, chunk) + x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 2, 3)
+
+    _, out = jax.lax.scan(
+        jax.checkpoint(step), jnp.zeros((bsz, h, dk, dv)),
+        tuple(chunks(x) for x in (q, k, v, g, beta)))
+    out = jnp.moveaxis(jnp.moveaxis(out, 0, 1), 2, 3)
+    return out.reshape(bsz, n * chunk, h, dv)[:, :t]
+
+
+def repeated_keys(args):
+    """Every token of a sequence writes under one of two keys."""
+    q, k, v, g, beta = args
+    return q, jnp.where((jnp.arange(k.shape[1]) % 3 == 0)[:, None, None],
+                        k[:, :1], k[:, 1:2]), v, g, beta
+
+
+# (T, chunk): 13 chunks of 16, 3 of 64 (T a multiple of neither), 5 of
+# 8, and 2 whole chunks of 64 with no decay
+@pytest.mark.parametrize("keys", ["random", "repeated"])
+@pytest.mark.parametrize("t,chunk,decay", [
+    (200, 16, 0.05), (200, 16, 20.0), (150, 64, 0.05), (150, 64, 20.0),
+    (37, 8, 0.05), (128, 64, 0.0),
+])
+def test_stacked_state_step_equals_four_products_and_token_by_token(
+        t, chunk, decay, keys):
+    args = inputs(t, decay, seed=5)
+    if keys == "repeated":
+        args = repeated_keys(args)
+    weight = jnp.cos(jnp.arange(8.0))
+    stacked = lambda *a: gated_delta_rule(*a, chunk=chunk)
+    four = lambda *a: per_chunk_scan(*a, chunk=chunk)
+    outs = [fn(*args) for fn in (stacked, four, gated_delta_rule_stepwise)]
+    assert bool(jnp.isfinite(outs[0]).all())
+    assert float(jnp.abs(outs[0] - outs[1]).max()) < 1e-6
+    assert float(jnp.abs(outs[0] - outs[2]).max()) < 5e-6
+
+    def grads(fn):
+        return jax.grad(
+            lambda *a: jnp.sum(fn(*a) * weight), argnums=(0, 1, 2, 3, 4)
+        )(*args)
+
+    for name, a, b, c in zip("qkvgb", grads(stacked), grads(four),
+                             grads(gated_delta_rule_stepwise)):
+        assert bool(jnp.isfinite(a).all()), name
+        scale = max(float(jnp.abs(c).max()), 1e-2)
+        assert float(jnp.abs(a - b).max()) < 1e-6 * scale, name
+        assert float(jnp.abs(a - c).max()) < 1e-5 * scale, name
+
+
+def scans(jaxpr):
+    return [e for e in equations(jaxpr) if e.primitive.name == "scan"]
+
+
+@pytest.mark.parametrize("chunks", [1, 3, 13])
+def test_one_scan_of_a_step_per_chunk(chunks):
+    """The op's whole program holds ONE scan, a step a chunk: the work
+    with the state is not a loop of its own."""
+    t, chunk = 8 * chunks - 5, 8
+    args = inputs(t, 0.05, b=1, h=1, dk=8, dv=4)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: gated_delta_rule(*a, chunk=chunk))(*args).jaxpr
+    loops = scans(jaxpr)
+    assert len(loops) == 1
+    assert loops[0].params["length"] == chunks
 
 
 def test_strong_decay_forgets_the_past():
