@@ -24,10 +24,10 @@ release's own keys (its `config.json`; `model_type` picks the family,
 `torch_dtype` the dtype the weights rest in) and takes the place of the
 GPT-shaping flags, which are refused beside it. Weights are random from
 `--seed`. A family that keeps a recurrent state per slot
-(`model_type: jamba`) is served from the page pool with chunked
-prefill only (`--page-size` and `--prefill-chunk` are required) and
-refuses `--prefix-cache`, `--speculative-k`, `--layout tp|sp` by name,
-each with the mechanism that is missing.
+(`model_type: jamba`, `qwen3_next`) is served from the page pool with
+chunked prefill only (`--page-size` and `--prefill-chunk` are
+required) and refuses `--prefix-cache`, `--speculative-k`, `--layout
+tp|sp` by name, each with the mechanism that is missing.
 
 The parser carries the shared training flags (grad reduction, pipeline
 stages) so a pasted training launch line fails fast with an explanation
@@ -228,11 +228,16 @@ def build_parser() -> argparse.ArgumentParser:
 
 # `model_type` of a --model-config file -> its configuration's builder.
 def _model_families() -> dict:
-    from distributed_model_parallel_tpu.models import glm_moe, jamba
+    from distributed_model_parallel_tpu.models import (
+        glm_moe,
+        jamba,
+        qwen3_next,
+    )
 
     return {
         jamba.MODEL_TYPE: jamba.config_from_dict,
         glm_moe.MODEL_TYPE: glm_moe.config_from_dict,
+        qwen3_next.MODEL_TYPE: qwen3_next.config_from_dict,
     }
 
 
@@ -565,6 +570,22 @@ def main(argv=None) -> dict:
             f"{spec.num_pages} pages of {spec.page_size} over "
             f"{spec.num_layers} layers, "
             f"{spec.num_pages * spec.page_bytes / 1e9:.3f} GB stored",
+            flush=True,
+        )
+    if engine.heads_major and jax.process_index() == 0:
+        spec, held = engine.paged_spec, getattr(cfg, "experts_held", None)
+        print(
+            f"==> {engine.family.name}: the cache holds {spec.num_pages} "
+            f"heads-major pages of {spec.page_size} "
+            f"{'rotated ' if engine.rotary else ''}keys and values, "
+            f"{spec.num_heads} heads of {spec.head_dim}, over "
+            f"{spec.num_layers} layers "
+            f"({spec.num_pages * spec.page_bytes / 1e9:.3f} GB)"
+            + (f", and {engine.state_spec.slot_bytes / 1e6:.2f} MB of "
+               f"state a slot over {len(engine.state_spec.layers)} layers"
+               if engine.state_spec else "")
+            + (f"; experts {held[0]}-{held[1] - 1} of "
+               f"{cfg.num_experts} held" if held else ""),
             flush=True,
         )
     if engine.chunk_state_program and jax.process_index() == 0:

@@ -16,7 +16,9 @@ token, or arrays of constant size per slot). `models/gpt.GPTConfig`
 answers with what the engine did before the seam;
 `models/jamba.JambaConfig` is the first with state,
 `models/glm_moe.GlmMoeConfig` the first with latent pages (and with
-expert layers, whose counters the engine sums).
+expert layers, whose counters the engine sums),
+`models/qwen3_next.Qwen3NextConfig` the first whose K/V layers rotate
+their keys inside the seam, beside a delta-rule state.
 """
 
 from __future__ import annotations
@@ -81,11 +83,17 @@ class LayerCache:
     heads and no separate values: a latent mixer's compressed row and
     its shared rotary key), or `state`, arrays of constant size per
     slot, {name: (shape of one slot's, dtype or None for the
-    activations')}."""
+    activations')}. `rotary` (values, base), on a layer of keys and
+    values: the layer hands its attention function q and k UNROTATED,
+    and the recorder rotates the first `values` of every query and key
+    head at the token's position (rotate-half, that base) and caches
+    the key rotated (`serving/decode.py`'s recorders of heads-major
+    pages)."""
 
     kv_heads: int = 0
     head_dim: int = 0
     latent_dim: int = 0
+    rotary: Optional[Tuple[int, float]] = None
     state: Dict[str, Tuple[Tuple[int, ...], Any]] = dataclasses.field(
         default_factory=dict
     )
@@ -158,6 +166,9 @@ class ServingFamily:
     counter_rows: Dict[str, Tuple[int, ...]] = dataclasses.field(
         default_factory=dict
     )
+    # {"last" counter: its dtype} where it is not int32 (a row of
+    # activations a layer took or gave).
+    counter_dtypes: Dict[str, Any] = dataclasses.field(default_factory=dict)
     # Widths the tp decode rings chunk over 'model', {label: n}.
     ring_widths: Dict[str, int] = dataclasses.field(default_factory=dict)
     # What of the engine this family cannot run yet, {option: the

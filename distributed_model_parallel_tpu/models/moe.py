@@ -273,7 +273,8 @@ COUNTERS = {
 # What a SERVED expert layer counts instead (a step whose mask says
 # which rows are real; `ServingFamily.counter_reductions`).
 SERVING_COUNTERS = {
-    "moe_picks": "sum",            # real rows' picks routed to an expert
+    "moe_picks": "sum",            # real rows' picks, held or not
+    "moe_picks_held": "sum",       # real rows' picks of a held expert
     "moe_experts_hit": "sum",      # held experts with at least one row
     "moe_expert_rows_max": "max",  # the fullest held expert's rows
     "moe_rows_masked": "sum",      # picks of rows that are not real
@@ -307,6 +308,33 @@ def route(scores_in, router_w, bias, top_k: int, scale: float):
     return ids, scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
 
 
+def widened(x):
+    """x in float32 holding exactly x's own values: a compiler that keeps
+    excess precision may otherwise hand a float32 consumer the value x
+    was rounded from, whichever way it fused the two."""
+    wide = x.astype(jnp.float32)
+    if x.dtype == jnp.float32:
+        return wide
+    f = jnp.finfo(x.dtype)
+    return jax.lax.reduce_precision(wide, exponent_bits=f.nexp,
+                                    mantissa_bits=f.nmant)
+
+
+def route_softmax(scores_in, router_w, bias, top_k: int, scale: float):
+    """Softmax router over ALL experts, `route`'s contract: the k experts
+    of largest probability + `bias` (zeros for a router that has no
+    correction), their probabilities renormalised to sum 1, times
+    `scale`. Float32 at `highest`, as `route`, on the rows as handed in
+    (`widened`): which experts a row takes does not hang on fusion."""
+    probs = jax.nn.softmax(jnp.matmul(
+        widened(scores_in), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    ), axis=-1)
+    _, ids = jax.lax.top_k(probs + bias, top_k)
+    picked = jnp.take_along_axis(probs, ids, axis=-1)
+    return ids, scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
+
+
 def held_experts_feed_forward(
     dim: int,
     hidden_dim: int,
@@ -317,6 +345,8 @@ def held_experts_feed_forward(
     shared_hidden_dim: int,
     routed_scale: float = 1.0,
     init_scale: float = 0.02,
+    router: str = "sigmoid",
+    shared_gate: bool = False,
 ) -> L.Layer:
     """One shared expert plus `num_experts` routed ones of which this
     chip holds the ids `experts_held = (first, past_last)`: it routes
@@ -337,6 +367,11 @@ def held_experts_feed_forward(
     `router_bias` (num_experts,), a buffer no gradient reaches and
     nothing updates, and the step's counters (`COUNTERS`), which the
     LM engine adds to its metrics.
+
+    `router` is the family's: "sigmoid" (`route`) or "softmax"
+    (`route_softmax`). `shared_gate` scales the shared expert by
+    `sigmoid(x . shared_gate.w)`, one number a row (param
+    `shared_gate.w` (D, 1)).
     """
     first, past = experts_held
     held = past - first
@@ -364,6 +399,8 @@ def held_experts_feed_forward(
                 "w_out": normal(kso, (shared_hidden_dim, dim)),
             },
         }
+        if shared_gate:
+            params["shared_gate"] = {"w": normal(kso, (dim, 1))}
         state = {"router_bias": jnp.zeros((num_experts,), jnp.float32)}
         state.update({c: jnp.zeros((), jnp.float32) for c in COUNTERS})
         return params, state
@@ -372,16 +409,23 @@ def held_experts_feed_forward(
         h, mask = x
         b, t, d = h.shape
         flat = h.reshape(b * t, d)
-        ids, weights = route(
+        # looked up as the step is traced, so that a patched router is
+        # the one a fresh step takes
+        ids, weights = (route_softmax if router == "softmax" else route)(
             flat, params["router"]["w"],
             jax.lax.stop_gradient(state["router_bias"]), top_k,
             routed_scale,
         )
         real = None if mask is None else mask.reshape(b * t)
-        routed, sizes, n_held, n_placed = held_experts_part(
+        routed, sizes, n_held, n_placed, n_real = held_experts_part(
             params["experts"], flat, ids, weights, first, real
         )
-        out = routed + gated_mlp(params["shared"], flat)
+        shared = gated_mlp(params["shared"], flat)
+        if shared_gate:
+            shared = shared * jax.nn.sigmoid(
+                flat @ params["shared_gate"]["w"].astype(flat.dtype)
+            )
+        out = routed + shared
         rows_max = jnp.max(sizes).astype(jnp.float32)
         if real is None:
             counters = {
@@ -391,7 +435,8 @@ def held_experts_feed_forward(
             }
         else:
             counters = {
-                "moe_picks": n_held,
+                "moe_picks": top_k * n_real,
+                "moe_picks_held": n_held,
                 "moe_experts_hit": jnp.sum(sizes > 0).astype(jnp.float32),
                 "moe_expert_rows_max": rows_max,
                 "moe_rows_masked": top_k * jnp.sum(~real).astype(
@@ -413,7 +458,8 @@ def held_experts_part(w, flat, ids, weights, first, real=None):
     held expert (held,), picks that landed on a held expert, those of
     them whose row in the sorted buffer lies among its expert's rows:
     where the sort put it, not what the mask says, so a buffer that ran
-    out or a sort that misplaced a pick would show). `first` may be
+    out or a sort that misplaced a pick would show, and the rows taken
+    as real: N without `real`). `first` may be
     traced (the test that adds up all the shares maps over it). `real`
     (N,) bool, where given, says which rows exist: the picks of the
     others count as absent.
@@ -454,8 +500,12 @@ def held_experts_part(w, flat, ids, weights, first, real=None):
         * jnp.where(is_held, weights, 0.0)[..., None],
         axis=1,
     ).astype(flat.dtype)
+    n_real = (
+        jnp.float32(flat.shape[0]) if real is None
+        else jnp.sum(real).astype(jnp.float32)
+    )
     return (part, sizes, jnp.sum(is_held).astype(jnp.float32),
-            picks_placed(group, inverse, sizes))
+            picks_placed(group, inverse, sizes), n_real)
 
 
 def picks_placed(group, inverse, sizes):

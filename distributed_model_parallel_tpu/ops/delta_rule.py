@@ -253,6 +253,17 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = DEFAULT_CHUNK):
     state starts at zero and is carried between chunks in float32. T
     need not be a multiple of `chunk`: the tail is padded with tokens
     that neither decay nor write."""
+    bsz, _, h, dk = q.shape
+    s0 = jnp.zeros((bsz, h, dk, v.shape[-1]), jnp.float32)
+    return gated_delta_rule_from(q, k, v, g, beta, s0, chunk=chunk)[0]
+
+
+def gated_delta_rule_from(q, k, v, g, beta, s0, *,
+                          chunk: int = DEFAULT_CHUNK):
+    """`gated_delta_rule` from the state `s0` (B, H, dk, dv) (a served
+    sequence's, left by its previous chunk) -> (outputs, the float32
+    state after the last position). A caller whose own tail is padding
+    gives it g = 0 and beta = 0, and the state passes it unchanged."""
     bsz, t, h, dk = q.shape
     dv = v.shape[-1]
     pad = -t % chunk
@@ -268,30 +279,40 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = DEFAULT_CHUNK):
         x = x.reshape((bsz, n, chunk) + x.shape[2:])
         return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 2, 3)
 
-    _, out = lax.scan(
+    state, out = lax.scan(
         jax.checkpoint(_chunk_step),
-        jnp.zeros((bsz, h, dk, dv), jnp.float32),
+        s0.astype(jnp.float32),
         (chunks(q), chunks(k), chunks(v), chunks(g), chunks(beta)),
     )
     # (n, B, H, C, dv) -> (B, T, H, dv)
     out = jnp.moveaxis(out, 0, 1)                        # (B, n, H, C, dv)
     out = jnp.moveaxis(out, 2, 3).reshape(bsz, n * chunk, h, dv)
-    return out[:, :t].astype(v.dtype)
+    return out[:, :t].astype(v.dtype), state
+
+
+def gated_delta_rule_step(q, k, v, g, beta, s):
+    """One token of every row from the state it has: q, k (B, H, dk),
+    v (B, H, dv), g (B, H, dk), beta (B, H), s (B, H, dk, dv) ->
+    (outputs (B, H, dv) float32, the new float32 state). The recurrence
+    as written, float32 at `highest`."""
+    qt, kt, vt, gt, bt = (x.astype(jnp.float32) for x in (q, k, v, g, beta))
+    s = jnp.exp(gt)[..., None] * s.astype(jnp.float32)
+    read = jnp.einsum("bhk,bhkv->bhv", kt, s, precision=_HIGHEST)
+    s = s + (bt[..., None] * kt)[..., None] * (vt - read)[..., None, :]
+    return jnp.einsum("bhk,bhkv->bhv", qt, s, precision=_HIGHEST), s
 
 
 def gated_delta_rule_stepwise(q, k, v, g, beta):
-    """The recurrence as written, one token a step: what the chunked
-    form is tested against (the benchmark's reference keeps its own
-    copy). Same contract as `gated_delta_rule`; float32 throughout."""
+    """The recurrence as written, one token a step
+    (`gated_delta_rule_step`): what the chunked form is tested against
+    (the benchmark's reference keeps its own copy). Same contract as
+    `gated_delta_rule`; float32 throughout."""
     bsz, _, h, dk = q.shape
     dv = v.shape[-1]
 
     def step(s, x):
-        qt, kt, vt, gt, bt = x                           # (B, H, ...)
-        s = jnp.exp(gt)[..., None] * s
-        read = jnp.einsum("bhk,bhkv->bhv", kt, s, precision=_HIGHEST)
-        s = s + (bt[..., None] * kt)[..., None] * (vt - read)[..., None, :]
-        return s, jnp.einsum("bhk,bhkv->bhv", qt, s, precision=_HIGHEST)
+        out, s = gated_delta_rule_step(*x, s)
+        return s, out
 
     xs = tuple(
         jnp.moveaxis(x.astype(jnp.float32), 1, 0)

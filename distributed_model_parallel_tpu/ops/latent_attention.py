@@ -83,6 +83,16 @@ def rope(x, positions, theta: float):
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
 
 
+def rotate_part(x, positions, rotary):
+    """x (B, T, H, Dh) at positions (B, T): the first `rotary[0]` values
+    of every head rotated by `rope` at base `rotary[1]`, the others as
+    they are, in x's dtype (a partial rotation of plain heads)."""
+    n, theta = rotary
+    return jnp.concatenate(
+        [rope(x[..., :n], positions, theta).astype(x.dtype), x[..., n:]], -1
+    )
+
+
 def latent_kind(queries: int, dims: LatentDims) -> str:
     """"absorbed" | "expanded": which form costs fewer products a key
     for `queries` query positions of one sequence (module doc)."""
@@ -268,25 +278,45 @@ def latent_attention_blocks(q_nope, q_rope, fetch, n_blocks, block: int,
     if kind == "expanded":
         width = dims.dv  # what the probabilities weigh
 
+    o = online_softmax(
+        n_blocks, block, q_pos,
+        lambda j: block_scores(kind, q, fetch(j), w_kvb, dims), weigh,
+        (1, h, tq), (1, tq, h, width),
+        lambda x: jnp.swapaxes(x, 1, 2)[..., None],
+    ).astype(q_nope.dtype)
+    return _result(kind, o, w_kvb, dims)
+
+
+def online_softmax(n_blocks, block: int, q_pos, scores, weigh,
+                   stats_shape, acc_shape, to_acc):
+    """Attention of queries at positions q_pos (Tq,) over as many
+    stretches of `block` keys as `n_blocks` (traced) says, a query
+    seeing the keys at or before it, by an online softmax between
+    stretches (float32 running maximum, sum and weighted values), so
+    that no score matrix over all keys exists and the work follows the
+    live length. `scores(j)` -> (float32 scores of stretch j, shaped
+    `stats_shape` + (block,), and what `weigh(p, that)` turns the
+    stretch's probabilities into: a term shaped `acc_shape`); `to_acc`
+    puts a statistic of `stats_shape` in the accumulator's layout.
+    -> the weighted values over the sum, float32 `acc_shape`."""
+
     def body(j, carry):
         m, l, acc = carry
-        s, values = block_scores(kind, q, fetch(j), w_kvb, dims)
+        s, values = scores(j)
         k_pos = j * block + jnp.arange(block)
-        seen = (k_pos[None, :] <= q_pos[:, None])[None, None]
+        seen = k_pos[None, :] <= q_pos[:, None]
         s = jnp.where(seen, s, NEG)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         alpha = jnp.exp(m - m_new)
         p = jnp.where(seen, jnp.exp(s - m_new[..., None]), 0.0)
         l = l * alpha + jnp.sum(p, axis=-1)
-        scale_acc = jnp.swapaxes(alpha, 1, 2)[..., None]
-        return m_new, l, acc * scale_acc + weigh(p, values)
+        return m_new, l, acc * to_acc(alpha) + weigh(p, values)
 
     m, l, acc = jax.lax.fori_loop(0, n_blocks, body, (
-        jnp.full((1, h, tq), NEG, F32), jnp.zeros((1, h, tq), F32),
-        jnp.zeros((1, tq, h, width), F32),
+        jnp.full(stats_shape, NEG, F32), jnp.zeros(stats_shape, F32),
+        jnp.zeros(acc_shape, F32),
     ))
-    o = (acc / jnp.swapaxes(l, 1, 2)[..., None]).astype(q_nope.dtype)
-    return _result(kind, o, w_kvb, dims)
+    return acc / to_acc(l)
 
 
 def latent_causal_attention(q_nope, q_rope, c, k_rope, w_kvb, mask,

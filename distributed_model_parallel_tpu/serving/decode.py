@@ -67,10 +67,16 @@ from distributed_model_parallel_tpu.ops.collective_matmul import (
     matmul_rs,
     matmul_rs_quant,
 )
+from distributed_model_parallel_tpu.ops import latent_attention
 from distributed_model_parallel_tpu.ops.latent_attention import (
+    KERNEL_PAGES,
+    LANES,
+    SUBLANES,
     latent_attention_blocks,
+    online_softmax,
     paged_decode_attention,
     rope,
+    rotate_part,
 )
 from distributed_model_parallel_tpu.ops.quant_matmul import quant_dot
 from distributed_model_parallel_tpu.runtime.compat import shard_map
@@ -90,6 +96,11 @@ def write_position(cache_layer, new, positions, active):
         )
     )(cache_layer, new, positions)
     return jnp.where(active[:, None, None, None], upd, cache_layer)
+
+
+def _divisor_at_most(n: int, most: int) -> int:
+    """The largest divisor of n that is at most `most` (>= 1)."""
+    return max(d for d in range(1, min(n, most) + 1) if n % d == 0)
 
 
 # ------------------------------------------------- decode attention fns
@@ -640,12 +651,8 @@ class PagedLatentChunk(_LatentRecorder):
         self.bt = bt_row  # (pages_per_slot,) int32
         self.start = start  # int32 global position of chunk token 0
         self.n_valid = n_valid  # int32 real tokens of the chunk
-        per_slot = bt_row.shape[0]
-        self.per = max(
-            n for n in range(1, per_slot + 1)
-            if per_slot % n == 0
-            and n * page_size <= max(self.KEY_BLOCK, page_size)
-        )
+        self.per = _divisor_at_most(
+            bt_row.shape[0], max(self.KEY_BLOCK // page_size, 1))
 
     def _write(self, pool, rows):
         """pool <- rows (chunk, row) at [start, start + n_valid): the
@@ -693,6 +700,228 @@ class PagedLatentChunk(_LatentRecorder):
             q_nope, rope(q_rope, pos, dims.theta).astype(q_nope.dtype),
             fetch, n_blocks, block, pool.shape[-1], pos[0], w_kvb, dims,
         )
+
+
+# ------------------------------------------ K/V pages, heads-major
+#
+# The recorders of a pool laid out heads-major, (num_pages, H, page,
+# Dh) (`PagedKVCacheSpec.heads_major`, which the engine lays where
+# `heads_major_pages` says the paged kernel tiles it): one head's page
+# is one (page, Dh) tile, so a decode kernel reads it whole and a pool
+# is read as one head's pages over `num_pages * H` pseudo-pages without
+# a copy. A family whose layers rotate their keys by position
+# (`models/lm_family.LayerCache.rotary`) hands over its query and key
+# UNROTATED, since only the recorder knows each token's position: the
+# recorder rotates the first `rotary[0]` values of every head
+# (rotate-half, base `rotary[1]`, float32 angles and products) and
+# caches the key rotated. A chunk's padded tail is not written.
+
+
+def _kernel_tiles(head_dim: int, page: int) -> bool:
+    """Heads of whole lane tiles in pages of whole sublanes."""
+    return head_dim % LANES == 0 and page % SUBLANES == 0
+
+
+def heads_major_pages(kv_heads: int, head_dim: int, page: int) -> bool:
+    """Whether K/V pages of these shapes are laid out heads-major:
+    grouped heads, more than one, that tile the paged kernel. One head
+    folds into such a tile token-major (`fold_heads`) and keeps the
+    token-major recorders."""
+    return kv_heads > 1 and _kernel_tiles(head_dim, page)
+
+
+def paged_kv_kind(head_dim: int, page: int) -> str:
+    """Which program `paged_grouped_attention` runs for these static
+    facts: `"kernel"` (JAX's Pallas paged attention) on a TPU for heads
+    of whole lane tiles and pages of whole sublanes; `"gather"` (every
+    slot's window gathered, XLA attention) anywhere else."""
+    if latent_attention._on_tpu() and _kernel_tiles(head_dim, page):
+        return "kernel"
+    return "gather"
+
+
+def paged_grouped_attention(q, k_pool, v_pool, block_table, positions,
+                            active):
+    """One decode step's grouped attention over heads-major pages: q
+    (slots, 1, Hq, Dh), k_pool / v_pool (num_pages, H, page, Dh) with
+    the step's keys already written, block_table (slots,
+    pages_per_slot) (-1: none), positions (slots,) each slot's newest
+    key -> (slots, 1, Hq, Dh). Query head j reads cached head j // (Hq /
+    H), scale Dh^-1/2; a slot attends over its keys 0..position (the
+    kernel reads only the pages that hold them); an inactive slot's
+    result is unspecified and finite."""
+    slots, _, hq, dh = q.shape
+    num_pages, h, page, _ = k_pool.shape
+    per_slot = block_table.shape[1]
+    pages = jnp.clip(block_table, 0, num_pages - 1)
+    if paged_kv_kind(dh, page) == "kernel":
+        from jax.experimental.pallas.ops.tpu.paged_attention import (
+            paged_attention,
+        )
+
+        # every (slot, cached head) pair one sequence of pseudo-pages
+        # page * H + head, its group of query heads its queries
+        rows = (q[:, 0].astype(jnp.float32) * dh ** -0.5).astype(q.dtype)
+        rows = rows.reshape(slots * h, hq // h, dh)
+        table = pages[:, None, :] * h + jnp.arange(h)[None, :, None]
+        lengths = jnp.repeat(jnp.where(active, positions + 1, 1), h)
+        flat = lambda pool: pool.reshape(1, num_pages * h, page, dh)
+        with jax.named_scope("kv_decode"):
+            o = paged_attention(
+                rows, flat(k_pool), flat(v_pool), lengths.astype(jnp.int32),
+                table.reshape(slots * h, per_slot).astype(jnp.int32),
+                pages_per_compute_block=_divisor_at_most(
+                    per_slot, KERNEL_PAGES),
+            )
+        return o.reshape(slots, 1, hq, dh).astype(q.dtype)
+    view = lambda pool: jnp.take(pool, pages, axis=0, mode="clip").swapaxes(
+        2, 3).reshape(slots, per_slot * page, h, dh)
+    valid = jnp.arange(per_slot * page)[None, :] <= positions[:, None]
+    return grouped_query_attention(q, view(k_pool), view(v_pool), mask=valid)
+
+
+def chunk_grouped_attention(q, k_view, v_view, q_pos, n_blocks, block: int):
+    """ONE sequence's chunk of queries q (1, C, Hq, Dh) at positions
+    q_pos (C,) over the first `n_blocks` (traced) stretches of `block`
+    keys of k_view / v_view (H, window, Dh), by
+    `ops/latent_attention.online_softmax`: a query sees keys at or
+    before it, and the work follows the live length."""
+    _, c, hq, dh = q.shape
+    h = k_view.shape[0]
+    g = hq // h
+    # (H, G * C, Dh): the G query heads of a group are G more rows
+    rows = q[0].reshape(c, h, g, dh).transpose(1, 2, 0, 3).reshape(
+        h, g * c, dh)
+
+    def scores(j):
+        k = lax.dynamic_slice_in_dim(k_view, j * block, block, axis=1)
+        v = lax.dynamic_slice_in_dim(v_view, j * block, block, axis=1)
+        return jnp.einsum("hqd,hkd->hqk", rows, k,
+                          preferred_element_type=jnp.float32) * dh ** -0.5, v
+
+    o = online_softmax(
+        n_blocks, block, jnp.tile(q_pos, g), scores,
+        lambda p, v: jnp.einsum("hqk,hkd->hqd", p.astype(v.dtype), v,
+                                preferred_element_type=jnp.float32),
+        (h, g * c), (h, g * c, dh), lambda x: x[..., None],
+    )
+    o = o.reshape(h, g, c, dh).transpose(2, 0, 1, 3)
+    return o.reshape(1, c, hq, dh).astype(q.dtype)
+
+
+class _HeadsRecorder:
+    def __init__(self, k, v, rotary, page_size: int):
+        self.k = k  # (layers, num_pages, H, page, Dh)
+        self.v = v
+        self.rotary = rotary  # (values, base), or None: keys as given
+        self.page = page_size
+        self.layer = 0
+
+    def _rotated(self, q, k_new, positions):
+        if self.rotary is None:
+            return q, k_new
+        return (rotate_part(q, positions, self.rotary),
+                rotate_part(k_new, positions, self.rotary))
+
+
+class PagedHeadsDecode(_HeadsRecorder):
+    """attention function for one traced PAGED decode step over
+    heads-major pages: every active slot's new key (rotated where the
+    family rotates) and value land at its own position (one row a head
+    scattered a slot; an inactive slot's drop out of bounds), then each
+    slot attends over its keys up to its position
+    (`paged_grouped_attention`)."""
+
+    def __init__(self, k, v, rotary, block_table, positions, active,
+                 page_size: int):
+        super().__init__(k, v, rotary, page_size)
+        self.bt = block_table  # (slots, pages_per_slot) int32
+        self.positions = positions  # (slots,) write/attend position
+        self.active = active  # (slots,) bool
+
+    def __call__(self, q, k_new, v_new, mask):
+        q, k_new = self._rotated(q, k_new, self.positions[:, None])
+        i = self.layer
+        self.layer += 1
+        dst = jnp.take_along_axis(
+            self.bt, self.positions[:, None] // self.page, axis=1
+        )[:, 0]
+        layers, num_pages, h, page, dh = self.k.shape
+        dst = jnp.where(self.active & (dst >= 0), dst, num_pages)
+        # one row a head into the pool seen as (num_pages * H) pages of
+        # one head (a free reshape): the index arrays side by side, so
+        # that the compiler keeps the pool's layout and writes in place
+        rows = dst[:, None] * h + jnp.arange(h)[None, :]
+        off = (self.positions % page)[:, None]
+
+        def write(pool, new):
+            flat = pool.reshape(layers, num_pages * h, page, dh)
+            flat = flat.at[i, rows, off].set(
+                new[:, 0].astype(pool.dtype), mode="drop")
+            return flat.reshape(pool.shape)
+
+        self.k, self.v = write(self.k, k_new), write(self.v, v_new)
+        return paged_grouped_attention(
+            q, self.k[i], self.v[i], self.bt, self.positions, self.active)
+
+
+class PagedHeadsChunk(_HeadsRecorder):
+    """attention function for ONE chunked-prefill step of ONE slot over
+    heads-major pages: the chunk's first `n_valid` keys (rotated where
+    the family rotates) and values land in the slot's pages at [start,
+    start + n_valid) (the (chunk - 1) // page + 2 pages the chunk can
+    touch are gathered, merged and scattered back whole; the padded
+    tail is not written), then the chunk's queries attend causally over
+    the slot's window stretch by stretch, as many stretches as reach
+    start + chunk (`chunk_grouped_attention`). A stretch is a whole
+    number of pages, at most `PagedLatentChunk.KEY_BLOCK` positions,
+    that divides the window."""
+
+    def __init__(self, k, v, rotary, bt_row, start, n_valid,
+                 page_size: int):
+        super().__init__(k, v, rotary, page_size)
+        self.bt = bt_row  # (pages_per_slot,) int32
+        self.start = start  # int32 global position of chunk token 0
+        self.n_valid = n_valid  # int32 real tokens of the chunk
+        self.per = _divisor_at_most(
+            bt_row.shape[0], max(PagedLatentChunk.KEY_BLOCK // page_size, 1))
+
+    def _write(self, pool, i, new):
+        """pool[i] <- new (chunk, H, Dh) at [start, start + n_valid)."""
+        chunk, page = new.shape[0], self.page
+        num_pages, per_slot = pool.shape[1], self.bt.shape[0]
+        idx = self.start // page + jnp.arange((chunk - 1) // page + 2)
+        dst = jnp.take(self.bt, jnp.clip(idx, 0, per_slot - 1), axis=0)
+        held = jnp.take(pool[i], dst, axis=0, mode="clip")  # (n, H, page, Dh)
+        t = idx[:, None] * page + jnp.arange(page)[None, :] - self.start
+        rows = jnp.take(new, jnp.clip(t, 0, chunk - 1), axis=0).swapaxes(1, 2)
+        inside = (t >= 0) & (t < self.n_valid)
+        dst = jnp.where((idx < per_slot) & (dst >= 0), dst, num_pages)
+        return pool.at[i, dst].set(
+            jnp.where(inside[:, None, :, None], rows.astype(pool.dtype), held),
+            mode="drop",
+        )
+
+    def __call__(self, q, k_new, v_new, mask):
+        chunk = q.shape[1]
+        pos = self.start + jnp.arange(chunk)
+        q, k_new = self._rotated(q, k_new, pos[None])
+        i = self.layer
+        self.layer += 1
+        self.k = self._write(self.k, i, k_new[0])
+        self.v = self._write(self.v, i, v_new[0])
+        block = self.per * self.page
+        n_blocks = jnp.minimum(
+            (self.start + chunk + block - 1) // block,
+            self.bt.shape[0] // self.per,
+        )
+        # the slot's window gathered ONCE, outside the loop (one slot's
+        # keys are tens of MB), heads leading: (H, window, Dh)
+        view = lambda pool: jnp.take(
+            pool[i], self.bt, axis=0, mode="clip"
+        ).transpose(1, 0, 2, 3).reshape(pool.shape[2], -1, pool.shape[-1])
+        return chunk_grouped_attention(
+            q, view(self.k), view(self.v), pos, n_blocks, block)
 
 
 # ------------------------------------------------------ state functions

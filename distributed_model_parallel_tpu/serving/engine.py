@@ -83,6 +83,8 @@ from distributed_model_parallel_tpu.serving.decode import (
     DecodeCollectiveMatmul,
     PagedCacheAttention,
     PagedChunkAttention,
+    PagedHeadsChunk,
+    PagedHeadsDecode,
     PagedLatentChunk,
     PagedLatentDecode,
     PagedSeqShardedCacheAttention,
@@ -91,6 +93,7 @@ from distributed_model_parallel_tpu.serving.decode import (
     SeqShardedCacheAttention,
     SlotStateChunk,
     SlotStateDecode,
+    heads_major_pages,
 )
 from distributed_model_parallel_tpu.serving.kv_cache import (
     KVCacheSpec,
@@ -261,6 +264,33 @@ class ServingEngine:
         paged_layers = sum(
             1 for lc in fam.layers if lc.kv_heads or lc.latent_dim
         )
+        # How the family's K/V layers rotate their keys inside the seam
+        # (None: they do not), one rotation for the one pool.
+        rotations = {
+            lc.rotary for lc in fam.layers if lc.kv_heads or lc.latent_dim
+        }
+        if len(rotations) != 1:
+            raise ValueError(
+                f"the {fam.name} family's layers rotate their keys as "
+                f"{sorted(rotations, key=str)}: one page pool, one rotation"
+            )
+        (self.rotary,) = rotations
+        # Pages heads-major where the paged kernel tiles them: only the
+        # replicated layout's chunked paged steps have their recorders
+        # (serving/decode.py), and only those rotate keys.
+        self.heads_major = (
+            self.layout == "replicated" and self.page_size is not None
+            and self.prefill_chunk is not None and not self.speculative_k
+            and heads_major_pages(kv_heads, head_dim, self.page_size)
+        )
+        if self.rotary is not None and not self.heads_major:
+            raise ValueError(
+                f"the {fam.name} family rotates its keys inside the "
+                "attention seam, which only the recorders of heads-major "
+                "pages do: the replicated layout's chunked paged steps "
+                "(page_size a multiple of 8, prefill_chunk, no "
+                "speculative_k) over grouped heads of whole lane tiles"
+            )
         self.latent_dim = latent_dim
         stateful = tuple(
             (i, tuple((name, tuple(shape), dtype or cache_dtype)
@@ -314,6 +344,7 @@ class ServingEngine:
                 # a unit head axis would be padded to a tile of rows
                 fold_heads=kv_heads == 1 and self.layout == "replicated",
                 latent_dim=latent_dim,
+                heads_major=self.heads_major,
             )
             self.paged_spec.validate(self.layout, self.mesh)
             if self.prefill_chunk is not None:
@@ -493,10 +524,11 @@ class ServingEngine:
                     continue
                 # a step's own array, one entry a row of the step
                 after = self.family.counter_rows[name]
+                dtype = self.family.counter_dtypes.get(name, jnp.int32)
                 for kind, rows in (("decode", self.num_slots),
                                    ("chunk", self.prefill_chunk or 0)):
                     counters[f"{name}_{kind}"] = jnp.zeros(
-                        (rows, *after), jnp.int32
+                        (rows, *after), dtype
                     )
         return out
 
@@ -697,7 +729,10 @@ class ServingEngine:
             rec = (
                 PagedLatentDecode(
                     cache["latent"], bt, positions, active, page
-                ) if latent else PagedCacheAttention(
+                ) if latent else PagedHeadsDecode(
+                    cache["k"], cache["v"], self.rotary, bt, positions,
+                    active, page,
+                ) if self.heads_major else PagedCacheAttention(
                     cache["k"], cache["v"], bt, positions, active, page
                 )
             )
@@ -818,7 +853,10 @@ class ServingEngine:
             rec = (
                 PagedLatentChunk(
                     cache["latent"], bt_row, start, n_valid, page
-                ) if latent else PagedChunkAttention(
+                ) if latent else PagedHeadsChunk(
+                    cache["k"], cache["v"], self.rotary, bt_row, start,
+                    n_valid, page,
+                ) if self.heads_major else PagedChunkAttention(
                     cache["k"], cache["v"], bt_row, start, page
                 )
             )
@@ -1571,9 +1609,11 @@ class ServingEngine:
             # what the family's layers counted, step by step on the
             # device (an expert layer's picks): fetched once, here
             **{
-                name: int(value) for name, value in
-                jax.device_get(cache.get("counters", {})).items()
-                if not value.shape  # (a step's own arrays stay there)
+                name: int(value) for name, value in jax.device_get({
+                    name: value
+                    for name, value in cache.get("counters", {}).items()
+                    if not value.shape  # (a step's own arrays stay there)
+                }).items()
             },
             # The one entry that holds times (Scheduler.timeline): its
             # counts repeat from run to run like the rest, its seconds

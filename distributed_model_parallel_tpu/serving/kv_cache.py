@@ -248,6 +248,11 @@ class PagedKVCacheSpec:
     # whole lane tiles, one pool a layer and no value pool; `num_heads`
     # and `head_dim` are not read.
     latent_dim: int = 0
+    # A page's rows are (num_heads, page_size, head_dim): one head's
+    # page is one tile, which a decode kernel reads whole, and a layer's
+    # pool reshapes to one head's pages without a copy
+    # (`serving/decode.py`'s `heads_major_pages` and its recorders).
+    heads_major: bool = False
 
     @property
     def latent_width(self) -> int:
@@ -260,6 +265,8 @@ class PagedKVCacheSpec:
         """One page of one layer's keys (or values, or latent rows)."""
         if self.latent_dim:
             return (self.page_size, self.latent_width)
+        if self.heads_major:
+            return (self.num_heads, self.page_size, self.head_dim)
         if self.fold_heads:
             return (self.page_size, self.num_heads * self.head_dim)
         return (self.page_size, self.num_heads, self.head_dim)

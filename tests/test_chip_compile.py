@@ -84,6 +84,32 @@ def test_latent_decode_attention_compiles_for_a_v5e_at_the_glm_cells_widths(
     assert "bf16[32,16384,640]" not in text and "[8192,64,640]" not in text
 
 
+def test_grouped_decode_attention_compiles_for_a_v5e_at_the_qwen3_cells_widths(
+        one_chip, monkeypatch):
+    """32 slots of 16 query heads over 2 cached heads of 256, a pool of
+    16,512 heads-major pages of 64, a block table of 516 pages a slot:
+    `qwen3next_serve_longread`'s decode step runs this once. Compiled,
+    not interpreted: one Mosaic call named `paged_attention`, and no
+    gathered view of every slot's whole window beside it."""
+    from distributed_model_parallel_tpu.ops import latent_attention as LA
+    from distributed_model_parallel_tpu.serving import decode
+
+    monkeypatch.setattr(LA, "_on_tpu", lambda: True)
+    assert decode.paged_kv_kind(256, 64) == "kernel"
+    assert decode.paged_kv_kind(64, 64) == "gather"
+    bf16 = jnp.bfloat16
+    arg = lambda shape, dtype=bf16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(decode.paged_grouped_attention).lower(
+            arg((32, 1, 16, 256)), arg((16512, 2, 64, 256)),
+            arg((16512, 2, 64, 256)), arg((32, 516), jnp.int32),
+            arg((32,), jnp.int32), arg((32,), jnp.bool_),
+        ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and "paged_attention" in text
+    assert "[32,33024,2,256]" not in text and "[32,516,2,64,256]" not in text
+
+
 @pytest.mark.parametrize(
     "cell, rows, dim, vocab",
     [("gpt2s_train", 16384, 768, 50257),

@@ -126,7 +126,7 @@ def test_the_parts_of_all_32_shares_add_up_to_the_uncut_layer(x):
         mine = jax.tree_util.tree_map(
             lambda w: jax.lax.dynamic_slice_in_dim(w, first, 2, axis=0),
             params["experts"])
-        part, _, held, placed = moe.held_experts_part(
+        part, _, held, placed, _ = moe.held_experts_part(
             mine, flat, ids, weights, first)
         return part, held - placed
 

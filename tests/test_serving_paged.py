@@ -310,6 +310,40 @@ def test_unaligned_chunk_ingest_logit_parity(dense):
         positions[0] += 1
 
 
+def test_heads_that_tile_the_kernel_are_paged_heads_major():
+    """K/V heads of whole lane tiles (2 heads of 128) over pages of 8
+    take the heads-major pool and its recorders, with no rotation (GPT
+    has none): chunked ingestion over page boundaries, then decode,
+    emit the dense twin's greedy tokens, a recycled slot included."""
+    cfg = GPTConfig(
+        vocab_size=61, dim=256, num_layers=2, num_heads=2, ffn_dim=64,
+        max_position=32, dropout_rate=0.0,
+    )
+    model = gpt_lm(cfg)
+    params, state = model.init(jax.random.PRNGKey(2))
+
+    def next_logits(ids):
+        ids = jnp.asarray(np.asarray(ids, np.int32))[None]
+        logits, _ = model.apply(params, state, ids, Context(train=False))
+        return np.asarray(logits[0, -1])
+
+    eng = ServingEngine(
+        cfg, num_slots=2, max_len=32, page_size=8, prefill_chunk=8,
+    )
+    assert eng.heads_major and eng.rotary is None
+    assert eng.init_cache()["k"].shape == (2, 8, 2, 8, 128)
+    rng = np.random.RandomState(3)
+    prompts = [rng.randint(1, 61, size=n).astype(np.int32)
+               for n in (13, 6, 9)]
+    sched = eng.run(eng.place_params(params), [
+        Request(rid=i, prompt=p, max_new_tokens=5)
+        for i, p in enumerate(prompts)
+    ])
+    got = {f.rid: f.tokens for f in sched.finished}
+    for i, p in enumerate(prompts):
+        assert got[i] == _greedy(next_logits, p, 5), i
+
+
 def test_chunked_lifts_prefill_len_cap(dense):
     """Chunked ingestion walks the prompt in place, so a prompt longer
     than the monolithic prefill_len pad serves fine (up to
