@@ -595,6 +595,13 @@ def main(argv=None) -> dict:
             f"{engine.chunk_state_program}",
             flush=True,
         )
+    if engine.chunk_decay_kind and jax.process_index() == 0:
+        print(
+            f"==> {engine.family.name}: a chunk of {engine.prefill_chunk} "
+            f"positions runs the delta rule's "
+            f"{engine.chunk_decay_kind}-decay path",
+            flush=True,
+        )
     draft_engine = draft_params = None
     if args.speculative_k:
         draft_cfg, draft_ckpt = _draft_config(args, cfg)

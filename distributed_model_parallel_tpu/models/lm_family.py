@@ -146,6 +146,10 @@ class ServingFamily:
     # ("kernel" | "loop"): what the model's own selector answers for
     # the widths of this family. None for a family that keeps none.
     chunk_state_program: Optional[Callable[[int], str]] = None
+    # The delta rule's path for the decay a chunk's state layers hand it
+    # (`ops/delta_rule.decay_kind`: "head" | "channel"). None for a
+    # family without delta-rule layers.
+    chunk_decay_kind: Optional[str] = None
     # The decode step's mask says which SLOTS are live (a family whose
     # layers route rows and count them); False hands every slot's row
     # as real, as the step did before such a family.

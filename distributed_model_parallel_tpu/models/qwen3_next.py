@@ -16,8 +16,9 @@ layer.
   depthwise, no bias; q and k repeated to Hv heads (head j reads key
   head j // (Hv / Hk)), `q <- l2norm(q) / sqrt(dk)`, `k <- l2norm(k)`;
   `beta = sigmoid(b)`, `g = -exp(A_log) * softplus(a + dt_bias)`, one
-  log decay per value head, broadcast over the key channels into
-  `ops/delta_rule.py`'s recurrence (float32 state at `highest`);
+  log decay per value head, handed to `ops/delta_rule.py`'s recurrence
+  as one (a trailing axis of 1: its head-decay path; float32 state at
+  `highest`);
   `o = RMSNorm(o) * w * silu(z)` per head (w starting at 1), `W_out`.
   Everything from the two in-projections to the recurrence (the
   convolution, its kept inputs, q, k, v, g, beta) is float32. Between
@@ -79,6 +80,7 @@ from distributed_model_parallel_tpu.ops.attention import (
     grouped_query_attention,
 )
 from distributed_model_parallel_tpu.ops.delta_rule import (
+    decay_kind,
     gated_delta_rule_from,
     gated_delta_rule_step,
 )
@@ -190,6 +192,9 @@ class Qwen3NextConfig:
                 for i in range(self.num_hidden_layers)
             ),
             param_dtype=jnp.dtype(self.param_dtype),
+            chunk_decay_kind=decay_kind(jax.eval_shape(
+                head_decay, jax.ShapeDtypeStruct(
+                    (1, 1, self.linear_num_value_heads), jnp.float32))),
             masks_inactive=True,
             counters=served_counters,
             counter_reductions={
@@ -336,6 +341,12 @@ def rotary_causal_attention(q, k, v, mask, *, rotary):
     )
 
 
+def head_decay(g):
+    """The mixer's log decay, one a value head (b, t, hv), as the delta
+    rule takes it: (b, t, hv, 1), the op's head-decay path."""
+    return g[..., None]
+
+
 def delta_mixer(cfg: Qwen3NextConfig, state_fn) -> L.Layer:
     d, k_conv = cfg.hidden_size, cfg.linear_conv_kernel_dim
     hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
@@ -402,15 +413,15 @@ def delta_mixer(cfg: Qwen3NextConfig, state_fn) -> L.Layer:
             q = unit(group(q)) * dk ** -0.5
             k = unit(group(k))
             v = v.reshape(b, t, hv, dv)
-            gk = jnp.broadcast_to(g[..., None], (b, t, hv, dk))
+            gh = head_decay(g)
             if t == 1:
                 o, s = gated_delta_rule_step(
-                    q[:, 0], k[:, 0], v[:, 0], gk[:, 0], beta[:, 0],
+                    q[:, 0], k[:, 0], v[:, 0], gh[:, 0], beta[:, 0],
                     state["s"],
                 )
                 o = o[:, None]
             else:
-                o, s = gated_delta_rule_from(q, k, v, gk, beta, state["s"])
+                o, s = gated_delta_rule_from(q, k, v, gh, beta, state["s"])
             return o, {"conv": kept, "s": s}
 
         o = rms_norm(params["o_norm"], state_fn(advance), eps)

@@ -1,14 +1,18 @@
-"""The gated delta rule with a decay per channel, in chunked form.
+"""The gated delta rule, in chunked form, with a decay per key channel
+or one a head.
 
-The recurrence of a delta-rule linear-attention head (Kimi Delta
-Attention's; per head a state S of shape (dk, dv)):
+The recurrence of a delta-rule linear-attention head (per head a state
+S of shape (dk, dv)):
 
     S_t = (I - b_t k_t k_t^T) Diag(exp g_t) S_{t-1} + b_t k_t v_t^T
     o_t = S_t^T q_t
 
-with g_t <= 0 the log of the decay (one number per key channel) and b_t
-in (0, 1) the write strength. Token by token that is T dependent steps
-of rank-one work; `gated_delta_rule` computes the same outputs chunk by
+with g_t <= 0 the log of the decay and b_t in (0, 1) the write
+strength. g_t is one number per key channel (Kimi Delta Attention's,
+g (..., dk)) or one a head (Gated DeltaNet's, g (..., 1)); `decay_kind`
+names the two by g's trailing axis, and everything but A, B and the
+sums G below takes either shape and broadcasts a trailing 1. Token by
+token that is T dependent steps of rank-one work; `gated_delta_rule` computes the same outputs chunk by
 chunk: inside a chunk of C tokens one unit-lower-triangular system, and
 between chunks the state alone.
 
@@ -30,7 +34,8 @@ what does (`_state_step`): one product of the state with rows stacked
 from solved K and q * exp G, and one of U with rows stacked from B and
 the decayed keys, in place of four products.
 
-A and B are built from sub-blocks of `_SUB` rows. Inside a sub-block
+With a decay per channel, A and B are built from sub-blocks of `_SUB`
+rows (`_chunk_products`). Inside a sub-block
 they are reduced on the vector unit from a (_SUB, _SUB, dk) tensor
 exp(G_i - G_j), j <= i, the differences taken from a cumulative sum
 that restarts at the sub-block (the more exact: under strong decay
@@ -55,6 +60,20 @@ form of A needs, overflows float32 after a handful of tokens at
 g = -20); a factor that underflows to 0 does so no sooner than
 exp(G_i - G_j) itself, which is no larger than either factor: it is
 the true value's own underflow.
+
+With one decay a head, exp(G_ic - G_jc) is one number a pair, so A
+and B are plain products masked by one (C, C) matrix (`_head_products`):
+
+    Gamma_ij = exp(G_i - G_j)   (j <= i; 0 above the diagonal)
+    A = tril(K K^T * Gamma, -1),   B = Q K^T * Gamma
+
+G_i - G_j is taken as the sum of the g between j and i (one product of
+the chunk's g with two triangles of ones), never as the difference of
+two sums over the chunk: under strong decay those reach -1,000 and
+more, where float32 cancellation would move a pair's factor by 1e-4.
+G_i and G_C - G_i are that product's first column (plus g_1) and last
+row, so the path takes no cumulative sum (on the TPU one is a window
+of C steps, as slow over one column as over 128: PERF.md section 6).
 
 Decay, state and the triangular solve are float32, and every product
 runs at `highest` precision: on the TPU a float32 product at the
@@ -125,6 +144,40 @@ def _unit_lower_inverse(n):
         ], axis=-2)
         size *= 2
     return inv[..., 0, :, :]
+
+
+def decay_kind(g) -> str:
+    """The path the op takes for a log decay g (..., dk) or (..., 1):
+    "head" where the trailing axis is 1 (one decay a head, Gated
+    DeltaNet's), else "channel" (one a key channel, Kimi Delta
+    Attention's)."""
+    return "head" if g.shape[-1] == 1 else "channel"
+
+
+def _head_products(q, k, g):
+    """A (strictly lower) and B (lower) of one chunk, both (..., C, C),
+    from float32 q, k (..., C, dk) and one log decay a head g
+    (..., C, 1), and the sums G_i and G_C - G_i, both (..., C, 1): the
+    module docstring's head form, every sum of g an entry of one
+    product."""
+    c = g.shape[-2]
+    upto = jnp.tril(jnp.ones((c, c), g.dtype))           # m <= i
+    # gaps_ij = sum over j < m <= i of g_m = G_i - G_j (for j <= i)
+    gaps = jnp.matmul(upto * jnp.swapaxes(g, -1, -2),
+                      jnp.tril(jnp.ones((c, c), g.dtype), -1),
+                      precision=_HIGHEST)
+    gamma = jnp.exp(jnp.where(upto > 0, gaps, -jnp.inf))
+    # K K^T and Q K^T as one product over rows stacked from k and q
+    products = jnp.einsum(
+        "...ic,...jc->...ij", jnp.concatenate([k, q], axis=-2), k,
+        precision=_HIGHEST,
+    )
+    return (
+        jnp.tril(products[..., :c, :] * gamma, -1),
+        products[..., c:, :] * gamma,
+        g[..., :1, :] + gaps[..., :1],                   # G_i
+        jnp.swapaxes(gaps[..., -1:, :], -1, -2),         # G_C - G_i
+    )
 
 
 def _chunk_products(q, k, g):
@@ -199,21 +252,29 @@ def _restart_every(n, upto, after):
 
 def _chunk_terms(chunk):
     """What a chunk (B, H, C, ...) needs from its inputs that does not
-    depend on the state it starts from:
+    depend on the state it starts from (g (..., C, dk) or (..., C, 1),
+    `decay_kind`):
 
     - `reads` (..., 2C, dk): rows (solved K; q * exp G), which multiply
       the state;
     - `solved_v` (..., C, dv);
     - `writes` (..., C + dk, C): rows (B; (k * exp(G_C - G))^T), which
       multiply U;
-    - `decay` (..., dk, 1): exp(G_C) for the state's rows.
+    - `decay` (..., dk | 1, 1): exp(G_C) for the state's rows.
     """
     q, k, v, g, beta = (x.astype(jnp.float32) for x in chunk)
     dv = v.shape[-1]
-    gc = jnp.cumsum(g, axis=-2)                          # G_i, <= 0
+    # (the channel path's operations in the order they always had)
+    head = decay_kind(g) == "head"
+    if head:
+        with jax.named_scope("kda_chunk"):
+            a, b, gc, after = _head_products(q, k, g)
+    else:
+        gc = jnp.cumsum(g, axis=-2)                      # G_i, <= 0
     eg = jnp.exp(gc)
     with jax.named_scope("kda_chunk"):
-        a, b = _chunk_products(q, k, g)
+        if not head:
+            a, b = _chunk_products(q, k, g)
         rhs = beta[..., None] * jnp.concatenate([v, k * eg], axis=-1)
         solved = jnp.matmul(
             _unit_lower_inverse(beta[..., None] * a), rhs,
@@ -221,8 +282,10 @@ def _chunk_terms(chunk):
         )
     g_end = gc[..., -1:, :]                              # G_C
     reads = jnp.concatenate([solved[..., dv:], q * eg], axis=-2)
+    if not head:
+        after = g_end - gc                               # G_C - G_i
     writes = jnp.concatenate(
-        [b, jnp.swapaxes(k * jnp.exp(g_end - gc), -1, -2)], axis=-2)
+        [b, jnp.swapaxes(k * jnp.exp(after), -1, -2)], axis=-2)
     decay = jnp.swapaxes(jnp.exp(g_end), -1, -2)
     return reads, solved[..., :dv], writes, decay
 
@@ -247,12 +310,13 @@ def _chunk_step(s0, chunk):
 
 
 def gated_delta_rule(q, k, v, g, beta, *, chunk: int = DEFAULT_CHUNK):
-    """q, k (B, T, H, dk), v (B, T, H, dv), g (B, T, H, dk) the log
-    decay (<= 0; float32), beta (B, T, H) -> outputs (B, T, H, dv) in
-    v's dtype. The arithmetic is float32 whatever q, k, v come in. The
-    state starts at zero and is carried between chunks in float32. T
-    need not be a multiple of `chunk`: the tail is padded with tokens
-    that neither decay nor write."""
+    """q, k (B, T, H, dk), v (B, T, H, dv), g (B, T, H, dk) or
+    (B, T, H, 1) the log decay (<= 0; float32; `decay_kind`), beta
+    (B, T, H) -> outputs (B, T, H, dv) in v's dtype. The arithmetic is
+    float32 whatever q, k, v come in. The state starts at zero and is
+    carried between chunks in float32. T need not be a multiple of
+    `chunk`: the tail is padded with tokens that neither decay nor
+    write."""
     bsz, _, h, dk = q.shape
     s0 = jnp.zeros((bsz, h, dk, v.shape[-1]), jnp.float32)
     return gated_delta_rule_from(q, k, v, g, beta, s0, chunk=chunk)[0]
@@ -292,9 +356,9 @@ def gated_delta_rule_from(q, k, v, g, beta, s0, *,
 
 def gated_delta_rule_step(q, k, v, g, beta, s):
     """One token of every row from the state it has: q, k (B, H, dk),
-    v (B, H, dv), g (B, H, dk), beta (B, H), s (B, H, dk, dv) ->
-    (outputs (B, H, dv) float32, the new float32 state). The recurrence
-    as written, float32 at `highest`."""
+    v (B, H, dv), g (B, H, dk) or (B, H, 1), beta (B, H), s
+    (B, H, dk, dv) -> (outputs (B, H, dv) float32, the new float32
+    state). The recurrence as written, float32 at `highest`."""
     qt, kt, vt, gt, bt = (x.astype(jnp.float32) for x in (q, k, v, g, beta))
     s = jnp.exp(gt)[..., None] * s.astype(jnp.float32)
     read = jnp.einsum("bhk,bhkv->bhv", kt, s, precision=_HIGHEST)

@@ -308,6 +308,10 @@ class ServingEngine:
             if stateful and fam.chunk_state_program and self.prefill_chunk
             else None
         )
+        # ... and which decay path their delta rule takes there.
+        self.chunk_decay_kind = (
+            fam.chunk_decay_kind if stateful and self.prefill_chunk else None
+        )
         self.spec = KVCacheSpec(
             num_layers=paged_layers, num_slots=self.num_slots,
             max_len=self.max_len, num_heads=kv_heads,
@@ -1331,8 +1335,12 @@ class ServingEngine:
             # chunks whose program ran the state layers' recurrence as
             # the kernel (0 for a page-only family and off a TPU)
             "state_kernel_chunks",
+            # chunks whose program ran the delta rule's head-decay path
+            # (0 for a family without delta-rule layers)
+            "state_head_decay_chunks",
         ), 0)
         kernel_chunk = int(self.chunk_state_program == "kernel")
+        head_decay_chunk = int(self.chunk_decay_kind == "head")
         state_pool_bytes = (
             self.state_spec.pool_bytes if self.state_spec else 0
         )
@@ -1460,6 +1468,7 @@ class ServingEngine:
                 tally["prefill_positions_valid"] += n
                 tally["prefill_positions_computed"] += self.prefill_chunk
                 tally["state_kernel_chunks"] += kernel_chunk
+                tally["state_head_decay_chunks"] += head_decay_chunk
                 if state_pool_bytes and start == 0:
                     tally["state_resets"] += 1
                 host.ensure_pages(slot, start + n)

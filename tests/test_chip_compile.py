@@ -110,6 +110,31 @@ def test_grouped_decode_attention_compiles_for_a_v5e_at_the_qwen3_cells_widths(
     assert "[32,33024,2,256]" not in text and "[32,516,2,64,256]" not in text
 
 
+@pytest.mark.parametrize("kind", ["head", "channel"])
+def test_the_delta_rule_compiles_for_a_v5e_at_the_qwen3_cells_chunk(
+        one_chip, kind):
+    """One delta-rule layer of `qwen3next_serve_longread`'s chunk
+    program: a chunk of 2,048 tokens, 32 heads of 128, float32, from a
+    state, g one a head (the head path) or broadcast over the key
+    channels. The TPU's compiler makes a cumulative sum a
+    `reduce-window` of 64 steps, as slow over the head path's one
+    column as over 128 channels (PERF.md section 6): the head path
+    runs none."""
+    from distributed_model_parallel_tpu.ops.delta_rule import (
+        gated_delta_rule_from,
+    )
+
+    t, h, d = 2048, 32, 128
+    arg = lambda shape: jax.ShapeDtypeStruct(
+        shape, jnp.float32, sharding=one_chip)
+    g = arg((1, t, h, 1 if kind == "head" else d))
+    text = jax.jit(gated_delta_rule_from).lower(
+        arg((1, t, h, d)), arg((1, t, h, d)), arg((1, t, h, d)), g,
+        arg((1, t, h)), arg((1, h, d, d)),
+    ).compile().as_text()
+    assert ("reduce-window(" in text) == (kind == "channel")
+
+
 @pytest.mark.parametrize(
     "cell, rows, dim, vocab",
     [("gpt2s_train", 16384, 768, 50257),

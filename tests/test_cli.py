@@ -695,7 +695,9 @@ def test_serve_cli_says_once_which_program_a_chunks_recurrence_runs_as(
     line = ("==> jamba: a chunk of 8 positions runs the state layers' "
             "recurrence as the loop")
     assert out.count(line) == 1 and out.count("recurrence as the") == 1
+    assert "decay path" not in out
     assert result["serving"]["paged"]["state_kernel_chunks"] == 0
+    assert result["serving"]["paged"]["state_head_decay_chunks"] == 0
     with open(mpath) as f:
         assert json.load(f)["gauges"]["serve_state_scan_kernel"] == 0.0
     assert "serve_state_scan_kernel" in metrics.METRIC_NAMES

@@ -4,7 +4,11 @@ of the chunk, no decay, mild decay, and a decay so strong that any
 `exp(-G)` would overflow float32. The chunk step's work with the state,
 two products over stacked rows, against the four products it replaced.
 And the chunk's A and B, which come from sub-blocks and matrix
-products, against the (C, C, dk) reduction they replaced."""
+products, against the (C, C, dk) reduction they replaced. The path for
+one decay a head (g (..., 1)) against the channel path with g broadcast
+and the recurrence, and the channel path held to the program it was."""
+
+import collections
 
 import math
 
@@ -16,8 +20,12 @@ from distributed_model_parallel_tpu.ops.delta_rule import (
     _SUB,
     _chunk_products,
     _chunk_step,
+    _chunk_terms,
     _unit_lower_inverse,
+    decay_kind,
     gated_delta_rule,
+    gated_delta_rule_from,
+    gated_delta_rule_step,
     gated_delta_rule_stepwise,
 )
 
@@ -267,3 +275,124 @@ def test_bfloat16_state_is_a_measurably_lower_precision():
     f32 = gated_delta_rule(*args)
     assert float(jnp.abs(f32 - exact).max()) < 5e-6
     assert float(jnp.abs(rounded_state(*args) - exact).max()) > 1e-3
+
+
+def test_decay_kind_follows_the_trailing_axis():
+    assert decay_kind(jnp.zeros((2, 64, 32, 1))) == "head"
+    assert decay_kind(jnp.zeros((2, 64, 32, 128))) == "channel"
+    assert decay_kind(jax.ShapeDtypeStruct((3, 1), jnp.float32)) == "head"
+
+
+def head_decays(decay, t, seed, b=2, h=3):
+    """g (b, t, h, 1); "mixed": even heads never decay, odd ones lose
+    e^-40 and e^-0.05 on alternate tokens."""
+    if decay == "mixed":
+        odd = jnp.where(jnp.arange(t) % 2 == 0, -40.0, -0.05)[:, None]
+        g = jnp.where(jnp.arange(h) % 2 == 0, 0.0, odd)
+        return jnp.broadcast_to(g[None, :, :, None], (b, t, h, 1))
+    return -decay * jax.random.uniform(
+        jax.random.PRNGKey(seed), (b, t, h, 1), minval=0.5, maxval=1.5)
+
+
+def stepwise_from(q, k, v, g, beta, s0):
+    """The recurrence as written from the state s0, one token a step
+    -> (outputs, the state after the last)."""
+    def step(s, x):
+        out, s = gated_delta_rule_step(*x, s)
+        return s, out
+
+    s, out = jax.lax.scan(
+        step, s0, tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta)))
+    return jnp.moveaxis(out, 0, 1), s
+
+
+# (T, chunk): T a multiple of no chunk, so every case ends in a padded
+# tail; 64 and 128 the chunks the served cell may take
+@pytest.mark.parametrize("decay", [0.0, 0.05, 20.0, "mixed"])
+@pytest.mark.parametrize("t,chunk", [(150, 64), (150, 128), (37, 16)])
+def test_head_path_equals_the_channel_path_and_token_by_token(
+        t, chunk, decay):
+    """From a nonzero state: outputs, the last state and the gradients
+    of both, the head path (g (..., 1)) against the channel path with g
+    broadcast over the key channels and against the recurrence."""
+    q, k, v, _, beta = inputs(t, 0.0, seed=6)
+    g = head_decays(decay, t, seed=7)
+    s0 = jax.random.normal(jax.random.PRNGKey(8), (2, 3, 16, 8))
+    head = lambda *a: gated_delta_rule_from(*a, chunk=chunk)
+    channel = lambda q, k, v, g, beta, s0: gated_delta_rule_from(
+        q, k, v, jnp.broadcast_to(g, q.shape), beta, s0, chunk=chunk)
+    args = (q, k, v, g, beta, s0)
+    runs = [jax.jit(fn)(*args) for fn in (head, channel, stepwise_from)]
+    for got, other, want in zip(*runs):
+        assert bool(jnp.isfinite(got).all())
+        scale = max(float(jnp.abs(want).max()), 1.0)
+        assert float(jnp.abs(got - other).max()) < 5e-6 * scale
+        assert float(jnp.abs(got - want).max()) < 5e-6 * scale
+
+    w_out = jnp.cos(jnp.arange(8.0))
+    w_state = jnp.sin(jnp.arange(16.0 * 8).reshape(16, 8))
+
+    def grads(fn):
+        def loss(*a):
+            out, state = fn(*a)
+            return jnp.sum(out * w_out) + jnp.sum(state * w_state)
+        return jax.jit(jax.grad(loss, argnums=tuple(range(6))))(*args)
+
+    for name, a, b, c in zip(("q", "k", "v", "g", "beta", "s0"), grads(head),
+                             grads(channel), grads(stepwise_from)):
+        assert a.shape == c.shape, name
+        assert bool(jnp.isfinite(a).all()), name
+        # float32 rounding against the leaf's own scale; under strong
+        # decay the decay's gradient (~5e-5) is a difference of terms of
+        # ~1, which float32 leaves 2e-7 apart
+        bound = 1e-5 * max(float(jnp.abs(c).max()), 2e-2)
+        assert float(jnp.abs(a - b).max()) < bound, (name, decay)
+        assert float(jnp.abs(a - c).max()) < bound, (name, decay)
+
+
+def test_head_path_takes_no_tensor_a_key_channel_wide_per_pair():
+    """One chunk step at (C, dk, dv) = (64, 128, 24) with one decay a
+    head: no exponential wider than the (C, C) decay matrix, nothing
+    larger than the (2C, dk) rows that read the state, no sub-block
+    reduction, and no cumulative sum: every sum of g comes out of the
+    one product that makes the decay matrix."""
+    c, dk, dv = 64, 128, 24
+    q, k, v, _, beta = one_chunk(0.0, c, dk, dv, h=1)
+    g = -jnp.ones(v.shape[:-1] + (1,))
+    jaxpr = jax.make_jaxpr(_chunk_step)(
+        jnp.zeros((1, 1, dk, dv)), (q, k, v, g, beta))
+    largest, exps = 0, []
+    for eqn in equations(jaxpr.jaxpr):
+        size = max(math.prod(var.aval.shape) for var in eqn.outvars)
+        largest = max(largest, size)
+        if eqn.primitive.name == "exp":
+            exps.append(size)
+        assert eqn.primitive.name not in ("reduce_sum", "cumsum"), eqn
+    assert largest <= 2 * c * dk
+    assert max(exps) == c * c and len(exps) <= 4
+
+
+# `_chunk_terms` at kimilin_train_8k's shapes ((2, 32) chunks of 64, 128
+# a head, bfloat16 q, k, v, a decay a key channel), primitive by
+# primitive as the program was before the head path came: the path the
+# Kimi cell times is the same program
+CHANNEL_PATH = {
+    "add": 26, "broadcast_in_dim": 77, "concatenate": 17,
+    "convert_element_type": 23, "cumsum": 3, "dot_general": 17, "eq": 1,
+    "exp": 13, "gather": 12, "ge": 5, "iota": 12, "jit": 26, "mul": 26,
+    "neg": 3, "pad": 15, "reduce_sum": 8, "reshape": 6, "select_n": 9,
+    "slice": 57, "squeeze": 9, "sub": 6, "transpose": 4,
+}
+
+
+def test_the_channel_path_is_the_program_it_was():
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    b, h, c, d = 2, 32, 64, 128
+    chunk = tuple(jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in [
+        ((b, h, c, d), bf16), ((b, h, c, d), bf16), ((b, h, c, d), bf16),
+        ((b, h, c, d), f32), ((b, h, c), f32)])
+    jaxpr = jax.make_jaxpr(_chunk_terms)(chunk).jaxpr
+    counts = collections.Counter(
+        e.primitive.name for e in equations(jaxpr))
+    assert dict(counts) == CHANNEL_PATH
+    assert len(jaxpr.eqns) == 293

@@ -169,6 +169,24 @@ def test_chunked_prefill_and_decode_equal_the_reference(engine, params):
     assert 0 < sched.paged_stats["moe_picks_held"] < 4 * 4 * real
 
 
+def test_every_chunk_runs_the_head_decay_path(engine, params):
+    """The family answers `decay_kind` of the decay its mixer hands the
+    op, and `state_head_decay_chunks` counts every chunk of a drain:
+    prompts of one, two and three chunks, padded tails among them."""
+    assert engine.chunk_decay_kind == "head"
+    rng = np.random.default_rng(6)
+    lengths = (41, 5, 16, 23)
+    sched = engine.run(params, [
+        Request(rid=i, prompt=rng.integers(1, 97, size=n, dtype=np.int32),
+                max_new_tokens=2)
+        for i, n in enumerate(lengths)])
+    stats = sched.paged_stats
+    chunks = sum(-(-n // 16) for n in lengths)
+    assert stats["state_head_decay_chunks"] == chunks == 7
+    assert stats["prefill_positions_computed"] == 16 * chunks
+    assert stats["state_kernel_chunks"] == 0
+
+
 def test_the_state_and_the_rotated_keys_are_the_references(engine, params):
     """What the programs left in the cache after 41 tokens and 6 decode
     steps: the first layer's S and kept convolution inputs, and the
@@ -353,9 +371,12 @@ def test_cli_serve_starts_the_family_and_says_what_the_cache_holds(
         "--prompt-len-min", "5", "--prompt-len-max", "20",
         "--max-new-tokens", "3", "--seed", "1",
     ])
-    lines = [line for line in capsys.readouterr().out.splitlines()
-             if "rotated keys" in line]
+    printed = capsys.readouterr().out
+    lines = [line for line in printed.splitlines() if "rotated keys" in line]
     assert len(lines) == 1 and lines[0].startswith("==> qwen3_next:")
+    assert printed.count(
+        "==> qwen3_next: a chunk of 16 positions runs the delta rule's "
+        "head-decay path") == 1
     assert "2 heads of 128" in lines[0]
     assert "experts 0-7 of 16 held" in lines[0]
     assert "MB of state a slot over 3 layers" in lines[0]

@@ -155,6 +155,8 @@ def test_chunked_prefill_then_the_absorbed_step_equal_the_reference(
     assert stats["moe_experts_hit"] >= 2 * steps  # a live step reaches one
     assert 1 <= stats["moe_expert_rows_max"] <= 2 * CHUNK
     assert stats["state_pool_bytes"] == 0
+    assert engine.chunk_decay_kind is None
+    assert stats["state_head_decay_chunks"] == 0
     assert stats["latent_pool_bytes"] == (
         engine.paged_spec.num_pages * PAGE * 3 * 128 * 4)  # as stored
 

@@ -114,6 +114,9 @@ def test_chunked_prefill_and_decode_equal_the_full_forward(engine, params):
     # off a TPU the recurrence is the loop, in every chunk
     assert engine.chunk_state_program == "loop"
     assert stats["state_kernel_chunks"] == 0
+    # a Mamba layer has no delta rule
+    assert engine.chunk_decay_kind is None
+    assert stats["state_head_decay_chunks"] == 0
     assert stats["cow_copies"] == 0
 
 
