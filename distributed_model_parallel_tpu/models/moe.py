@@ -466,12 +466,13 @@ def held_experts_part(w, flat, ids, weights, first, real=None):
 
     Picks are sorted by expert, absent ones behind every held expert's
     rows, into a buffer of N * k rows — the worst case, since a smaller
-    one would drop picks — and multiplied group by group at the cost of
-    the live rows alone."""
+    one would drop picks — multiplied group by group at the cost of
+    the live rows alone, and gathered back and weighed in one pass
+    (`combine_rows`)."""
     from distributed_model_parallel_tpu.ops.grouped_matmul import (
+        combine_rows,
         grouped_matmul,
         sort_rows,
-        unsort_rows,
     )
 
     held, top_k = w["w_in"].shape[0], ids.shape[-1]
@@ -491,15 +492,13 @@ def held_experts_part(w, flat, ids, weights, first, real=None):
     gate, up = jnp.split(
         grouped_matmul(rows, w["w_in"], sizes), 2, axis=-1
     )
-    rows = grouped_matmul(jax.nn.silu(gate) * up, w["w_out"], sizes)
-    picks = unsort_rows(rows, order, inverse).reshape(
-        flat.shape[0], top_k, flat.shape[1]
+    # the rows past the live end are absent picks', which the combine
+    # selects away and gives no gradient: the product's tail need not
+    # be zeroed
+    rows = grouped_matmul(
+        jax.nn.silu(gate) * up, w["w_out"], sizes, zero_tail=False
     )
-    part = jnp.sum(
-        picks.astype(jnp.float32)
-        * jnp.where(is_held, weights, 0.0)[..., None],
-        axis=1,
-    ).astype(flat.dtype)
+    part = combine_rows(rows, order, inverse, weights, is_held)
     n_real = (
         jnp.float32(flat.shape[0]) if real is None
         else jnp.sum(real).astype(jnp.float32)

@@ -185,3 +185,33 @@ def test_head_loss_compiles_for_a_v5e_at_the_training_cells_shapes(
         "tpu_custom_call" in ln or " get-tuple-element(" in ln
         for ln in touching
     ), touching
+
+
+def test_the_expert_combine_compiles_for_a_v5e_at_the_qwen3_cells_chunk(
+        one_chip, monkeypatch):
+    """One expert layer of `qwen3next_serve_longread`'s chunk program:
+    2,048 tokens of 2,048, ten picks each among 512 experts of which 256
+    are held, 512 wide. The picks come back through `combine_rows`: no
+    array of every pick's row in token order, in any dtype, and no
+    float32 one of every sorted row; the temporaries are the sorted
+    buffer's 84 MB and little more (218 MB with the unsort and float32
+    sum it replaced)."""
+    from distributed_model_parallel_tpu.models import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, k, d, f, held = 2048, 10, 2048, 512, 256
+    arg = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    w = {"w_in": arg((held, d, 2 * f)), "w_out": arg((held, f, d))}
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(
+            lambda w, flat, ids, weights, real: moe.held_experts_part(
+                w, flat, ids, weights, 0, real)[0]
+        ).lower(
+            w, arg((n, d)), arg((n, k), jnp.int32),
+            arg((n, k), jnp.float32), arg((n,), jnp.bool_),
+        ).compile()
+    text = compiled.as_text()
+    for shape in (f"[{n},{k},{d}]", f"f32[{n * k},{d}]"):
+        assert shape not in text, shape
+    assert compiled.memory_analysis().temp_size_in_bytes < 96 * 2 ** 20

@@ -3,13 +3,18 @@ nothing (`models/moe.py::held_experts_feed_forward`): every pick on one
 held expert, every pick on held experts (the buffer's worst case), no
 pick on any, and the tie to the whole model: the parts of all 32 shares,
 the shared expert counted once, add up to the uncut layer's output,
-which is also the plain reference's."""
+which is also the plain reference's. And the combine that brings the
+picks back (`ops/grouped_matmul.combine_rows`) against the unsort and
+float32 sum it replaced."""
 
 import os
+import re
 import sys
+import types
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -18,6 +23,7 @@ sys.path.insert(0, ROOT)
 from benchmark.reference import kimi_linear_ref as ref  # noqa: E402
 from distributed_model_parallel_tpu.models import moe  # noqa: E402
 from distributed_model_parallel_tpu.models.layers import Context  # noqa: E402
+from distributed_model_parallel_tpu.ops import grouped_matmul as gm  # noqa: E402
 
 D, F, E, K, SCALE = 32, 16, 64, 4, 2.446
 TOKENS = (2, 16)
@@ -177,3 +183,154 @@ def test_the_dropped_counter_reads_where_the_sort_put_a_pick():
     # a buffer of 4 rows: the pick sorted to row 4 has none
     assert float(moe.picks_placed(
         group, jnp.where(inverse < 4, inverse, 8), sizes)) == 4
+
+
+def picks(seed, n=48):
+    """A sorted buffer's routing: held (n, K), order and inverse as
+    `held_experts_part` makes them (absent picks behind 4 experts'
+    rows), weights (n, K)."""
+    kh, kg, kw = jax.random.split(jax.random.PRNGKey(seed), 3)
+    held = jax.random.bernoulli(kh, 0.6, (n, K))
+    group = jnp.where(held, jax.random.randint(kg, (n, K), 0, 4), 4)
+    order = jnp.argsort(group.reshape(-1), stable=True)
+    inverse = jnp.zeros_like(order).at[order].set(jnp.arange(n * K))
+    return held, order, inverse, jax.random.uniform(kw, (n, K))
+
+
+def unsort_and_sum(rows, order, inverse, weights, held):
+    """What `held_experts_part` did before `combine_rows`: every pick's
+    row back in token order, widened, weighted and summed over k."""
+    n, k = weights.shape
+    back = jnp.take(rows, inverse, axis=0).reshape(n, k, rows.shape[1])
+    return jnp.sum(back.astype(jnp.float32)
+                   * jnp.where(held, weights, 0.0)[..., None],
+                   axis=1).astype(rows.dtype)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_combine_equals_the_unsort_and_float32_sum(dtype):
+    held, order, inverse, weights = picks(0)
+    rows = jax.random.normal(jax.random.PRNGKey(3), (held.size, D), dtype)
+    got = jax.jit(gm.combine_rows)(rows, order, inverse, weights, held)
+    assert got.dtype == rows.dtype and got.shape == (held.shape[0], D)
+    old = jax.jit(unsort_and_sum)(rows, order, inverse, weights, held)
+    if dtype == "float32":
+        # the picks added in slot order, each product rounded to float32
+        back = np.asarray(rows)[np.asarray(inverse)].reshape(
+            held.shape + (D,))
+        w = np.where(np.asarray(held), np.asarray(weights), 0.0).astype(
+            np.float32)
+        want = np.zeros((held.shape[0], D), np.float32)
+        for j in range(held.shape[1]):
+            want = want + back[:, j] * w[:, j, None]
+        assert np.array_equal(np.asarray(got), want)
+        # jnp.sum adds the same picks in an order of its own
+        assert float(jnp.abs(got - old).max()) <= 4 * np.finfo(
+            np.float32).eps * float(jnp.abs(old).max())
+    else:
+        # rounded once from float32 sums that differ at most in their
+        # order: within one bfloat16 step of the old result
+        step = jnp.abs(jnp.nextafter(old, jnp.inf * jnp.sign(old)) - old)
+        wide = lambda a: a.astype(jnp.float32)
+        assert bool(jnp.all(jnp.abs(wide(got) - wide(old)) <= wide(step)))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_combine_gradients_equal_autodiff_of_the_plain_form(dtype):
+    held, order, inverse, weights = picks(1)
+    rows = jax.random.normal(jax.random.PRNGKey(4), (held.size, D), dtype)
+
+    # a linear loss: both forms are handed the same cotangent, whatever
+    # order their forward sums took
+    g = jax.random.normal(jax.random.PRNGKey(5), (held.shape[0], D))
+
+    def grads(fn):
+        loss = lambda r, w: jnp.sum(
+            fn(r, order, inverse, w, held).astype(jnp.float32) * g)
+        return jax.jit(jax.grad(loss, (0, 1)))(rows, weights)
+
+    (d_rows, d_w), (want_rows, want_w) = (
+        grads(gm.combine_rows), grads(unsort_and_sum))
+    assert d_rows.dtype == rows.dtype and d_w.dtype == weights.dtype
+    # a row no held pick owns has no gradient; nor has such a weight
+    unowned = ~held.reshape(-1)[order]
+    assert float(jnp.abs(d_rows[unowned].astype(jnp.float32)).max()) == 0
+    assert float(jnp.abs(d_w[~held]).max()) == 0
+    # the rows' gradient is the same products, gathered the other way
+    assert bool(jnp.array_equal(d_rows, want_rows))
+    assert float(jnp.abs(d_w - want_w).max()) <= 1e-5 * max(
+        1.0, float(jnp.abs(want_w).max()))
+
+
+def test_rows_past_the_live_end_leave_the_combine_finite():
+    """The grouped product leaves the rows past its live end unwritten:
+    whatever they hold (NaN here) is selected away, forward and in
+    both gradients, not weighted by zero."""
+    held, order, inverse, weights = picks(2)
+    rows = jax.random.normal(jax.random.PRNGKey(5), (held.size, D))
+    live = jnp.arange(held.size) < jnp.sum(held)
+    poisoned = jnp.where(live[:, None], rows, jnp.nan)
+    loss = lambda r, w: jnp.sum(jnp.sin(
+        gm.combine_rows(r, order, inverse, w, held)))
+    for got, want in zip(
+            jax.jit(jax.value_and_grad(loss, (0, 1)))(poisoned, weights),
+            jax.jit(jax.value_and_grad(loss, (0, 1)))(
+                jnp.where(live[:, None], rows, 0.0), weights)):
+        for a, b in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            assert bool(jnp.all(jnp.isfinite(a)))
+            assert bool(jnp.array_equal(a, b))
+
+
+def test_an_unwritten_product_tail_leaves_the_layer_finite(x, monkeypatch):
+    """The layer with a grouped product that writes NaN past the live
+    end, where the kernel writes nothing: its output and every gradient
+    are those of the sound layer."""
+    l = layer((8, 16))
+    params, state = l.init(jax.random.PRNGKey(2))
+    loss = lambda p, xx: jnp.sum(jnp.sin(l.apply(
+        p, state, (xx, None), Context(train=True))[0][0]))
+    run = jax.jit(jax.value_and_grad(loss, (0, 1)))
+    want = run(params, x)
+
+    sound = gm.megablox.gmm
+
+    def unwritten_tail(lhs, rhs, group_sizes, *rest):
+        out = sound(lhs, rhs, group_sizes, *rest)
+        live = jnp.arange(out.shape[0]) < jnp.sum(group_sizes)
+        return jnp.where(live[:, None], out, jnp.nan)
+
+    monkeypatch.setattr(gm, "megablox", types.SimpleNamespace(
+        gmm=unwritten_tail))
+    got = jax.jit(jax.value_and_grad(loss, (0, 1)))(params, x)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        assert float(jnp.abs(a - b).max()) <= 1e-6 * max(
+            1.0, float(jnp.abs(b).max()))
+
+
+ROWS_SCATTERED = re.compile(r"\w+:\w+\[(\d+),(\d+)\] = scatter-add")
+
+
+def test_the_layer_gradient_scatters_no_rows():
+    """Every row movement of the expert layer's gradient is a gather:
+    the only scatter-adds are the grouped product's group bookkeeping
+    (a vector), none over a buffer of rows, which the TPU serialises."""
+    held, order, inverse, weights = picks(3)
+    flat = jax.random.normal(jax.random.PRNGKey(6), (held.shape[0], D))
+    l = layer((0, 8))
+    params, _ = l.init(jax.random.PRNGKey(0))
+    ids = jnp.argsort(jax.random.uniform(
+        jax.random.PRNGKey(7), (held.shape[0], E)), axis=-1)[:, :K]
+    loss = lambda w, f, ww: jnp.sum(moe.held_experts_part(
+        w, f, ids, ww, 0)[0] ** 2)
+    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(
+        params["experts"], flat, weights))
+    assert "custom_vjp_call" in text
+    assert not ROWS_SCATTERED.findall(text)
+    # the detector sees a row gather's plain transpose
+    plain = str(jax.make_jaxpr(jax.grad(lambda r: jnp.sum(
+        unsort_and_sum(r, order, inverse, weights, held) ** 2)))(
+        jax.random.normal(jax.random.PRNGKey(8), (held.size, D))))
+    assert ROWS_SCATTERED.findall(plain)
